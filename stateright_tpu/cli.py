@@ -922,6 +922,9 @@ def _run_status(spec: "CliSpec", args) -> int:
 
 def example_main(spec: CliSpec, argv=None) -> int:
     from .core.report import WriteReporter
+    from .runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     args = list(sys.argv[1:] if argv is None else argv)
     if not args or args[0] in ("-h", "--help", "help"):

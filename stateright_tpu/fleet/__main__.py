@@ -171,6 +171,9 @@ def _quota_main(argv: List[str]) -> int:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    from ..runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     argv = sys.argv[1:] if argv is None else list(argv)
     verbs = {
         "submit": _submit_main, "status": _status_main,

@@ -30,10 +30,11 @@ choices, documented here because `hbm_util_frac` inherits them:
   *relative* shape is the signal; docs/OBSERVABILITY.md discusses the
   bias.
 
-Peaks are public per-chip numbers keyed by JAX ``device_kind``; unknown
-devices (including the CPU backend the tests run on) fall back to a
-conservative estimate flagged ``estimated`` so a util number can never
-masquerade as a measured-hardware claim.
+Peaks are public per-chip numbers keyed by JAX ``device_kind``.  The
+CPU backend the tests run on has an explicit row flagged ``estimated``,
+so a util number from it can never masquerade as a measured-hardware
+claim; any other device missing from the table is an error, not a
+default.
 """
 
 from __future__ import annotations
@@ -51,18 +52,18 @@ DEVICE_PEAKS: Dict[str, float] = {
     "v4": 1.228e12,      # 1,228 GB/s
     "v3": 9.0e11,        # 900 GB/s
     "v2": 7.0e11,        # 700 GB/s
+    # Not a published peak: a conservative host-DRAM figure, so the ratio
+    # stays meaningful on the virtual CPU meshes the tests run on.
+    "cpu": 2.0e10,       # 20 GB/s, estimated
 }
-
-# Fallback for unknown/CPU devices: a conservative host-DRAM figure so
-# the ratio stays meaningful on the virtual CPU meshes the tests run on.
-_FALLBACK_PEAK = 2.0e10  # 20 GB/s
 
 
 def peaks_for_device(device) -> Dict:
     """Peak table entry for a JAX device: ``{"device_kind", "platform",
-    "hbm_bytes_per_sec", "estimated"}``.  ``estimated`` is True whenever
-    the kind did not match the table — util fractions derived from an
-    estimated peak are labeled as such everywhere they surface."""
+    "hbm_bytes_per_sec", "estimated"}``.  ``estimated`` marks the CPU
+    row — util fractions derived from it are labeled as such everywhere
+    they surface.  Raises ``KeyError`` for a device kind the table does
+    not hold."""
     kind = str(getattr(device, "device_kind", "") or "")
     platform = str(getattr(device, "platform", "") or "")
     low = kind.lower()
@@ -72,14 +73,12 @@ def peaks_for_device(device) -> Dict:
                 "device_kind": kind,
                 "platform": platform,
                 "hbm_bytes_per_sec": peak,
-                "estimated": False,
+                "estimated": key == "cpu",
             }
-    return {
-        "device_kind": kind or platform or "unknown",
-        "platform": platform,
-        "hbm_bytes_per_sec": _FALLBACK_PEAK,
-        "estimated": True,
-    }
+    raise KeyError(
+        f"no peak HBM bandwidth for device kind {kind!r} (platform "
+        f"{platform!r}); add it to DEVICE_PEAKS with its source"
+    )
 
 
 def hbm_util_frac(bytes_touched: float, seconds: float,

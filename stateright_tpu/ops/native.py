@@ -12,6 +12,7 @@ to the pure-Python implementations.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import pathlib
 import subprocess
@@ -19,7 +20,7 @@ import threading
 from typing import Optional
 
 _SRC = pathlib.Path(__file__).resolve().parent.parent.parent / "native"
-_OUT = pathlib.Path(__file__).resolve().parent / "_libstateright_core.so"
+_OUT_DIR = pathlib.Path(__file__).resolve().parent
 
 _lock = threading.Lock()
 _lib = None
@@ -30,9 +31,14 @@ def _compile() -> Optional[pathlib.Path]:
     src = _SRC / "stateright_core.cpp"
     if not src.exists():
         return None
-    if _OUT.exists() and _OUT.stat().st_mtime >= src.stat().st_mtime:
-        return _OUT
-    tmp = _OUT.with_suffix(f".tmp{os.getpid()}.so")
+    # Keyed on the source's content, not its mtime: a copied tree keeps
+    # whatever mtimes the copy gave it, and must never load a library
+    # built from other source.
+    digest = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+    out = _OUT_DIR / f"_libstateright_core.{digest}.so"
+    if out.exists():
+        return out
+    tmp = out.with_suffix(f".tmp{os.getpid()}.so")
     try:
         subprocess.run(
             [
@@ -50,11 +56,11 @@ def _compile() -> Optional[pathlib.Path]:
         )
         # Atomic rename: concurrent processes never dlopen a half-written
         # library.
-        os.replace(tmp, _OUT)
+        os.replace(tmp, out)
     except (OSError, subprocess.CalledProcessError):
         tmp.unlink(missing_ok=True)
         return None
-    return _OUT
+    return out
 
 
 def load():

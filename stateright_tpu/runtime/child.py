@@ -19,12 +19,12 @@ from __future__ import annotations
 
 import json
 import os
-import pathlib
 import pickle
 import sys
 import time
 import traceback
 
+from .compile_cache import use_compile_cache
 from .journal import Journal
 from .supervisor import (
     CHECKPOINT_FILE,
@@ -45,13 +45,9 @@ FAULT_ENV = "STATERIGHT_RUNTIME_FAULT_EXIT_AFTER_CHECKPOINT"
 
 def run_child(run_dir: str) -> int:
     run_dir = os.path.abspath(run_dir)
-    # Persistent XLA cache: restarted children recompile the same
-    # programs; without this every resume pays full compile time.
-    repo = pathlib.Path(run_dir)
-    os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR", str(repo / ".jax_cache")
-    )
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
+    # Restarted children recompile the same programs; the fixed cache
+    # directory lets every resume hit them.
+    use_compile_cache()
 
     journal = Journal(os.path.join(run_dir, JOURNAL_FILE))
     try:

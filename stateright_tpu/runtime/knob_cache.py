@@ -4,22 +4,22 @@ process.
 bench.py's measurement protocol discovers right-sized engine knobs with a
 default-knob auto-tune run before every measured run.  Discovery is the
 expensive half — ~21 minutes for the 61.5M-state ``2pc check 10`` — and
-was re-paid by every round and every suite child because the result never
-left the process (VERDICT r5 weak #2).  This cache stores each workload's
-tuned kwargs as one JSON object keyed by (workload, model identity,
-device, engine geometry), under a directory that doubles as the bench's
-checkpoint dir; suite children (separate processes) and later rounds
-reload instead of rediscovering.
+was re-paid by every round because the result never left the process
+(VERDICT r5 weak #2).  This cache stores each workload's tuned kwargs
+as one JSON object keyed by (workload, model identity, device, engine
+geometry), under a directory that doubles as the bench's checkpoint
+dir; later rounds, fleet workers and the service reload instead of
+rediscovering.
 
 Staleness is harmless by construction: the engines' auto-tune grows
 undersized knobs in place mid-run, and the caller golden-gates every
 measured run — a cache entry that no longer reproduces the golden is
 dropped (:func:`drop_knobs`) and the caller falls back to a fresh
-discovery.  Writes are atomic (write + rename) so concurrent children
+discovery.  Writes are atomic (write + rename) so concurrent processes
 can never leave a torn file.  Within one process every mutation holds a
 module lock around its read-merge-write, so the checking service's
 concurrent jobs (serve/scheduler.py) never lose each other's entries;
-ACROSS processes (bench suite children) last-whole-file-writer wins,
+ACROSS processes (fleet workers) last-whole-file-writer wins,
 which is fine for a cache whose entries are all independently
 rediscoverable.
 """

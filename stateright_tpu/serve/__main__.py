@@ -39,6 +39,9 @@ DEFAULT_ADDRESS = "localhost:3100"
 
 
 def main(argv=None) -> int:
+    from ..runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     args = list(sys.argv[1:] if argv is None else argv)
     if args and args[0] in ("-h", "--help", "help"):
         print(__doc__.strip())

@@ -50,7 +50,6 @@ from ..parallel.sharded import (
     NO_GID,
     ShardedTpuChecker,
     _owner_mix_host_np,
-    _shard_map,
 )
 from .cold_store import ColdStore
 from .engine import capacity_for_budget
@@ -230,7 +229,7 @@ class TieredShardedTpuChecker(ShardedTpuChecker):
 
         def sharded(fn, n_in, donate=()):
             return jax.jit(
-                _shard_map(
+                jax.shard_map(
                     fn, mesh=self._mesh,
                     in_specs=(shard,) * n_in, out_specs=shard,
                 ),

@@ -168,3 +168,29 @@ def test_twophase_native_bfs_guards():
         twophase_bfs_native(13)  # past the packed layout's 12-RM bound
     with pytest.raises(RuntimeError, match="rc="):
         twophase_bfs_native(5, max_unique=100)  # memory guard trips
+
+
+def test_library_is_keyed_on_source_content(tmp_path, monkeypatch):
+    """A library built from other source is never reused, whatever the
+    mtimes say: a copied tree can carry a .so newer than a changed
+    source."""
+    import os
+
+    from stateright_tpu.ops import native
+
+    src = tmp_path / "stateright_core.cpp"
+    monkeypatch.setattr(native, "_SRC", tmp_path)
+    monkeypatch.setattr(native, "_OUT_DIR", tmp_path)
+
+    src.write_text('extern "C" int sr_probe() { return 1; }\n')
+    first = native._compile()
+    assert first is not None and first.exists()
+    assert native._compile() == first  # same content: reused
+
+    src.write_text('extern "C" int sr_probe() { return 2; }\n')
+    # The stale library looks newer than the changed source.
+    stamp = first.stat().st_mtime
+    os.utime(src, (stamp - 60, stamp - 60))
+    second = native._compile()
+    assert second is not None and second != first
+    assert second.exists() and first.exists()

@@ -38,9 +38,13 @@ def _cpu():
 # --- roofline units -----------------------------------------------------------
 
 
-def test_peaks_for_device_known_and_fallback():
+def test_peaks_for_device_known_cpu_and_unknown():
     class FakeV5e:
         device_kind = "TPU v5 lite"
+        platform = "tpu"
+
+    class FakeUnknownTpu:
+        device_kind = "TPU v99"
         platform = "tpu"
 
     p = peaks_for_device(FakeV5e())
@@ -48,8 +52,12 @@ def test_peaks_for_device_known_and_fallback():
     assert p["estimated"] is False
 
     p = peaks_for_device(_cpu())
-    assert p["estimated"] is True  # unknown kinds never masquerade
+    assert p["estimated"] is True  # the CPU row never masquerades
     assert p["hbm_bytes_per_sec"] > 0
+
+    # A device missing from the table is an error, not a default.
+    with pytest.raises(KeyError, match="TPU v99"):
+        peaks_for_device(FakeUnknownTpu())
 
 
 def test_byte_model_sanity():

@@ -284,7 +284,8 @@ def test_trajectory_on_committed_rounds_is_clean():
     traj = bench_trajectory(paths)
     assert len(traj["rounds"]) == len(paths)
     assert traj["regressions"] == []
-    assert "BENCH_r01" in render_trajectory_markdown(traj)
+    md = render_trajectory_markdown(traj)
+    assert all(os.path.basename(p)[:-5] in md for p in paths)
 
 
 # --- the report CLI verb -----------------------------------------------------

@@ -1,5 +1,5 @@
 """Runtime subsystem: telemetry journal, geometry-backoff policy,
-isolated-child runner, engine checkpoint/journal hooks, and the
+compile-cache placement, engine checkpoint/journal hooks, and the
 crash-resilience acceptance test — a supervised CPU-backend check whose
 child is killed mid-run resumes from the latest checkpoint and finishes
 with an IDENTICAL discovery set and counts to an uninterrupted run.
@@ -18,7 +18,6 @@ from stateright_tpu.runtime.supervisor import (
     SupervisorConfig,
     journal_events,
     relax_geometry,
-    run_isolated,
 )
 
 jax = pytest.importorskip("jax")
@@ -185,36 +184,40 @@ def test_relax_sharded_uses_chunk_size():
     assert step["chunk_size"] == 4096
 
 
-# --- isolated-child runner ---------------------------------------------------
+# --- compile-cache placement --------------------------------------------------
 
 
-def test_run_isolated_success_first_try():
-    res = run_isolated([sys.executable, "-c", "print('ok')"], attempts=2)
-    assert res.returncode == 0 and res.attempts_used == 1
-    assert "ok" in res.stdout and not res.timed_out
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir_env_wins_else_fixed_checkout_path(
+    tmp_path, monkeypatch, from_env
+):
+    """The environment's directory wins; otherwise every entry point and
+    every supervised child uses ``<checkout>/.jax_cache`` — never a path
+    derived from a run directory, so restarts and later runs hit it."""
+    from stateright_tpu.runtime import compile_cache as cc
 
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if from_env:
+        want = str(tmp_path / "shared-cache")
+        monkeypatch.setenv(cc.ENV_VAR, want)
+    else:
+        want = os.path.join(checkout, ".jax_cache")
+        monkeypatch.delenv(cc.ENV_VAR, raising=False)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        assert cc.use_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert os.environ[cc.ENV_VAR] == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
 
-def test_run_isolated_retries_crash_in_fresh_process(tmp_path):
-    # The child crashes on the first run (no marker file) and succeeds on
-    # the second — the fresh-process-retry contract.
-    marker = str(tmp_path / "marker")
-    prog = (
-        "import os, sys; p = sys.argv[1]\n"
-        "sys.exit(0) if os.path.exists(p) else (open(p, 'w').close(),"
-        " sys.exit(1))"
+    if not from_env:
+        monkeypatch.delenv(cc.ENV_VAR)
+    sup = RunSupervisor(
+        SupervisorConfig(run_dir=str(tmp_path / "run")),
+        child_argv=[sys.executable, "-c", "pass"],
     )
-    res = run_isolated(
-        [sys.executable, "-c", prog, marker], attempts=2,
-    )
-    assert res.returncode == 0 and res.attempts_used == 2
-
-
-def test_run_isolated_timeout_is_final():
-    res = run_isolated(
-        [sys.executable, "-c", "import time; time.sleep(60)"],
-        timeout=1.0, attempts=3,
-    )
-    assert res.timed_out and res.attempts_used == 1
+    assert sup._child_env()[cc.ENV_VAR] == want
 
 
 # --- engine checkpoint/journal hooks (in-process, CPU backend) ---------------
