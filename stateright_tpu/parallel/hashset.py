@@ -393,9 +393,14 @@ def insert_batch_claim(
     executed probe round count, as in :func:`insert_batch_compact`.
 
     The one failure mode the election shares with the sorted path is
-    probe exhaustion (``probe_ok`` False): the engines' sortless flag
-    dispatch falls back to the sort-rung path before growing the table,
-    so a pathological batch costs a recompile, never a wrong answer.
+    probe exhaustion (``probe_ok`` False): the engines raise flag 1 and
+    grow the table, as on the sorted path.  When the caller's
+    compaction buffer overflows at its full width (a duplicate-dense
+    batch), the single-chip engine relaxes ``dedup_factor`` and falls
+    back to the sort path at a narrow rung in one move
+    (wave_loop.land_on_sort_rung); the sharded engine relaxes and stays
+    on the election at full width.  Either way a pathological batch
+    costs a recompile, never a wrong answer.
     """
     capacity = table.capacity
     mask = _U32(capacity - 1)

@@ -375,6 +375,31 @@ def relax_dedup_geometry(chunk, dedup_factor, lanes_of, lane_cap,
     return 1, c, "; ".join(notes)
 
 
+def land_on_sort_rung(eng, old_full: int) -> str:
+    """The relax-path tail of a run whose rung the tuner owns (the
+    narrow twin of :func:`reset_sort_rung_to_full`): a FULL-buffer
+    flag-4 overflow relaxed ``dedup_factor`` to 1, and the compaction
+    lands on the next power of two above the width that overflowed
+    instead of the whole relaxed batch — a duplicate-dense batch fills a
+    fraction of it.  The rung is the floor (the overflow proved the old
+    width too small); a later overflow climbs it through
+    :func:`climb_sort_rung`.  A claim-election run flips to the sort
+    path in the same move: at one rung the sort path measured faster on
+    such batches (PERF.md, 2pc 8 on a v5e).  Returns the grow note."""
+    notes = []
+    if eng._sortless:
+        eng._sortless = False
+        notes.append("sortless=0")
+    rung = clamp_sort_lanes(old_full + 1)
+    eng._sort_lanes = rung
+    eng._sort_rung_floor = rung
+    eng._sort_peak_valid = max(eng._sort_peak_valid, old_full)
+    notes.append(f"sort_lanes={rung}")
+    if eng._journal:
+        eng._journal.append("geometry", **eng._wl_geometry())
+    return "; ".join(notes)
+
+
 class CheckpointCadence:
     """Mid-run checkpoint pacing shared by every host loop: due every
     ``every_waves`` waves (counted in whatever quantum the loop reports)

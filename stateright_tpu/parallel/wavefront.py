@@ -191,6 +191,11 @@ _PROGRAM_CACHE_MAX = 32
 class TpuChecker(Checker):
     """Single-device wavefront checker behind the standard Checker surface."""
 
+    # A full-buffer flag-4 overflow on a tuner-owned rung relaxes and
+    # lands on a narrow sort rung in one move (_grow); False keeps the
+    # two-step dispatch: flip to the sort path, then relax to full width.
+    _land_on_rung = True
+
     def __init__(
         self,
         options,
@@ -245,17 +250,20 @@ class TpuChecker(Checker):
         unless an explicit ``sort_lanes`` rung selects the sorted
         fallback path.  Under sortless, a flag-4 overflow of a
         TUNER-pinned compaction rung just climbs one rung (same as the
-        sort path — the tuner guessed small, the election is fine);
-        the FALLBACK to the sort-rung path fires when the claim
-        compaction buffer overflows at its full worst-case width (a
-        duplicate-heavy workload the election cannot represent at this
-        ``dedup_factor``) — non-committing, the wave re-runs, no work
-        lost — and ``tuned_kwargs()`` persists the flip, so the
-        selection is per-workload through the knob cache.  Passing
+        sort path — the tuner guessed small, the election is fine).
+        When the compaction buffer overflows at its full worst-case
+        width (a duplicate-dense workload), one move relaxes
+        ``dedup_factor`` to 1, FALLS BACK to the sort path, and lands
+        it on the next power-of-two rung above the width that
+        overflowed, as the tuner's floor (at one rung the sort path
+        measured faster than the election on such batches); later
+        overflows climb the rung.  Every move is non-committing — the
+        wave re-runs, no work lost — and ``tuned_kwargs()`` persists
+        the path and the rung, so a warm repeat starts there.  Passing
         BOTH ``sortless=True`` and ``sort_lanes`` keeps the election
         but makes the rung an explicit claim BUDGET: its overflow
-        falls back immediately — the forcing knob tests/CI use to
-        exercise the fallback on small models.
+        falls back at once, with no relax — the forcing knob tests/CI
+        use to exercise the fallback on small models.
 
         ``sort_lanes``: the sorted fallback path's adaptive rung (the
         PR 12 ladder) — a power-of-two width for the per-wave
@@ -378,6 +386,10 @@ class TpuChecker(Checker):
         self._sort_rung_floor = SORT_RUNG_MIN
         self._sort_peak_valid = 0.0
         self._sort_quanta = 0
+        # Flag-4 moves (metrics()): rung climbs, the landing after a
+        # relax included, and sortless → sort flips.
+        self._dedup_rung_climbs = 0
+        self._dedup_fallbacks = 0
         # Dedup-path selection (the sortless claim-plane election is the
         # default; an explicit sort_lanes rung selects the sorted
         # fallback path — see the docstring).
@@ -1091,8 +1103,8 @@ class TpuChecker(Checker):
         if flag & 4:
             from .hashset import unique_buffer_size
             from .wave_loop import (
-                climb_sort_rung, fall_back_to_sort, relax_dedup_geometry,
-                reset_sort_rung_to_full,
+                climb_sort_rung, fall_back_to_sort, land_on_sort_rung,
+                relax_dedup_geometry, reset_sort_rung_to_full,
             )
 
             # EXPLICIT claim-budget cap first: ``sortless=True`` with a
@@ -1105,6 +1117,7 @@ class TpuChecker(Checker):
             # repeat re-arms the tuner instead of inheriting a
             # one-notch-tight explicit cap).
             if self._sortless and not self._sort_tune:
+                self._dedup_fallbacks += 1
                 return fall_back_to_sort(self)
             # Compact-rung ladder next, on BOTH dedup paths: when the
             # compact/claim buffers run at a TUNER-pinned rung below
@@ -1122,18 +1135,19 @@ class TpuChecker(Checker):
             full = self._wl_full_sort_lanes()
             note = climb_sort_rung(self, full)
             if note is not None:
+                self._dedup_rung_climbs += 1
                 return note
-            # SORTLESS fallback at the FULL buffer: the valid batch
-            # exceeded the claim compaction buffer at its worst-case
-            # width — the per-workload signal that the election cannot
-            # represent this (duplicate-heavy) batch at the current
-            # dedup_factor.  Flip to the sorted fallback rung
-            # (wave_loop.fall_back_to_sort; the flagged wave committed
-            # nothing, so the re-run at the sorted program is exact)
-            # and let ITS relax rules take over on subsequent
-            # overflows.  tuned_kwargs persists the flip, so the
-            # selection is per-workload through the knob cache.
-            if self._sortless:
+            # The FULL buffer overflowed: the valid batch exceeded the
+            # compaction buffer at its worst-case width for this
+            # dedup_factor (a duplicate-dense model).  Relax below and
+            # land on a narrow sort rung in the same move.  Without
+            # _land_on_rung (the tiered engine) a sortless run first
+            # flips alone to the sorted fallback rung
+            # (wave_loop.fall_back_to_sort) and relaxes on its next
+            # overflow.  The flagged wave committed nothing, so either
+            # re-run is exact; tuned_kwargs persists path and rung.
+            if self._sortless and not self._land_on_rung:
+                self._dedup_fallbacks += 1
                 return fall_back_to_sort(self)
             # Straight to the always-safe 1, not stepwise (the
             # intermediate dd=2-at-doubled-frontier stop measured as a
@@ -1158,9 +1172,15 @@ class TpuChecker(Checker):
                 # worker-crash band.
                 return None
             self._dedup_factor, self._max_frontier, note = relaxed
-            # The FULL buffer overflowed on valid count: the relaxed
-            # dd=1 geometry starts at its own full width (evidence +
-            # geometry re-journal in the shared helper).
+            if self._land_on_rung and self._sort_tune:
+                # The next rung above the width that overflowed, as the
+                # tuner's floor; a sortless run flips to the sort path
+                # (evidence + geometry re-journal in the shared helper).
+                self._dedup_fallbacks += int(self._sortless)
+                self._dedup_rung_climbs += 1
+                return f"{note}; {land_on_sort_rung(self, full)}"
+            # An explicit rung (or the tiered engine): the relaxed dd=1
+            # geometry starts at its own full width.
             reset_sort_rung_to_full(self, full)
             return note
         return None
@@ -2222,6 +2242,8 @@ class TpuChecker(Checker):
             sort_lanes_rung=self._sort_lanes or 0,
             step_lanes=self._step_width(),
             step_lanes_rung=self._step_lanes or 0,
+            dedup_rung_climbs=self._dedup_rung_climbs,
+            dedup_fallbacks=self._dedup_fallbacks,
         )
         snap = self._metrics.snapshot()
         # Table load factor: mid-run it is the loop's already-synced

@@ -91,6 +91,9 @@ def capacity_for_budget(memory_budget_mb: float) -> int:
 class TieredTpuChecker(TpuChecker):
     """Budget-bounded wavefront checker behind the standard surface."""
 
+    # Flag 4 at the full buffer keeps the two-step dispatch (TpuChecker).
+    _land_on_rung = False
+
     def __init__(
         self,
         options,

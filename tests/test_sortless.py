@@ -9,7 +9,8 @@ eviction, symmetry through the golden orbit count — including
 forced-overflow runs: a tiny forced step rung climbs via the
 non-committing flag 128, and a sortless run forced onto a tiny
 compaction buffer FALLS BACK to the sort-rung path mid-run
-(``grow sortless=0``) with no lost work.
+(``grow sortless=0``) with no lost work, and a duplicate-dense default
+run that overflows the full buffer lands on a narrow sort rung.
 
 The reference in every gate is ``sortless=False`` with ``sort_lanes``
 pinned past the full buffer — the PR 12 fixed-geometry sort path."""
@@ -163,6 +164,65 @@ def test_forced_fallback_to_sort_rung_mid_run(tmp_path, reference_fps):
         e for e in read_journal(journal) if e["event"] == "geometry"
     ]
     assert any(g.get("sortless") is False for g in geoms)
+
+
+@pytest.fixture(scope="module")
+def dense_run(tmp_path_factory):
+    """A default-knob 2pc 6 check from the spec's kwargs: its chunks
+    (one per level) carry up to 60,802 valid lanes, past the 32,768-lane
+    dd=8 compaction buffer, so flag 4 fires at the full buffer."""
+    journal = str(tmp_path_factory.mktemp("dense") / "dense.jsonl")
+    ck = TwoPhaseSys(rm_count=6).checker().spawn_tpu(
+        capacity=1 << 20, max_frontier=1 << 13, device=_cpu(),
+        journal=journal,
+    ).join()
+    return ck, journal
+
+
+def test_full_buffer_overflow_lands_on_narrow_sort_rung(dense_run):
+    """One flag-4 move relaxes dedup_factor, falls back to the sort path
+    and lands on the next rung above the overflowed width (65,536 lanes,
+    not the 262,144-lane relaxed batch); the set is bit-identical to the
+    sort path at full width."""
+    ck, journal = dense_run
+    ref = TwoPhaseSys(rm_count=6).checker().spawn_tpu(
+        capacity=1 << 20, max_frontier=1 << 13, device=_cpu(),
+        dedup_factor=1, sortless=False, sort_lanes=FULL,
+    ).join()
+    assert ref.metrics()["sort_lanes"] == 1 << 18
+    assert np.array_equal(
+        ck.discovered_fingerprints(), ref.discovered_fingerprints()
+    )
+    m = ck.metrics()
+    assert m["sortless"] is False
+    assert m["dedup_factor"] == 1
+    assert m["sort_lanes"] == 1 << 16
+    assert m["dedup_fallbacks"] == 1
+    assert m["dedup_rung_climbs"] >= 1
+    flag4 = [
+        e for e in read_journal(journal)
+        if e["event"] == "grow" and e["flags"] & 4
+    ]
+    assert len(flag4) == 1
+    assert flag4[0]["grown"] == "dedup_factor=1; sortless=0; sort_lanes=65536"
+
+
+def test_landed_rung_warm_start_skips_the_overflow(dense_run, tmp_path):
+    ck, _ = dense_run
+    journal = str(tmp_path / "warm.jsonl")
+    warm = TwoPhaseSys(rm_count=6).checker().spawn_tpu(
+        device=_cpu(), journal=journal, **ck.tuned_kwargs(),
+    ).join()
+    assert not [
+        e for e in read_journal(journal)
+        if e["event"] == "grow" and e["flags"] & 4
+    ]
+    m = warm.metrics()
+    assert m["sortless"] is False
+    assert m["sort_lanes"] == 1 << 16
+    assert np.array_equal(
+        warm.discovered_fingerprints(), ck.discovered_fingerprints()
+    )
 
 
 def test_forced_tiny_step_rung_climbs_and_bit_identical(

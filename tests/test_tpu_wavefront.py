@@ -385,17 +385,20 @@ def test_auto_tune_dedup_growth_clamps_frontier():
     ck._log_capacity_explicit = False
     ck._dedup_factor = 4
     ck._max_frontier = 1 << 15
-    # Default sort-rung state (the full buffer) on the SORT path: the
-    # flag-4 growth goes straight to the dd relax, not a rung climb
-    # (and not the sortless fallback, which fires first when armed).
+    # Default sort-rung state (the full buffer, tuner-owned) on the SORT
+    # path: the flag-4 growth goes straight to the dd relax and lands on
+    # a narrow rung, not a rung climb.
     ck._sortless = False
     ck._sort_lanes = None
+    ck._sort_tune = True
     ck._step_lanes = None
     ck._sort_peak_valid = 0.0
+    ck._dedup_rung_climbs = ck._dedup_fallbacks = 0
     ck._journal = None  # the relax tail re-journals geometry when set
     msg = ck._grow(4)
     assert ck._dedup_factor == 1
     assert "max_frontier" in msg
+    assert "sort_lanes=" in msg
     assert (
         unique_buffer_size(
             ck._max_frontier * ck._compiled.max_actions, ck._dedup_factor
