@@ -4,7 +4,8 @@ file or computed by the plain reference after the window.
 
 Each number compared has its own limit.  All comparisons here are
 exact, so every limit is 0; PERF.md gives the readings of sound runs
-and of the control that each limit sits between.
+and of the control that each limit sits between.  A sharded check is
+also held to its layout: every shard holds its share of the states.
 """
 
 from __future__ import annotations
@@ -46,12 +47,26 @@ def compare_exhaustive(records: List[Dict], want: Dict) -> Dict[str, Dict]:
                     default=0)
     wrong_names = sum(r["discoveries"] != sorted(want["discoveries"])
                       for r in done)
-    return {
+    out = {
         "unique_gap": {"value": unique_gap, "limit": 0},
         "depth_gap": {"value": depth_gap, "limit": 0},
         "discovery_names_wrong": {"value": wrong_names, "limit": 0},
         "requests_failed": {"value": len(records) - len(done), "limit": 0},
     }
+    sharded = [r["unique_per_shard"] for r in done
+               if r.get("unique_per_shard")]
+    if sharded:
+        out["shards_underfull"] = {
+            "value": max(map(shards_underfull, sharded)), "limit": 0}
+    return out
+
+
+def shards_underfull(per_shard: List[int]) -> int:
+    """Shards that hold less than half of an even share of the states:
+    an owner-sharded visited set spreads its states over every shard by
+    fingerprint, so none does unless states stay where they were made."""
+    even = sum(per_shard) / len(per_shard)
+    return sum(u < even / 2 for u in per_shard)
 
 
 def compare_hunt(records: List[Dict], want: Dict) -> Dict[str, Dict]:

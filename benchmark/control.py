@@ -17,6 +17,9 @@ Plants:
   path but not a shortest one.
 - ``half``: half of the new states left out (all states whose low
   fingerprint bit is set collide into one).
+- ``no_exchange``: the sharded engine's exchange between chips left out:
+  ``all_to_all`` hands each shard back its own send buffers, so a state
+  is inserted where it was generated and not by its owner.
 - ``answer_count``, ``answer_path`` and ``answer_drop``: an answer
   altered where it is produced: the unique count one too high, the
   counterexample path one state short, the discoveries dropped.
@@ -59,6 +62,14 @@ def half():
     return _patch_fp(merge)
 
 
+def no_exchange():
+    import jax
+
+    orig = jax.lax.all_to_all
+    jax.lax.all_to_all = lambda x, *_a, **_kw: x  # each shard keeps its sends
+    return lambda: setattr(jax.lax, "all_to_all", orig)
+
+
 def dfs():
     from stateright_tpu.core.checker import CheckerBuilder
 
@@ -67,37 +78,44 @@ def dfs():
     return lambda: setattr(CheckerBuilder, "spawn_tpu", orig)
 
 
-def answer_count():
+def _patch_answer(method: str, wrap):
+    """Replace ``method`` of both device engines by ``wrap(original)``."""
+    from stateright_tpu.parallel.sharded import ShardedTpuChecker
     from stateright_tpu.parallel.wavefront import TpuChecker
 
-    orig = TpuChecker.unique_state_count
-    TpuChecker.unique_state_count = lambda self: orig(self) + 1
-    return lambda: setattr(TpuChecker, "unique_state_count", orig)
+    origs = {cls: getattr(cls, method) for cls in (TpuChecker,
+                                                   ShardedTpuChecker)}
+    for cls, orig in origs.items():
+        setattr(cls, method, wrap(orig))
+
+    def undo():
+        for cls, orig in origs.items():
+            setattr(cls, method, orig)
+
+    return undo
+
+
+def answer_count():
+    return _patch_answer("unique_state_count",
+                         lambda orig: lambda self: orig(self) + 1)
 
 
 def answer_path():
     from stateright_tpu.core.path import Path
-    from stateright_tpu.parallel.wavefront import TpuChecker
 
-    orig = TpuChecker.discoveries
+    def short(orig):
+        return lambda self: {k: Path(p._steps[:-1])
+                             for k, p in orig(self).items()}
 
-    def short(self):
-        return {k: Path(p._steps[:-1]) for k, p in orig(self).items()}
-
-    TpuChecker.discoveries = short
-    return lambda: setattr(TpuChecker, "discoveries", orig)
+    return _patch_answer("discoveries", short)
 
 
 def answer_drop():
-    from stateright_tpu.parallel.wavefront import TpuChecker
-
-    orig = TpuChecker.discoveries
-    TpuChecker.discoveries = lambda self: {}
-    return lambda: setattr(TpuChecker, "discoveries", orig)
+    return _patch_answer("discoveries", lambda orig: lambda self: {})
 
 
-PLANTS = {f.__name__: f for f in (fp32, half, dfs, answer_count, answer_path,
-                                  answer_drop)}
+PLANTS = {f.__name__: f for f in (fp32, half, no_exchange, dfs, answer_count,
+                                  answer_path, answer_drop)}
 
 
 def run_planted(name: str, plant: str, seeds, seconds: float,

@@ -2,10 +2,15 @@
 the system under test with them.
 
 A request is one check as a user of ``check-tpu`` runs it: build the
-model, ``spawn_tpu`` with the workload's kwargs (auto-tune on, no knob
-cache), ``join()``, and read the result, discoveries with their paths
-included.  The loop is closed with one client: the next request starts
-when the last one has returned.
+model, spawn the configuration's engine with the workload's kwargs
+(``spawn_tpu``: auto-tune on, no knob cache), ``join()``, and read the
+result, discoveries with their paths included.  A configuration may name
+``spawn_tpu_sharded`` as its ``engine``: the kwargs are then translated as
+``check-tpu --sharded`` translates them and the engine runs over a 1-D
+mesh of the cell's chips.  Its ``kwargs`` object is merged over the
+workload's; a cell file's ``target_max_depth`` bounds every check.  The
+loop is closed with one client: the next request starts when the last
+one has returned.
 """
 
 from __future__ import annotations
@@ -16,17 +21,50 @@ from contextlib import nullcontext
 from typing import Callable, Dict, List, Tuple
 
 
-def model_builder(cfg: Dict, overrides: Dict) -> Callable[[], Tuple[object, dict]]:
-    """``() -> (model, engine_kwargs)`` for a configuration file, with a
-    cell's property variant (``model_overrides``) applied to the actor
-    model's configuration dataclass (``model.cfg``)."""
+# The single-chip engine's knobs that ``check-tpu --sharded`` drops.
+SINGLE_CHIP_ONLY = ("log_capacity", "waves_per_call", "auto_tune")
+
+
+def engine_kwargs(engine: str, spec_kwargs: Dict, cfg_kwargs: Dict) -> Dict:
+    """The workload's kwargs as ``check-tpu`` hands them to ``engine``,
+    with the configuration's own ``kwargs`` merged over them."""
+    kwargs = dict(spec_kwargs)
+    if engine == "spawn_tpu_sharded":
+        if "max_frontier" in kwargs:
+            kwargs["chunk_size"] = kwargs.pop("max_frontier")
+        for key in SINGLE_CHIP_ONLY:
+            kwargs.pop(key, None)
+    kwargs.update(cfg_kwargs)
+    return kwargs
+
+
+def model_builder(cfg: Dict, cell_file: Dict, chips: int
+                  ) -> Callable[[], Tuple[object, Dict]]:
+    """``() -> (model, request)`` for a configuration file: ``request``
+    holds the ``engine`` to spawn, its ``kwargs`` and the cell's
+    ``target_max_depth`` (None when unbounded).  A cell's property
+    variant (``model_overrides``) is applied to the actor model's
+    configuration dataclass (``model.cfg``)."""
     from stateright_tpu.serve.workloads import build_model
+
+    overrides = cell_file.get("model_overrides", {})
+    engine = cfg["engine"]
+    mesh = None
+    if engine == "spawn_tpu_sharded":
+        import jax
+        import numpy as np
+
+        mesh = jax.sharding.Mesh(np.array(jax.devices()[:chips]), ("shards",))
 
     def build():
         model, spec, _ = build_model(cfg["workload"], cfg["n"], cfg["network"])
         if overrides:
             model = dataclasses.replace(model.cfg, **overrides).into_model()
-        return model, dict(spec.tpu_kwargs)
+        kwargs = engine_kwargs(engine, spec.tpu_kwargs, cfg.get("kwargs", {}))
+        if mesh is not None:
+            kwargs["mesh"] = mesh
+        return model, {"engine": engine, "kwargs": kwargs,
+                       "target_max_depth": cell_file.get("target_max_depth")}
 
     return build
 
@@ -44,18 +82,21 @@ def one_request(build, traffic: Dict, spans: bool = False) -> Dict:
     read.  ``latency_s`` runs from the spawn to the discoveries read."""
     from stateright_tpu.core.has_discoveries import HasDiscoveries
 
-    model, kwargs = build()
+    model, request = build()
     builder = model.checker()
     builder = builder.finish_when(getattr(HasDiscoveries, traffic["finish_when"]))
+    if request["target_max_depth"] is not None:
+        builder = builder.target_max_depth(request["target_max_depth"])
     t0 = time.perf_counter()
     with annotate("bench/spawn", spans):
-        ck = builder.spawn_tpu(**kwargs)
+        ck = getattr(builder, request["engine"])(**request["kwargs"])
     with annotate("bench/join", spans):
         ck.join()
     with annotate("bench/discoveries", spans):
         found = ck.discoveries()
     t1 = time.perf_counter()
     metrics = ck.metrics()
+    exchange = metrics.get("accounting", {})
     compiled = getattr(ck, "_compiled", None)
     rec = {
         "latency_s": t1 - t0,
@@ -67,6 +108,12 @@ def one_request(build, traffic: Dict, spans: bool = False) -> Dict:
         "state_width": getattr(compiled, "state_width", None),
         "host_sec": metrics.get("host_sec_total"),
         "device_call_sec": metrics.get("device_call_sec_total"),
+        # the sharded engine's all_to_all (useful and transmitted bytes)
+        # and its layout; None on the single-chip engine
+        "exchange_payload_bytes_total": exchange.get(
+            "exchange_payload_bytes_total"),
+        "exchange_bytes_total": exchange.get("all_to_all_bytes_total"),
+        "unique_per_shard": exchange.get("unique_per_shard"),
     }
     for name, path in found.items():
         states = path.into_states()

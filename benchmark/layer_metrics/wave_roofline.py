@@ -1,7 +1,7 @@
 """The fused wave program's share of its memory roofline, in %: the bytes
 a breadth-first search needs for the window's checks (work.py) over the
-device's busy seconds in the trace times the chip's HBM bandwidth
-(peaks.json)."""
+device's busy seconds in the trace (averaged over the devices) times the
+cell's chips times one chip's HBM bandwidth (peaks.json)."""
 
 import importlib.util
 import pathlib
@@ -20,4 +20,5 @@ def read(ctx):
         return None
     need = sum(_work.bfs_bytes(r["unique"], r["generated"], r["state_width"])
                for r in done)
-    return 100.0 * need / (t["busy_s"] * peaks["hbm_bytes_per_s"])
+    return 100.0 * need / (t["busy_s"] * ctx["chips"]
+                           * peaks["hbm_bytes_per_s"])

@@ -14,7 +14,7 @@ It imports nothing of the program.  A state packs into one int64:
 The messages form a set, so a message sent twice is one message.  The
 search is a level-by-level breadth-first search with numpy arrays:
 every state of a level is expanded at once, and a successor is new when
-it is in no earlier level.  Run it as ``python twophase.py N``.
+it is in no earlier level.  Run it as ``python twophase.py N [MAX_DEPTH]``.
 """
 
 from __future__ import annotations
@@ -106,16 +106,20 @@ def properties(states: np.ndarray, n: int) -> dict:
     }
 
 
-def check(n: int) -> dict:
+def check(n: int, max_depth: int = None) -> dict:
     """Exhaustive BFS: unique states, generated successors, depth (the
     number of levels, the initial state's level being 1) and the names
-    of the properties discovered."""
+    of the properties discovered.  With ``max_depth`` the search stops at
+    that level: its states are counted but neither checked nor expanded,
+    as stateright's ``target_max_depth`` leaves them."""
     level = np.zeros(1, np.int64)  # every RM working, TM init, no messages
     seen = level.copy()
     generated, depth = 0, 0
     found = set()
     while level.size:
         depth += 1
+        if depth == max_depth:
+            break
         found |= {k for k, v in properties(level, n).items() if v}
         succ = successors(level, n)
         generated += succ.size
@@ -132,4 +136,5 @@ def check(n: int) -> dict:
 
 
 if __name__ == "__main__":
-    print(json.dumps(check(int(sys.argv[1]))))
+    # python twophase.py N [MAX_DEPTH]
+    print(json.dumps(check(*map(int, sys.argv[1:3]))))

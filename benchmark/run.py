@@ -148,8 +148,7 @@ def run(name: str, seed: int, seconds: float, trace: bool,
         from stateright_tpu.obs.timeline import set_xprof
 
         set_xprof(True)  # the program's host spans become TraceAnnotations
-    build = model_builder(spec["config"],
-                          spec["cell_file"].get("model_overrides", {}))
+    build = model_builder(spec["config"], spec["cell_file"], cell["chips"])
     warm_up(build, traffic, spans=trace)
 
     trace_dir = OUT_DIR / f"trace-{name}"
@@ -184,6 +183,7 @@ def run(name: str, seed: int, seconds: float, trace: bool,
     ctx = {
         "records": records, "window_s": window_s, "setup_s": setup_s,
         "compile": clock, "trace": reduced, "peaks": peaks,
+        "chips": cell["chips"],
     }
     section = "per_layer" if trace else "end_to_end"
     metrics = {}

@@ -20,6 +20,10 @@ def test_sound_runs_are_correct():
     ("paxos-3.hunt", "answer_drop", "not_found"),
     ("paxos-3.exhaustive", "half", "depth_gap"),
     ("twophase-8.exhaustive", "answer_drop", "discovery_names_wrong"),
+    ("sharded4.bounded", "half", "unique_gap"),
+    ("sharded4.bounded", "no_exchange", "shards_underfull"),
+    ("sharded4.bounded", "answer_count", "unique_gap"),
+    ("sharded4.bounded", "answer_drop", "discovery_names_wrong"),
 ])
 def test_plant_fails_its_number(key, plant, number):
     out = run_small(key, plant=plant, seconds=0.5)

@@ -11,7 +11,8 @@ import pytest
 from conftest import BENCH, ROOT, run_small
 
 
-@pytest.mark.parametrize("key", ["twophase-8.exhaustive", "paxos-3.hunt"])
+@pytest.mark.parametrize("key", ["twophase-8.exhaustive", "paxos-3.hunt",
+                                 "sharded4.bounded"])
 @pytest.mark.parametrize("trace", [False, True])
 def test_dry_run_is_correct_and_reports_no_metric(key, trace):
     out = run_small(key, seconds=1.0, trace=trace)
