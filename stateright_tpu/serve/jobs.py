@@ -27,7 +27,7 @@ import os
 import socket
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 # Lifecycle states.
 QUEUED = "queued"
@@ -255,6 +255,10 @@ class JobStore:
         self._jobs: Dict[str, Job] = {}
         self._seq = 0
         self._journal = journal
+        # Called with each job that reaches a terminal state, after the
+        # transition is journaled and before its waiters wake, so that a
+        # waiter sees everything the scheduler writes at the end.
+        self.on_terminal: Optional[Callable[[Job], None]] = None
 
     def create(self, spec: JobSpec) -> Job:
         with self._lock:
@@ -307,7 +311,7 @@ class JobStore:
             job.state = CANCELLED
             job.finished_at = time.time()
         self._log("job_cancelled", job, reason="while queued")
-        job._finished.set()
+        self._release(job)
         return True
 
     def transition(self, job: Job, state: str, **fields) -> None:
@@ -322,6 +326,13 @@ class JobStore:
                 job.finished_at = time.time()
         self._log(f"job_{state}", job, **fields)
         if job.terminal:
+            self._release(job)
+
+    def _release(self, job: Job) -> None:
+        try:
+            if self.on_terminal is not None:
+                self.on_terminal(job)
+        finally:
             job._finished.set()
 
     def _log(self, event: str, job: Job, **fields) -> None:

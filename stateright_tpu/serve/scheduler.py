@@ -201,6 +201,7 @@ class Scheduler:
         oldest unexplored checkers past the cap are released (their job
         results remain; only ``/jobs/{id}/explore`` stops working)."""
         self.store = store
+        store.on_terminal = self._finish_spans
         self.journal = journal
         self.knob_cache_dir = knob_cache_dir
         # The persistent verification store (incr/, docs/INCREMENTAL.md)
@@ -259,7 +260,6 @@ class Scheduler:
         # cooperative stop — never both terminal transitions.
         if self.store.try_cancel_queued(job):
             self.metrics.inc("jobs_cancelled")
-            self._finish_spans(job)
         return True
 
     # -- per-job spans / SLO aggregation --------------------------------------
@@ -359,7 +359,6 @@ class Scheduler:
                 job, CANCELLED,
                 unique=result.get("unique_state_count"),
             )
-            self._finish_spans(job)
             return
         except Exception as exc:
             import traceback
@@ -374,7 +373,6 @@ class Scheduler:
                     traceback=traceback.format_exc(limit=5)[-2000:],
                 )
             self.store.transition(job, FAILED, error=job.error[:500])
-            self._finish_spans(job)
             return
         result["completed"] = True
         result["elapsed_sec"] = round(time.monotonic() - t0, 3)
@@ -399,7 +397,6 @@ class Scheduler:
             unique=result.get("unique_state_count"),
             violation=result.get("violation"),
         )
-        self._finish_spans(job)
         self._enforce_checker_retention(job)
 
     def _enforce_checker_retention(self, job: Job) -> None:
