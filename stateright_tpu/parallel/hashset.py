@@ -27,10 +27,19 @@ rung (knob cache selected).  The sorted form runs in three stages:
    back, the unique winner scatters its key (the two key planes can never
    interleave words from different lanes — no phantom keys); occupied by
    another key → linear probe.  Rounds stop once the unresolved count fits
-   the straggler buffer.
-3. **Straggler tail**: the few lanes with deep probe chains are compacted
-   into a small buffer and finish their rounds there — one deep chain must
-   not make the full batch repeat forty rounds.
+   the first straggler stage.
+3. **Straggler ladder**: each round costs its stage's full width in
+   gathers, scatters and the sorts XLA puts before them, however few
+   lanes still probe, so the unresolved lanes step down a geometric
+   ladder of narrower stages (:data:`STRAGGLER_LADDER`: 16,384, 2,048
+   and 256 lanes).  A stage runs rounds while its unresolved count
+   exceeds the next stage's width, then compacts the unresolved lane ids
+   (order-preserving, so the lowest lane stays lowest) and gathers their
+   keys and slots into the next; the last stage runs to completion.  A
+   hand-off with nothing unresolved is skipped, and a stage not narrower
+   than the batch is never entered.  One deep chain must not make the
+   full batch repeat forty rounds.  Both insert paths share the ladder
+   (:func:`_probe_ladder`), and report the rounds run at each stage.
 
 Empty slots are (0, 0); fingerprints are guaranteed nonzero
 (ops.device_fp, mirroring the reference's NonZeroU64 fingerprints,
@@ -93,11 +102,11 @@ def home_slot(hi, lo, capacity: int):
     return _fmix32(hi ^ _rotl(lo, 16) ^ _U32(0x7FEB352D)) & _U32(capacity - 1)
 
 
-def _probe_rounds(kh, kl, claim, hi, lo, lane, slot, done, is_new, mask,
-                  capacity, stop_count, max_rounds, elect: bool = False):
+def _probe_rounds(kh, kl, claim, hi, lo, slot, done, stop_count: int,
+                  elect: bool):
     """Probe/claim rounds until ≤ ``stop_count`` lanes remain unresolved (or
-    ``max_rounds`` elapse).  The trailing element of the returned tuple is
-    the executed round count — the one measured input the roofline's
+    ``4 × capacity`` rounds elapse).  The trailing element of the returned
+    tuple is the executed round count — the one measured input the roofline's
     probe-bytes model needs (obs/roofline.py).
 
     ``elect=True`` is the SORTLESS mode (:func:`insert_batch_claim`):
@@ -113,11 +122,14 @@ def _probe_rounds(kh, kl, claim, hi, lo, lane, slot, done, is_new, mask,
     Every wanted slot is won (the min contender is a contender), so a
     slot with a stale claim is always occupied and can never block a
     later round's election."""
+    capacity = kh.shape[0]
+    mask = _U32(capacity - 1)
+    lane = jnp.arange(hi.shape[0], dtype=_U32)
 
     def cond(carry):
         _kh, _kl, _claim, _slot, done, _new, rounds = carry
         undone = jnp.sum(~done, dtype=jnp.int32)
-        return (undone > stop_count) & (rounds < max_rounds)
+        return (undone > stop_count) & (rounds < 4 * capacity)
 
     def body(carry):
         kh, kl, claim, slot, done, is_new, rounds = carry
@@ -143,8 +155,83 @@ def _probe_rounds(kh, kl, claim, hi, lo, lane, slot, done, is_new, mask,
         slot = jnp.where(advance, (slot + _U32(1)) & mask, slot)
         return kh, kl, claim, slot, done, is_new | won, rounds + 1
 
-    init = (kh, kl, claim, slot, done, is_new, jnp.zeros((), jnp.int32))
+    init = (
+        kh, kl, claim, slot, done, done & ~done, jnp.zeros((), jnp.int32)
+    )
     return jax.lax.while_loop(cond, body, init)
+
+
+# Lane widths of the straggler stages below the main one (module docstring,
+# stage 3): each rung is an eighth of the one above it.
+STRAGGLER_LADDER = (1 << 14, 1 << 11, 1 << 8)
+
+
+def stage_widths(b: int) -> Tuple[int, ...]:
+    """The lane width behind each entry of the per-stage round vector an
+    insert over a ``b``-lane buffer reports: the main stage, then every
+    ladder rung (a rung not narrower than ``b`` is skipped and reads 0)."""
+    return (b,) + STRAGGLER_LADDER
+
+
+def _ladder_stage(kh, kl, claim, hi, lo, slot, done, rungs, elect):
+    """Probe rounds at this stage's width until the unresolved lanes fit
+    ``rungs[0]``, then hand them down the remaining rungs and scatter
+    their results back.  Returns ``(kh, kl, slot, done, is_new,
+    rounds[1 + len(rungs)])``."""
+    from .wave_common import compact
+
+    w = hi.shape[0]
+    nxt = rungs[0] if rungs else 0
+    kh, kl, claim, slot, done, is_new, rounds = _probe_rounds(
+        kh, kl, claim, hi, lo, slot, done, nxt, elect
+    )
+    if not rungs:
+        return kh, kl, slot, done, is_new, rounds[None]
+
+    def hand_off(ops):
+        kh, kl, claim, slot, done, is_new = ops
+        undone = ~done
+        ref = compact(undone, jnp.arange(w, dtype=_U32), nxt)
+        t_act = jnp.arange(nxt, dtype=_U32) < jnp.sum(undone, dtype=_U32)
+        kh, kl, t_slot, t_done, t_new, t_rounds = _ladder_stage(
+            kh, kl, claim, hi[ref], lo[ref], slot[ref], ~t_act, rungs[1:],
+            elect,
+        )
+        back = jnp.where(t_act, ref, _U32(w))
+        return (
+            kh, kl, slot.at[back].set(t_slot, mode="drop"),
+            done.at[back].set(t_done, mode="drop"),
+            is_new.at[back].set(t_new, mode="drop"), t_rounds,
+        )
+
+    def skip(ops):
+        kh, kl, _claim, slot, done, is_new = ops
+        return kh, kl, slot, done, is_new, jnp.zeros(
+            (len(rungs),), jnp.int32
+        )
+
+    kh, kl, slot, done, is_new, sub = jax.lax.cond(
+        jnp.any(~done), hand_off, skip, (kh, kl, claim, slot, done, is_new)
+    )
+    return kh, kl, slot, done, is_new, jnp.concatenate([rounds[None], sub])
+
+
+def _probe_ladder(table: HashSet, claim, hi, lo, active, elect: bool):
+    """Insert-if-absent probing shared by both insert paths: the main
+    stage at the batch width, then the straggler ladder.  Returns ``(kh,
+    kl, slot, done, is_new, stage_rounds)`` with ``stage_rounds`` an
+    int32 vector over :func:`stage_widths` of the batch (0 at a skipped
+    rung); the executed round count is its sum."""
+    rungs = tuple(w for w in STRAGGLER_LADDER if w < hi.shape[0])
+    kh, kl, slot, done, is_new, rounds = _ladder_stage(
+        table.key_hi, table.key_lo, claim, hi, lo,
+        home_slot(hi, lo, table.capacity), ~active, rungs, elect,
+    )
+    skipped = len(STRAGGLER_LADDER) - len(rungs)
+    stage_rounds = jnp.concatenate(
+        [rounds[:1], jnp.zeros((skipped,), jnp.int32), rounds[1:]]
+    )
+    return kh, kl, slot, done, is_new, stage_rounds
 
 
 def unique_buffer_size(b: int, dedup_factor: int) -> int:
@@ -300,11 +387,11 @@ def insert_batch_compact(
     worst-case U (wave_loop.py's ladder): the pre-dedup sort, the probe
     rounds, and every U-sized result buffer then span the rung instead.
 
-    ``with_rounds=True`` appends one more element — the executed probe
-    round count (main rounds + straggler-tail rounds, an int32 scalar) —
-    which the traced engines feed to the roofline's probe-bytes model
-    (obs/roofline.py); the default arity is unchanged so the fused hot
-    paths don't carry an unused readback.
+    ``with_rounds=True`` appends one more element — the probe rounds run
+    at each stage, an int32 vector over :func:`stage_widths` of the
+    buffer, whose sum is the executed round count — which the engines
+    count (``metrics()["dedup_stage_rounds"]``) and the traced loops feed
+    to the roofline's probe-bytes model (obs/roofline.py).
 
     This compact form is the engine hot path: per-chunk candidate batches
     carry ~95% invalid/duplicate lanes, and scattering B-indexed state
@@ -312,48 +399,15 @@ def insert_batch_compact(
     wall-clock — U-sized row scatters make that cost proportional to the
     *distinct* keys instead.
     """
-    capacity = table.capacity
-    mask = _U32(capacity - 1)
-    b = hi.shape[0]
-
-    from .wave_common import compact
-
     u_hi, u_lo, u_origin, u_active, overflow = prededup(
         hi, lo, active, dedup_factor, sort_lanes=sort_lanes
     )
-    u = u_hi.shape[0]
-
-    # Stage 2: probe rounds over the unique buffer.
+    # Stages 2-3 over the unique buffer; distinct keys, so the claim is a
+    # plain set.
     claim = table.key_hi ^ table.key_hi  # varying-typed zero plane
-    u_lane = jnp.arange(u, dtype=_U32)
-    u_slot0 = home_slot(u_hi, u_lo, capacity)
-    tail = min(u, 1 << 14)
-    stop1 = tail if tail < u else 0
-    kh, kl, claim, u_slot, u_done, u_new, rounds = _probe_rounds(
-        table.key_hi, table.key_lo, claim, u_hi, u_lo, u_lane, u_slot0,
-        ~u_active, u_active & ~u_active, mask, capacity,
-        stop_count=jnp.int32(stop1), max_rounds=4 * capacity,
+    kh, kl, u_slot, u_done, u_new, rounds = _probe_ladder(
+        table, claim, u_hi, u_lo, u_active, elect=False
     )
-
-    # Stage 3: straggler tail in a small buffer.
-    if tail < u:
-        undone = ~u_done
-        t_ref = compact(undone, u_lane, tail)
-        t_hi = compact(undone, u_hi, tail)
-        t_lo = compact(undone, u_lo, tail)
-        t_slot = compact(undone, u_slot, tail)
-        t_active = compact(undone, undone, tail)
-        t_lane = jnp.arange(tail, dtype=_U32)
-        kh, kl, _claim, t_slot, t_done, t_new, t_rounds = _probe_rounds(
-            kh, kl, claim, t_hi, t_lo, t_lane, t_slot, ~t_active,
-            t_active & ~t_active, mask, capacity,
-            stop_count=jnp.int32(0), max_rounds=4 * capacity,
-        )
-        rounds = rounds + t_rounds
-        safe_ref = jnp.where(t_active, t_ref, _U32(u))
-        u_slot = u_slot.at[safe_ref].set(t_slot, mode="drop")
-        u_new = u_new.at[safe_ref].set(t_new, mode="drop")
-        u_done = u_done.at[safe_ref].set(t_done, mode="drop")
 
     out = (
         HashSet(kh, kl), u_slot, u_new, u_origin, u_active,
@@ -390,7 +444,7 @@ def insert_batch_claim(
     is structurally False — there is no representative buffer to
     overflow; the caller's :func:`compact_valid` criterion is the only
     capacity gate on this path.  ``with_rounds=True`` appends the
-    executed probe round count, as in :func:`insert_batch_compact`.
+    per-stage probe rounds, as in :func:`insert_batch_compact`.
 
     The one failure mode the election shares with the sorted path is
     probe exhaustion (``probe_ok`` False): the engines raise flag 1 and
@@ -402,47 +456,16 @@ def insert_batch_claim(
     on the election at full width.  Either way a pathological batch
     costs a recompile, never a wrong answer.
     """
-    capacity = table.capacity
-    mask = _U32(capacity - 1)
-    b = hi.shape[0]
-
-    from .wave_common import compact
-
-    lane = jnp.arange(b, dtype=_U32)
-    slot0 = home_slot(hi, lo, capacity)
     # The claim plane, initialized to the all-ones sentinel (no real
     # lane id) so the min-election starts pristine; derived from the
-    # key plane to stay varying-typed under shard_map.
+    # key plane to stay varying-typed under shard_map.  Deep probe chains
+    # and their same-key followers, which move in lockstep and so stay
+    # electable, finish down the straggler ladder.
     claim = (table.key_hi ^ table.key_hi) | _SENTINEL
-    tail = min(b, 1 << 14)
-    stop1 = tail if tail < b else 0
-    kh, kl, claim, slot, done, is_new, rounds = _probe_rounds(
-        table.key_hi, table.key_lo, claim, hi, lo, lane, slot0,
-        ~active, active & ~active, mask, capacity,
-        stop_count=jnp.int32(stop1), max_rounds=4 * capacity, elect=True,
+    kh, kl, slot, done, is_new, rounds = _probe_ladder(
+        table, claim, hi, lo, active, elect=True
     )
-
-    # Straggler tail, exactly the sorted path's: deep probe chains (and
-    # their same-key followers, which move in lockstep and therefore
-    # stay electable) finish in a small buffer.
-    if tail < b:
-        undone = ~done
-        t_ref = compact(undone, lane, tail)
-        t_hi = compact(undone, hi, tail)
-        t_lo = compact(undone, lo, tail)
-        t_slot = compact(undone, slot, tail)
-        t_active = compact(undone, undone, tail)
-        t_lane = jnp.arange(tail, dtype=_U32)
-        kh, kl, _claim, t_slot, t_done, t_new, t_rounds = _probe_rounds(
-            kh, kl, claim, t_hi, t_lo, t_lane, t_slot, ~t_active,
-            t_active & ~t_active, mask, capacity,
-            stop_count=jnp.int32(0), max_rounds=4 * capacity, elect=True,
-        )
-        rounds = rounds + t_rounds
-        safe_ref = jnp.where(t_active, t_ref, _U32(b))
-        slot = slot.at[safe_ref].set(t_slot, mode="drop")
-        is_new = is_new.at[safe_ref].set(t_new, mode="drop")
-        done = done.at[safe_ref].set(t_done, mode="drop")
+    lane = jnp.arange(hi.shape[0], dtype=_U32)
 
     out = (
         HashSet(kh, kl), slot, is_new, lane, active,

@@ -1307,7 +1307,7 @@ class ShardedTpuChecker(Checker):
                 )
             return (
                 table.key_hi, table.key_lo, r_slot, r_new, r_origin,
-                probe_ok[None], dd_overflow[None], rounds[None],
+                probe_ok[None], dd_overflow[None], jnp.sum(rounds)[None],
             )
 
         def append_shard(store, parent, ebits, queue, rw, rg, reb,

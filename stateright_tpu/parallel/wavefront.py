@@ -67,7 +67,26 @@ NO_SLOT_HOST = 0xFFFFFFFF
     STAT_DEPTH,
     STAT_FLAGS,
 ) = range(8)
-STAT_DISC = 8  # disc[P] rides at [STAT_DISC : STAT_DISC + n_props]
+# The dedup probe rounds run() spent at each stage, in
+# hashset.stage_widths order (main stage, then the straggler ladder):
+# counted over the call, zero on the way in.
+STAT_ROUNDS = 8
+STAT_DISC = 12  # disc[P] rides at [STAT_DISC : STAT_DISC + n_props]
+
+
+def host_stats(level_start, level_end, tail, states, unique, depth, disc):
+    """A stats vector in the layout above, built on the host (resume,
+    and the traced and tiered loops' snapshots); flags and probe rounds
+    read 0."""
+    head = [
+        level_start, level_end, tail, states & 0xFFFFFFFF,
+        (states >> 32) & 0xFFFFFFFF, unique, depth,
+    ]
+    return np.concatenate([
+        np.array(head, np.uint32),
+        np.zeros((STAT_DISC - STAT_FLAGS,), np.uint32),
+        np.asarray(disc, np.uint32),
+    ])
 
 # Auto-tune growth bounds: the table's key planes cost 8 bytes a slot
 # (2 GiB at the cap, plus a transient claim plane per insert) and the row
@@ -390,6 +409,8 @@ class TpuChecker(Checker):
         # relax included, and sortless → sort flips.
         self._dedup_rung_climbs = 0
         self._dedup_fallbacks = 0
+        # Dedup probe rounds run at each stage width (metrics()).
+        self._stage_rounds: Dict[int, int] = {}
         # Dedup-path selection (the sortless claim-plane election is the
         # default; an explicit sort_lanes rung selects the sorted
         # fallback path — see the docstring).
@@ -524,11 +545,12 @@ class TpuChecker(Checker):
 
         Carry: (key_hi, key_lo, rows, parent, ebits, level_start,
         level_end, tail, sc_lo, sc_hi, unique_count, depth, disc[P],
-        waves_left, flags).  ``sc_lo``/``sc_hi`` form the 64-bit
-        generated-state counter (no u64 on device).  flag values: 1 = table
-        overfull (probe failure or beyond 50% load); 2 = row log full
-        (unique states exceeded ``log_capacity``); 4 = insert dedup-buffer
-        overflow; 8 = model step kernel capacity overflow.
+        waves_left, flags, probe).  ``sc_lo``/``sc_hi`` form the 64-bit
+        generated-state counter (no u64 on device); ``probe`` counts the
+        call's dedup probe rounds per stage (``STAT_ROUNDS``).  flag
+        values: 1 = table overfull (probe failure or beyond 50% load); 2 =
+        row log full (unique states exceeded ``log_capacity``); 4 = insert
+        dedup-buffer overflow; 8 = model step kernel capacity overflow.
         """
         import jax
         import jax.numpy as jnp
@@ -614,6 +636,7 @@ class TpuChecker(Checker):
                 disc,
                 waves_left,
                 flags,
+                probe,
             ) = carry
 
             count = jnp.minimum(level_end - level_start, jnp.uint32(f_eff))
@@ -675,18 +698,20 @@ class TpuChecker(Checker):
                 # are identical (u_origin is the identity map).
                 (
                     table, _u_slot, u_new, u_origin, _u_active, probe_ok,
-                    dd_overflow,
+                    dd_overflow, stage_rounds,
                 ) = insert_batch_claim(
                     HashSet(key_hi, key_lo), hi, lo, v_act,
+                    with_rounds=True,
                 )
             else:
                 (
                     table, _u_slot, u_new, u_origin, _u_active, probe_ok,
-                    dd_overflow,
+                    dd_overflow, stage_rounds,
                 ) = insert_batch_compact(
                     HashSet(key_hi, key_lo), hi, lo, v_act,
-                    dedup_factor=1,
+                    dedup_factor=1, with_rounds=True,
                 )
+            probe = probe + stage_rounds.astype(jnp.uint32)
             dd_overflow = dd_overflow | v_overflow
             n_new = jnp.sum(u_new, dtype=jnp.uint32)
 
@@ -779,6 +804,7 @@ class TpuChecker(Checker):
                 disc,
                 waves_left - 1,
                 flags,
+                probe,
             )
 
         def wave_cond(carry):
@@ -819,11 +845,12 @@ class TpuChecker(Checker):
                 stats[STAT_DISC : STAT_DISC + len(props)],
                 jnp.int32(waves_per_call),
                 jnp.uint32(0),
+                jnp.zeros((STAT_DISC - STAT_ROUNDS,), jnp.uint32),
             )
             out = jax.lax.while_loop(wave_cond, wave_body, carry)
             stats_out = jnp.concatenate(
                 [jnp.stack([out[5], out[6], out[7], out[8], out[9],
-                            out[10], out[11], out[14]]), out[12]]
+                            out[10], out[11], out[14]]), out[15], out[12]]
             )
             return out[0], out[1], out[2], out[3], out[4], stats_out
 
@@ -877,6 +904,7 @@ class TpuChecker(Checker):
                         jnp.uint32(0),  # depth
                         seed_fail,  # flags (nonzero = seed overflow)
                     ]),
+                    jnp.zeros((STAT_DISC - STAT_ROUNDS,), jnp.uint32),
                     jnp.full((len(props),), NO_SLOT_HOST, jnp.uint32),
                 ]
             )
@@ -1248,26 +1276,12 @@ class TpuChecker(Checker):
                     jnp.asarray(sized(np.asarray(snap["ebits"]), qcap + pad))
                 )
                 disc_np = np.asarray(snap["disc"]).astype(np.uint32)
-                stats = _device_owned(jnp.asarray(
-                    np.concatenate(
-                        [
-                            np.array(
-                                [
-                                    int(snap["level_start"]),
-                                    int(snap["level_end"]),
-                                    int(snap["tail"]),
-                                    int(snap["sc_lo"]),
-                                    int(snap["sc_hi"]),
-                                    int(snap["unique_count"]),
-                                    int(snap["depth"]),
-                                    0,  # flags
-                                ],
-                                np.uint32,
-                            ),
-                            disc_np,
-                        ]
-                    )
-                ))
+                stats = _device_owned(jnp.asarray(host_stats(
+                    int(snap["level_start"]), int(snap["level_end"]),
+                    int(snap["tail"]),
+                    (int(snap["sc_hi"]) << 32) | int(snap["sc_lo"]),
+                    int(snap["unique_count"]), int(snap["depth"]), disc_np,
+                )))
                 with self._lock:
                     self._state_count = (
                         int(snap["sc_hi"]) << 32
@@ -1355,6 +1369,7 @@ class TpuChecker(Checker):
         # host reads travels in the stats vector.
         stats_h = np.asarray(carry[5])
         self._last_stats_h = stats_h
+        self._count_stage_rounds(stats_h[STAT_ROUNDS:STAT_DISC])
         remaining = int(stats_h[STAT_LEVEL_END]) - int(
             stats_h[STAT_LEVEL_START]
         )
@@ -1396,6 +1411,25 @@ class TpuChecker(Checker):
         buffer those waves actually fill.  Queried per quantum because
         auto-grow and both ladders may move the geometry mid-run."""
         return self._wl_full_sort_lanes()
+
+    def _count_stage_rounds(self, stage_rounds) -> int:
+        """Fold one readback of per-stage probe rounds (the insert's
+        ``with_rounds`` vector, or the fused call's ``STAT_ROUNDS``
+        slots) into ``metrics()["dedup_stage_rounds"]``, keyed by the
+        live compaction width's stage widths; returns the rounds run."""
+        from .hashset import stage_widths
+
+        total = 0
+        with self._lock:
+            for width, n in zip(
+                stage_widths(self._sort_width()), stage_rounds.tolist()
+            ):
+                if n:
+                    self._stage_rounds[width] = (
+                        self._stage_rounds.get(width, 0) + int(n)
+                    )
+                    total += int(n)
+        return total
 
     # --- sort-geometry rung (wave_loop.py's ladder) --------------------------
 
@@ -1858,7 +1892,7 @@ class TpuChecker(Checker):
                 # stream), plus the visitor callbacks themselves.
                 n_new = int(np.asarray(n_new_d))
                 generated = int(np.asarray(gen_d))
-                rounds = int(np.asarray(rounds_d))
+                rounds = self._count_stage_rounds(np.asarray(rounds_d))
                 flags = 0
                 if (
                     not bool(np.asarray(probe_ok_d))
@@ -2032,22 +2066,10 @@ class TpuChecker(Checker):
             # for path reconstruction, a carry for save_snapshot, the
             # final checkpoint, and the engine_done journal record.
             self._tables_dev = (parent, rows)
-            stats_final = np.concatenate([
-                np.array(
-                    [
-                        level_start,
-                        level_end,
-                        tail,
-                        self._state_count & 0xFFFFFFFF,
-                        (self._state_count >> 32) & 0xFFFFFFFF,
-                        self._unique_count,
-                        depth,
-                        0,
-                    ],
-                    np.uint32,
-                ),
-                disc_h.astype(np.uint32),
-            ])
+            stats_final = host_stats(
+                level_start, level_end, tail, self._state_count,
+                self._unique_count, depth, disc_h,
+            )
             if self._journal:
                 self._journal.append("trace_summary", **tracer.summary())
             from .wave_loop import finalize_run
@@ -2244,6 +2266,14 @@ class TpuChecker(Checker):
             step_lanes_rung=self._step_lanes or 0,
             dedup_rung_climbs=self._dedup_rung_climbs,
             dedup_fallbacks=self._dedup_fallbacks,
+        )
+        with self._lock:
+            stage_rounds = dict(sorted(self._stage_rounds.items()))
+        out.update(
+            dedup_stage_rounds=stage_rounds,
+            dedup_probe_lanes_total=sum(
+                w * n for w, n in stage_rounds.items()
+            ),
         )
         snap = self._metrics.snapshot()
         # Table load factor: mid-run it is the loop's already-synced

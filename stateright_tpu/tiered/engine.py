@@ -56,6 +56,7 @@ from ..parallel.wavefront import (
     _device_owned,
     _OverflowRetry,
     _resize_flat,
+    host_stats,
 )
 from .cold_store import ColdStore
 
@@ -390,6 +391,7 @@ class TieredTpuChecker(TpuChecker):
             jax.block_until_ready(key_lo)
             t.append(time.perf_counter())
         n_new_hot = int(np.asarray(n_new_d))
+        rounds = self._count_stage_rounds(np.asarray(rounds_d))
         flags = 0
         if (
             not bool(np.asarray(probe_ok_d))
@@ -455,7 +457,7 @@ class TieredTpuChecker(TpuChecker):
                 self._t_trace_last = self._tracer.record_wave(
                     phases,
                     self._traced_wave_bytes(
-                        int(np.asarray(rounds_d)),
+                        rounds,
                         two_phase_capable(self._compiled),
                     ),
                 )
@@ -901,22 +903,10 @@ class TieredTpuChecker(TpuChecker):
     def _stats_np(self) -> np.ndarray:
         """Host bookkeeping in the base engine's stats-vector layout, so
         ``_carry_from`` / snapshots share one npz schema."""
-        return np.concatenate([
-            np.array(
-                [
-                    self._t_level_start,
-                    self._t_level_end,
-                    self._t_tail,
-                    self._t_states & 0xFFFFFFFF,
-                    (self._t_states >> 32) & 0xFFFFFFFF,
-                    self._t_unique,
-                    self._t_depth,
-                    0,
-                ],
-                np.uint32,
-            ),
-            np.asarray(self._t_disc_h, np.uint32),
-        ])
+        return host_stats(
+            self._t_level_start, self._t_level_end, self._t_tail,
+            self._t_states, self._t_unique, self._t_depth, self._t_disc_h,
+        )
 
     def _wl_write_checkpoint(self, carry) -> dict:
         self._write_snapshot(

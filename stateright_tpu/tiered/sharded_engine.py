@@ -318,11 +318,8 @@ class TieredShardedTpuChecker(ShardedTpuChecker):
             states = jax.lax.dynamic_slice(rows2d, (off, u(0)), (seg, w))
             hi, lo = fp_of(states)
             act = jnp.arange(seg, dtype=u) < cnt
-            (
-                table, _slot, _new, _orig, _ra, probe_ok,
-                _dd, _rounds,
-            ) = insert_batch_claim(
-                HashSet(kh, kl), hi, lo, act, with_rounds=True,
+            table, _slot, _new, _orig, _ra, probe_ok, _dd = (
+                insert_batch_claim(HashSet(kh, kl), hi, lo, act)
             )
             return table.key_hi, table.key_lo, probe_ok[None]
 

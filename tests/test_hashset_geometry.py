@@ -448,21 +448,149 @@ def test_claim_election_matches_prededup_representatives():
     assert k_claim == k_sort
 
 
-def test_claim_election_straggler_tail_batch():
-    # Batches past the 16K straggler threshold route unresolved lanes
-    # through the tail buffer; representatives stay the lowest lanes.
-    from stateright_tpu.parallel.hashset import insert_batch_claim
+def _sequential_table(vals, capacity):
+    """A plain sequential insert-if-absent (linear probing from the same
+    home slots) of ``vals``, as numpy key planes."""
+    from stateright_tpu.parallel.hashset import home_slot
 
+    kh = np.zeros(capacity, np.uint32)
+    kl = np.zeros(capacity, np.uint32)
+    hi, lo = (np.asarray(x) for x in _keys(vals))
+    home = np.asarray(home_slot(jnp.asarray(hi), jnp.asarray(lo), capacity))
+    for h, l, s in zip(hi.tolist(), lo.tolist(), home.tolist()):
+        while (kh[s], kl[s]) not in ((0, 0), (h, l)):
+            s = (s + 1) % capacity
+        kh[s], kl[s] = h, l
+    return kh, kl
+
+
+def _ladder_rounds_model(kh, kl, hi, lo, active, capacity):
+    """Per-stage probe rounds of lockstep parallel probing with lowest-lane
+    election, in numpy, in hashset.stage_widths order: the main stage runs
+    while more lanes are unresolved than the first narrower rung holds,
+    each rung while more than the next holds, the last to completion."""
+    from stateright_tpu.parallel.hashset import STRAGGLER_LADDER, home_slot
+
+    kh, kl, hi, lo = (np.array(x) for x in (kh, kl, hi, lo))
+    b = hi.shape[0]
+    slot = np.asarray(home_slot(jnp.asarray(hi), jnp.asarray(lo), capacity))
+    slot = slot.astype(np.int64)
+    done = ~np.asarray(active)
+    lane = np.arange(b)
+    rungs = [w for w in STRAGGLER_LADDER if w < b]
+    per_stage = []
+    for nxt in rungs + [0]:
+        r = 0
+        while (~done).sum() > nxt:
+            cur_h, cur_l = kh[slot], kl[slot]
+            present = (cur_h == hi) & (cur_l == lo)
+            empty = (cur_h == 0) & (cur_l == 0)
+            want = ~done & empty
+            claim = np.full(capacity, b)
+            np.minimum.at(claim, slot[want], lane[want])
+            won = want & (claim[slot] == lane)
+            kh[slot[won]], kl[slot[won]] = hi[won], lo[won]
+            done = done | present | won
+            advance = ~done & ~empty & ~present
+            slot = np.where(advance, (slot + 1) % capacity, slot)
+            r += 1
+        per_stage.append(r)
+    rung_rounds = dict(zip(rungs, per_stage[1:]))
+    return [per_stage[0]] + [rung_rounds.get(w, 0) for w in STRAGGLER_LADDER]
+
+
+def _ladder_batch(kind):
+    """(prefilled keys, batch keys, batch active mask, capacity)."""
     rng = np.random.default_rng(11)
-    n = (1 << 14) + 256
-    vals = rng.integers(1, 3000, size=n).astype(np.uint64)
-    hi, lo = _keys(vals)
-    t, slot, new, _o, _a, ok, _ovf = insert_batch_claim(
-        make_hashset(1 << 13), hi, lo, jnp.ones((n,), jnp.bool_)
+    if kind == "tail":  # just past 16K lanes into an empty table
+        n = (1 << 14) + 256
+        vals = rng.integers(1, 3000, size=n).astype(np.uint64)
+        return np.zeros(0, np.uint64), vals, np.ones(n, bool), 1 << 13
+    # A table at 45% load; the batch mixes present keys with new ones,
+    # each drawn several times.
+    n, capacity = {"all_stages": (40_000, 1 << 17),
+                   "under_16k": (10_000, 1 << 15),
+                   "zero_valid": (40_000, 1 << 17)}[kind]
+    keys = rng.choice(1 << 62, size=int(0.45 * capacity) + n // 2,
+                      replace=False).astype(np.uint64) + np.uint64(1)
+    pre = keys[: int(0.45 * capacity)]
+    pool = np.concatenate([pre[: n // 8], keys[len(pre):]])
+    vals = rng.choice(pool, size=n)
+    return pre, vals, np.full(n, kind != "zero_valid"), capacity
+
+
+@pytest.mark.parametrize("batch", ["tail", "all_stages", "under_16k",
+                                   "zero_valid"])
+@pytest.mark.parametrize("path", ["claim", "compact"])
+def test_claim_election_straggler_tail_batch(path, batch):
+    # Both insert paths down the straggler ladder equal a plain
+    # sequential insert-if-absent: the same key set, ``new`` on exactly
+    # the lowest lane of each new key, every representative's slot
+    # holding its key, and the rounds counted at each stage width.
+    from stateright_tpu.parallel.hashset import (
+        HashSet, STRAGGLER_LADDER, home_slot, insert_batch_claim,
+        insert_batch_compact,
     )
+
+    pre, vals, active, capacity = _ladder_batch(batch)
+    n = len(vals)
+    kh0, kl0 = _sequential_table(pre, capacity)
+    hi, lo = _keys(vals)
+    table = HashSet(jnp.asarray(kh0), jnp.asarray(kl0))
+    if path == "claim":
+        t, slot, new, origin, rep, ok, _ovf, rounds = insert_batch_claim(
+            table, hi, lo, jnp.asarray(active), with_rounds=True
+        )
+    else:
+        t, slot, new, origin, rep, ok, _ovf, rounds = insert_batch_compact(
+            table, hi, lo, jnp.asarray(active), dedup_factor=1,
+            with_rounds=True,
+        )
     assert bool(ok)
-    new = np.asarray(new)
-    first = {}
+    slot, new, origin, rep = (np.asarray(x) for x in (slot, new, origin, rep))
+    if path == "claim":  # per input lane: the representatives are the
+        first = {}       # lowest active lane of each key
+        for i, v in enumerate(vals.tolist()):
+            if active[i]:
+                first.setdefault(v, i)
+        rep = np.zeros(n, bool)
+        rep[list(first.values())] = True
+
+    kh, kl = np.asarray(t.key_hi), np.asarray(t.key_lo)
+    want_kh, want_kl = _sequential_table(
+        np.concatenate([pre, vals[active]]), capacity
+    )
+    stored = set(zip(kh.tolist(), kl.tolist()))
+    assert stored == set(zip(want_kh.tolist(), want_kl.tolist()))
+
+    present = set(pre.tolist())
+    new_lanes = {int(origin[i]) for i in np.flatnonzero(new)}
+    lowest = {}
     for i, v in enumerate(vals.tolist()):
-        first.setdefault(v, i)
-    assert {i for i in range(n) if new[i]} == set(first.values())
+        if active[i] and v not in present:
+            lowest.setdefault(v, i)
+    assert new_lanes == set(lowest.values())
+    for i in np.flatnonzero(rep):
+        v = int(vals[origin[i]])
+        assert (int(kh[slot[i]]) << 32) | int(kl[slot[i]]) == v
+
+    rounds = np.asarray(rounds).tolist()
+    assert len(rounds) == 1 + len(STRAGGLER_LADDER)
+    skipped = [w >= n for w in STRAGGLER_LADDER]
+    assert all(r == 0 for r, s in zip(rounds[1:], skipped) if s)
+    if batch == "zero_valid":
+        assert rounds == [0] * len(rounds)
+    if batch == "all_stages":
+        assert all(r > 0 for r in rounds)
+    if path == "claim":
+        model = _ladder_rounds_model(kh0, kl0, hi, lo, active, capacity)
+        assert rounds == model
+        assert sum(rounds) == sum(model)
+    elif active.any():
+        # Each round moves a lane one slot: the deepest representative
+        # needed at least its displacement plus one.
+        u_hi, u_lo = (np.asarray(x)[origin[rep]] for x in (hi, lo))
+        home = np.asarray(home_slot(jnp.asarray(u_hi), jnp.asarray(u_lo),
+                                    capacity))
+        depth = (slot[rep].astype(np.int64) - home) % capacity
+        assert sum(rounds) >= int(depth.max()) + 1

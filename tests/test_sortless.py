@@ -101,6 +101,29 @@ def test_sortless_traced_bit_identical(tmp_path, reference_fps):
     )
 
 
+def test_dedup_stage_rounds_in_metrics():
+    # 2pc 5 at the default geometry: every wave's valid lanes fit the
+    # 16,384-lane rung, so the main stage hands off at once and that rung
+    # does the first rounds.  The traced loop counts the same rounds.
+    from stateright_tpu.parallel.hashset import STRAGGLER_LADDER
+
+    readings = []
+    for trace in (False, True):
+        ck = TwoPhaseSys(rm_count=5).checker().spawn_tpu(
+            device=_cpu(), trace=trace,
+        ).join()
+        assert ck.unique_state_count() == 8832
+        m = ck.metrics()
+        rounds = m["dedup_stage_rounds"]
+        assert rounds[1 << 14] > 0
+        assert set(STRAGGLER_LADDER) <= set(rounds)
+        assert m["dedup_probe_lanes_total"] == sum(
+            w * n for w, n in rounds.items()
+        )
+        readings.append(rounds)
+    assert readings[0] == readings[1]
+
+
 @pytest.mark.parametrize("shards", [1, 2, 4, 8])
 def test_sortless_sharded_bit_identical(shards, tmp_path, reference_fps):
     ck = _model().checker().spawn_tpu_sharded(
