@@ -41,10 +41,10 @@ def _usage(name: str, spec: "CliSpec") -> str:
     if spec.tpu:
         lines.append(f"  check-tpu [{n_meta}]{net}"
                      " [--supervise] [--checkpoint-dir DIR] [--resume]"
-                     " [--trace] [--sharded[=SHARDS]] [--bucket-slack PCT]"
+                     " [--sharded[=SHARDS]] [--bucket-slack PCT]"
                      " [--sort-lanes N] [--sortless|--no-sortless]"
                      " [--step-lanes N]"
-                     " [--tiered] [--memory-budget-mb MB]"
+                     " [--tiered [--trace]] [--memory-budget-mb MB]"
                      " [--store-dir DIR] [--incremental]"
                      " [--xprof-dir DIR]")
         lines.append(f"  reshard [{n_meta}] IN.npz OUT.npz --shards M{net}")
@@ -1009,7 +1009,7 @@ def example_main(spec: CliSpec, argv=None) -> int:
         print(
             "--tiered --sharded does not combine with --trace (the "
             "composed pod-scale engine has no traced mode; trace the "
-            "single-chip tiered engine or the plain sharded engine)",
+            "single-chip tiered engine)",
             file=sys.stderr,
         )
         return 2
@@ -1042,9 +1042,18 @@ def example_main(spec: CliSpec, argv=None) -> int:
         return 2
     if trace and sub not in ("check-tpu", "spawn"):
         print(
-            "--trace requires the check-tpu subcommand (phase-timed "
-            "device wave tracing) or spawn (the actor runtime's causal "
-            "trace envelope); docs/OBSERVABILITY.md",
+            "--trace requires the check-tpu subcommand with --tiered "
+            "(the tiered engine's phase-timed waves) or spawn (the actor "
+            "runtime's causal trace envelope); docs/OBSERVABILITY.md",
+            file=sys.stderr,
+        )
+        return 2
+    if trace and sub == "check-tpu" and not tiered:
+        print(
+            "check-tpu --trace requires --tiered (the phase-timed "
+            "breakdown of the tiered engine's separately dispatched "
+            "waves); profile the fused wave program with --xprof-dir DIR "
+            "(docs/OBSERVABILITY.md \"Timeline export and profiling\")",
             file=sys.stderr,
         )
         return 2
@@ -1145,11 +1154,6 @@ def example_main(spec: CliSpec, argv=None) -> int:
             tpu_kwargs = dict(spec.tpu_kwargs)
             if ckpt_dir is not None:
                 tpu_kwargs.update(_checkpointed_tpu_kwargs(ckpt_dir, resume))
-            if trace:
-                # Phase-timed wave tracing (docs/OBSERVABILITY.md); with
-                # --checkpoint-dir the enriched wave records land in the
-                # run dir's journal.jsonl — the wave-trace artifact.
-                tpu_kwargs["trace"] = True
             if sort_lanes is not None:
                 # The dedup-sort geometry rung — a knob every device
                 # engine accepts (single-chip, sharded, tiered).
@@ -1210,7 +1214,10 @@ def example_main(spec: CliSpec, argv=None) -> int:
                 # overrides any spec-tuned capacity hint riding along.
                 if memory_budget_mb is not None:
                     tpu_kwargs["memory_budget_mb"] = memory_budget_mb
-                checker = builder.spawn_tpu_tiered(**tpu_kwargs)
+                # --trace: the phase-timed breakdown (docs/
+                # OBSERVABILITY.md); with --checkpoint-dir the enriched
+                # wave records land in the run dir's journal.jsonl.
+                checker = builder.spawn_tpu_tiered(trace=trace, **tpu_kwargs)
             elif store_dir is not None:
                 # Incremental re-checking through the persistent
                 # verification store (docs/INCREMENTAL.md): classify
@@ -1250,8 +1257,8 @@ def example_main(spec: CliSpec, argv=None) -> int:
 
             print("recheck: " + _json.dumps(recheck_info, sort_keys=True))
         if sub == "check-tpu" and trace:
-            # One parseable line with the roofline reduction, so shell
-            # pipelines (and the CI trace smoke) can gate on it without
+            # One parseable line with the tiered run's roofline
+            # reduction, so shell pipelines can gate on it without
             # reading the journal.
             import json as _json
 

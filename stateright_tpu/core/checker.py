@@ -115,12 +115,12 @@ class CheckerBuilder:
         spec (parallel/canon.py) while logging original rows; models
         without a canon spec fail the spawn loudly.
 
-        ``trace=True`` runs the wave loop in phase-timed segments with
-        roofline byte accounting (obs/, docs/OBSERVABILITY.md).  Coarse
-        wave-granularity visitors are supported via the traced readback
-        path: a ``visitor()`` forces tracing on and receives every
-        unique state once, at expansion, as a single-state path — BFS
-        level order across waves, fingerprint-sorted within a level."""
+        A ``visitor()`` runs on the fused wave loop at the granularity
+        of one fused call: after each committed call the row log's
+        newly expanded states are read back, and the visitor receives
+        every unique state once, at expansion, as a single-state path —
+        BFS level order, log order within a level
+        (docs/OBSERVABILITY.md)."""
         self._require("stateright_tpu.parallel.wavefront", "TPU wavefront checker")
         from ..parallel.wavefront import TpuChecker
 
@@ -292,7 +292,8 @@ class Checker:
         """Live observability snapshot — counts every engine has; the
         device engines extend it with their registry (wave cadence,
         table occupancy, device-call time, always-on vitals histograms)
-        and, under ``trace=True``, the roofline trace summary.  Served
+        and, for ``spawn_tpu_tiered(trace=True)``, the roofline trace
+        summary.  Served
         by the Explorer's ``GET /.metrics`` (docs/OBSERVABILITY.md names
         the fields); never blocks on a still-running checker.
 

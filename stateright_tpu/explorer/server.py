@@ -11,8 +11,8 @@ checker, and serves:
 - ``GET /.metrics`` — the checker's live ``metrics()`` snapshot (this
   package's addition; the reference has no metrics surface): counts for
   every engine, plus wave cadence / table occupancy / device-call time
-  for the TPU engines and the roofline trace summary under ``trace=True``
-  (docs/OBSERVABILITY.md);
+  for the TPU engines and, for a traced tiered run, the roofline trace
+  summary (docs/OBSERVABILITY.md);
 - ``GET /.states/{fp1}/{fp2}/...`` — the successor ``StateView`` list for
   the state reached by re-executing the fingerprint path (404 on a bad
   path), each visit nudging the background checker via
@@ -202,8 +202,9 @@ def serve(builder, address, block: bool = True, engine: str = "on_demand",
     if engine == "on_demand":
         checker = builder.visitor(snapshot).spawn_on_demand(**engine_kwargs)
     elif engine == "tpu":
-        # Deliberately NO snapshot visitor: a visitor forces the traced
-        # per-wave loop (docs/OBSERVABILITY.md), which would slow the
+        # Deliberately NO snapshot visitor: a visitor reads every
+        # expanded state back and decodes it on the host after each
+        # fused call (docs/OBSERVABILITY.md), which would slow the
         # exhaustive background run the UI is watching.  The recent-path
         # pane stays empty; live counts come from /.status and /.metrics.
         checker = builder.spawn_tpu(**engine_kwargs)
@@ -258,7 +259,7 @@ def serve_checker(checker, address, block: bool = True, snapshot=None):
                 # checker's metrics() snapshot (counts for every engine;
                 # the device engines add wave cadence, table occupancy,
                 # device-call totals, the always-on vitals histograms,
-                # and — traced — the roofline summary).  JSON by
+                # and — traced tiered runs — the roofline summary).  JSON by
                 # default; ``?format=prometheus`` (or a scraper's
                 # Accept header) selects the standard text exposition
                 # (obs/prometheus.py).  Names: docs/OBSERVABILITY.md.

@@ -157,8 +157,8 @@ class FleetService:
         }
         out.update(view.counters)
         # Gang occupancy: mean jobs per device dispatch.  1.0 means the
-        # batcher never found compatible work; the CPU-gauge bench
-        # phase (bench.py phase_fleet) drives this toward gang_max.
+        # batcher never found compatible work; a queue of compatible
+        # jobs drives it toward gang_max.
         dispatches = view.counters.get("gang_dispatches", 0)
         if dispatches:
             out["gang_occupancy"] = round(

@@ -504,8 +504,8 @@ class TwoPhaseEdited:
     codec, constants, and symmetry hash identical to the stock model
     (the subclasses below inherit ``encode``/``step`` unchanged, so the
     code digests match), only the property component differs.  Used by
-    tests/test_incr.py, the CI incremental smoke, and bench.py's
-    ``recheck`` phase as the canonical near-identical resubmission."""
+    tests/test_incr.py and the CI incremental smoke as the canonical
+    near-identical resubmission."""
 
     @staticmethod
     def build(rm_count: int) -> Model:

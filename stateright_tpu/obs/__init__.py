@@ -1,14 +1,14 @@
 """Observability for the TPU engines: metrics, trace spans, roofline.
 
-Three small pieces, composed by the wavefront engines
-(parallel/wavefront.py, parallel/sharded.py) and surfaced through the
-runtime journal, the Explorer's ``GET /.metrics`` endpoint, the CLI's
-``check-tpu --trace``, and ``bench.py``:
+Small pieces, composed by the wavefront engines (parallel/wavefront.py,
+parallel/sharded.py, tiered/) and surfaced through the runtime journal,
+the Explorer's ``GET /.metrics`` endpoint, and the CLI's ``check-tpu``
+flags (``--xprof-dir``, ``--tiered --trace``):
 
 - :mod:`.metrics` — a thread-safe name->value registry every checker
   carries; counters, gauges, and fixed-boundary histograms (with
   p50/p95/p99 readback) the host loop updates from the scalars it
-  already reads back (no extra device syncs with ``trace=False``).
+  already reads back (no extra device syncs).
 - :mod:`.prometheus` — the standard text exposition of any metrics
   dict (``GET /.metrics?format=prometheus`` on the Explorer and the
   checking service) plus a minimal validating parser for CI.
@@ -16,10 +16,11 @@ runtime journal, the Explorer's ``GET /.metrics`` endpoint, the CLI's
   breakdown, bottleneck_phase, throughput curve, restart timeline, job
   spans) and the cross-round ``BENCH_r*.json`` trajectory with
   regression flagging; backs the ``report`` CLI verb.
-- :mod:`.trace` — per-wave phase-timed trace spans: with ``trace=True``
-  the engines run the wave loop in separately-dispatched phase programs
-  (step kernel / canon+fingerprint / dedup-sort+probe / exchange /
-  append / host readback) and record seconds + modeled bytes per phase.
+- :mod:`.trace` — per-wave phase-timed trace spans: the tiered engine
+  runs its waves as separately-dispatched phase programs (step kernel /
+  canon+fingerprint / dedup-sort+probe / cold probe / append / host
+  readback), and with ``trace=True`` records seconds + modeled bytes
+  per phase.
 - :mod:`.roofline` — the per-device-peak table and the bytes-touched
   model that reduce a wave's phase records into ``hbm_util_frac``
   (fraction of the device's peak HBM bandwidth the wave achieved).

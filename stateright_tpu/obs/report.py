@@ -16,8 +16,8 @@ answers instead of raw lines:
   round of the same metric — the cross-round story that previously
   lived in seven disconnected files;
 - :func:`render_markdown` / :func:`render_trajectory_markdown` emit the
-  human-readable form (``docs/BENCH_TRAJECTORY.md`` is generated by the
-  trajectory renderer via ``bench.py``);
+  human-readable form of a journal report and of the ``BENCH_r*.json``
+  trajectory;
 - :func:`report_main` backs the ``report`` CLI verb (cli.py):
   ``python -m stateright_tpu.models.<any> report <journal|bench-glob>``.
 

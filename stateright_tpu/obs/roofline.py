@@ -24,9 +24,9 @@ choices, documented here because `hbm_util_frac` inherits them:
   serialization cost of scatter shows up as *time*, which the measured
   denominator already carries;
 - phase wall-times come from ``block_until_ready`` around each phase
-  dispatch, so they include per-dispatch launch overhead — with
-  ``trace=True`` the loop is deliberately un-fused, and utilization reads
-  LOWER than the fused ``trace=False`` loop achieves.  The breakdown's
+  dispatch, so they include per-dispatch launch overhead — the tiered
+  loop is un-fused, and its utilization reads LOWER than the in-HBM
+  engine's fused loop achieves.  The breakdown's
   *relative* shape is the signal; docs/OBSERVABILITY.md discusses the
   bias.
 

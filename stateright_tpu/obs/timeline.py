@@ -6,15 +6,16 @@ export and profiling"):
 - :class:`SpanRecorder` — the zero-new-readback span layer the fused
   host loop (parallel/wave_loop.py) threads through its per-quantum
   tail: every named sub-phase (``journal`` append, ``checkpoint``
-  write, tiered ``spill`` drain, sort/step ``retune``, overflow
-  ``grow``, the previous record's own ``flush`` write) is timed with
-  two ``time.monotonic()`` calls and journaled as ONE ``host_span``
+  write, the ``after_commit`` hook — a tiered spill or the visitor
+  stream —, sort/step ``retune``, overflow ``grow``, the previous
+  record's own ``flush`` write) is timed with two
+  ``time.monotonic()`` calls and journaled as ONE ``host_span``
   event per quantum plus per-phase ``host_<phase>_sec`` histograms —
   so ``host_sec_total`` decomposes into named parts.  Engines report
   in-call host work (the ``readback`` decode, the tiered engine's
   ``cold_probe`` windowing) through the same record under
-  ``call_spans``.  No device traffic anywhere: the trace=False fused
-  program stays byte-for-byte pinned.
+  ``call_spans``.  No device traffic anywhere: the fused program stays
+  byte-for-byte pinned.
 
 - :func:`export_timeline` — fold any run / serve / fleet journal
   (or several) into Chrome trace-event JSON loadable in Perfetto /
@@ -53,7 +54,9 @@ SPAN_EVENT = "host_span"
 # the residual ``other`` and the previous record's own ``flush`` write —
 # to the quantum's ``host_sec`` gap, the same gap LoopVitals accounts
 # into ``host_sec_total``).
-TAIL_PHASES = ("journal", "spill", "retune", "checkpoint", "grow", "other")
+TAIL_PHASES = (
+    "journal", "after_commit", "retune", "checkpoint", "grow", "other",
+)
 # Spans measured INSIDE the device-call window (host-observed, but part
 # of ``device_call_sec_total``, not the host tail): the stats readback
 # decode and the tiered engine's host-side cold windowing.

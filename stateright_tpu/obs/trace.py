@@ -1,14 +1,15 @@
-"""Per-wave phase-timed trace spans for the wavefront engines.
+"""Per-wave phase-timed trace spans for the tiered engine.
 
-With ``trace=True`` an engine runs each wave as separately-dispatched
-phase programs and hands the tracer one ``(phase -> seconds, phase ->
+The tiered engine (tiered/engine.py) runs each wave as separately
+dispatched phase programs; with ``spawn_tpu_tiered(trace=True)`` it
+times each phase and hands the tracer one ``(phase -> seconds, phase ->
 bytes)`` record per wave.  The tracer:
 
 - enriches the engine's journal ``wave`` event with ``wave_breakdown``
   (seconds per phase), ``bytes`` (modeled bytes touched per phase) and
   ``hbm_util_frac`` for that wave;
 - accumulates run totals, reduced by :meth:`WaveTracer.summary` into the
-  shape ``bench.py`` and ``Checker.metrics()`` emit.
+  shape ``trace_summary()`` and ``metrics()`` report.
 
 Phase names are part of the observable surface (docs/OBSERVABILITY.md):
 
@@ -19,11 +20,11 @@ Phase names are part of the observable surface (docs/OBSERVABILITY.md):
                       fingerprinting of the candidate buffer
 ``dedup``             sort pre-dedup + claim-plane probe rounds + table
                       insert (parallel/hashset.py)
-``exchange``          owner bucketing + the packed all_to_all (sharded
-                      engine only; elided on a 1-shard mesh)
 ``append``            row/parent/ebits block appends at the log tail
-``readback``          host-side scalar sync + (visitor runs) the chunk
-                      state transfer — host time, excluded from HBM util
+``cold_probe``        the pre-commit merge-join against the evicted runs
+                      (host windowing + device filter) — host-classed
+``readback``          host-side scalar sync — host time, excluded from
+                      HBM util
 ====================  =======================================================
 """
 
@@ -34,17 +35,16 @@ from typing import Dict, Optional
 
 from .roofline import hbm_util_frac, peaks_for_device
 
-# Canonical display order; engines may omit phases they don't have.
-# ``cold_probe`` is the tiered engines' pre-commit merge-join against
-# the evicted runs (host searchsorted + device window filter).
+# Canonical display order.  ``cold_probe`` is the tiered engine's
+# pre-commit merge-join against the evicted runs (host searchsorted +
+# device window filter).
 PHASE_ORDER = (
-    "step", "canon", "dedup", "exchange", "cold_probe", "append",
-    "readback",
+    "step", "canon", "dedup", "cold_probe", "append", "readback",
 )
 
 # Host-side phases: excluded from the HBM-utilization denominator (they
 # are not device time) but included in wave/call wall time.  Public so
-# consumers picking a "bottleneck" phase (bench.py) can exclude the
+# consumers picking a "bottleneck" phase (obs/report.py) can exclude the
 # trace instrumentation's own cost the same way.
 HOST_PHASES = frozenset({"readback", "cold_probe"})
 _HOST_PHASES = HOST_PHASES
@@ -78,7 +78,7 @@ class WaveTracer:
         """Fold one wave's record into the totals; returns the journal
         enrichment for that wave (``wave_breakdown`` / ``bytes`` /
         ``hbm_util_frac``).  ``extra_counters`` accumulate into the
-        summary (e.g. the sharded engine's per-wave exchange payload)."""
+        summary (e.g. a wave's probe rounds)."""
         bytes_touched = bytes_touched or {}
         with self._lock:
             self.waves += 1

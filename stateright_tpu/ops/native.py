@@ -209,9 +209,9 @@ class NativeFpSet:
 def twophase_bfs_native(n_rms: int, max_unique: int = 0) -> Optional[dict]:
     """Exhaustive single-threaded C++ BFS of the direct two-phase-commit
     model (native/stateright_core.cpp: packed successor generation +
-    fingerprint + open-addressing dedup, NO property evaluation) — the
-    honest-denominator hot loop bench.py's ``denominator_native`` phase
-    measures.  Returns ``{"unique_states", "generated", "max_depth"}``,
+    fingerprint + open-addressing dedup, NO property evaluation) — an
+    independent host baseline for the device engines' 2pc counts.
+    Returns ``{"unique_states", "generated", "max_depth"}``,
     None if the native core is unavailable.  Raises on bad arguments or
     a blown ``max_unique`` memory guard (0 = unlimited)."""
     lib = load()

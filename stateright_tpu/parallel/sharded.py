@@ -118,7 +118,6 @@ class ShardedTpuChecker(Checker):
         checkpoint_path: Optional[str] = None,
         checkpoint_every_waves: Optional[int] = None,
         checkpoint_every_sec: Optional[float] = None,
-        trace: bool = False,
         bucket_slack: Optional[int] = None,
         sort_lanes: Optional[int] = None,
         sortless: Optional[bool] = None,
@@ -132,17 +131,6 @@ class ShardedTpuChecker(Checker):
         run.  A sharded snapshot is bound to the MESH SIZE (global ids
         encode the owner shard), but adopts the snapshot's per-shard
         capacity and chunk geometry as data.
-
-        ``trace``: run the wave loop in phase-timed segments (step /
-        canon+fp / dedup-sort+probe / exchange / append / host
-        readback), one host sync per wave, with roofline byte accounting
-        per phase AND the exchange instrumented live — measured payload
-        bytes and lane occupancy PER WAVE in the journal (the fused
-        loop only totals them at run end).  Same kernels and commit
-        order as the fused loop; throughput is not comparable (per-wave
-        dispatch+sync).  ``trace=False`` leaves the fused single-program
-        path byte-for-byte unchanged.  Traced runs do not support
-        ``resume_from``; docs/OBSERVABILITY.md states the contract.
 
         ``bucket_slack``: per-destination exchange bucket width, in
         PERCENT of the even share ``u_sz/n`` (wave_loop.py's
@@ -190,13 +178,6 @@ class ShardedTpuChecker(Checker):
 
         if options._visitor is not None:
             raise ValueError("spawn_tpu_sharded() does not support visitors")
-        self._trace = bool(trace)
-        if self._trace and resume_from is not None:
-            raise ValueError(
-                "spawn_tpu_sharded(trace=True) does not support "
-                "resume_from: tracing is a diagnostic mode; resume "
-                "untraced and trace a fresh (bounded) run instead"
-            )
         self._options = options
         self._compiled = compiled or compiled_model_for(options.model)
         # Symmetry: dedup — and therefore OWNER ROUTING — keys on the
@@ -344,7 +325,6 @@ class ShardedTpuChecker(Checker):
         from ..obs.metrics import MetricsRegistry
 
         self._metrics = MetricsRegistry()
-        self._tracer = None  # built by the traced host loop
         from ..runtime.journal import as_journal
 
         self._journal = as_journal(journal)
@@ -398,8 +378,8 @@ class ShardedTpuChecker(Checker):
     def _bucket_lanes(self) -> int:
         """Per-destination exchange bucket width at the CURRENT slack
         rung — the one source of truth (wave_loop.exchange_bucket_lanes)
-        shared by the device programs, the traced byte model, and
-        ``accounting()``, so reported payload geometry can never drift
+        shared by the device programs (this engine's and the
+        tiered-sharded engine's) and ``accounting()``, so reported payload geometry can never drift
         from what the device transmits.  Sized from the SORT width (the
         buffer the exchange actually buckets), so the dedup rung shrinks
         transmitted bytes too; the cap at the full sort buffer keeps the
@@ -731,7 +711,7 @@ class ShardedTpuChecker(Checker):
             # batch) but stays wired into the FATAL flag 64 below: if
             # the sizing rule ever changes, dropped received states must
             # be a loud error, never a silently wrong "verified" result
-            # (the traced loop keeps the same invariant guard).
+            # (the tiered-sharded loop keeps the same invariant guard).
             if sortless:
                 # Claim-plane election (hashset.insert_batch_claim):
                 # the receive batch probes directly, winners are the
@@ -1096,661 +1076,10 @@ class ShardedTpuChecker(Checker):
         finally:
             self._done.set()
 
-    # --- traced (phase-timed) mode -------------------------------------------
-
-    def _traced_programs(self):
-        """Phase-program set for ``trace=True``, cached like the fused
-        program.  Host-driven knobs (waves, finish_when, depth gating)
-        are not baked in — the traced loop decides them per wave."""
-        key = (
-            "traced",
-            self._compiled.cache_key(),
-            hasattr(self._compiled, "step_valid")
-            and hasattr(self._compiled, "step_lane"),
-            self._canon is not None,
-            self._cap_s,
-            self._chunk,
-            self._dedup_factor,
-            self._sortless,  # the dedup path is a trace-time branch
-            self._sort_width(),  # the live sort-geometry rung
-            self._step_width(),  # the live step-geometry rung
-            self._bucket_slack,  # shapes the exchange buckets
-            tuple((d.platform, d.id) for d in self._mesh.devices.flat),
-            tuple(p.expectation for p in self._properties),
-        )
-        from .wave_common import cached_program
-
-        return cached_program(
-            _PROGRAM_CACHE, _PROGRAM_CACHE_MAX, key, self._build_traced,
-            label="ShardedTpuChecker.traced",
-            journal=self._journal,
-            provenance=self._key_provenance(),
-        )
-
-    def _build_traced(self):
-        """The sharded wave as six separately-dispatched shard_map phase
-        programs — the same kernels as the fused ``body``, cut at the
-        roofline's phase boundaries (step kernel / canon+fp / local
-        dedup-sort / exchange / table insert / append), with level and
-        termination bookkeeping moved to the host (per-shard control
-        scalars ride a tiny uploaded ctrl vector; all cross-shard
-        reductions become host sums over per-shard outputs, so the only
-        collective left is the all_to_all itself)."""
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
-        from ..ops.device_fp import device_fp64
-        from .hashset import (
-            HashSet, compact_valid_indices, insert_batch_claim,
-            insert_batch_compact, prededup,
-        )
-        from .wave_common import wave_eval
-
-        cm = self._compiled
-        w = cm.state_width
-        fpw = cm.fp_words or w
-        canon = self._canon
-
-        def fp_of(rows):
-            rows_c = rows if canon is None else jax.vmap(canon)(rows)
-            return device_fp64(rows_c[:, :fpw])
-
-        a = cm.max_actions
-        f_eff = self._step_width()  # the live step-geometry rung
-        n = self._n
-        cap_s = self._cap_s
-        qcap = cap_s
-        slot_bits = self._slot_bits
-        props = self._properties
-        ev_indices = self._ev_indices
-        dedup_factor = self._dedup_factor
-        sortless = self._sortless  # the dedup path (claim vs sort)
-        sort_lanes = (
-            None if self._sort_lanes is None else self._sort_width()
-        )
-        b = f_eff * a
-        bkt = self._bucket_lanes()  # per-destination exchange bucket
-        u = jnp.uint32
-        shard = P("shards")
-
-        def sharded(fn, n_in, donate=()):
-            return jax.jit(
-                jax.shard_map(
-                    fn, mesh=self._mesh,
-                    in_specs=(shard,) * n_in, out_specs=shard,
-                ),
-                donate_argnums=donate,
-            )
-
-        def step_shard(store, ebits, queue, disc, ctrl):
-            me = jax.lax.axis_index("shards").astype(u)
-            level_start = ctrl[0, 0]
-            level_end = ctrl[0, 1]
-            count = jnp.minimum(level_end - level_start, u(f_eff))
-            chunk = jax.lax.dynamic_slice(queue, (level_start,), (f_eff,))
-            lane = jnp.arange(f_eff, dtype=u)
-            active = lane < count
-            safe_slots = jnp.where(active, chunk, 0)
-            states = store[safe_slots]
-            my_gids = (me << u(slot_bits)) | safe_slots
-            disc_v, eb, nexts, valid, gen_local, step_flag = wave_eval(
-                cm, props, ev_indices, states, active, my_gids,
-                ebits[safe_slots], disc[0], allow_two_phase=True,
-            )
-            flat_valid = valid.reshape(b)
-            v_orig, v_act, _n_valid, local_overflow = compact_valid_indices(
-                flat_valid, dedup_factor, sort_lanes=sort_lanes
-            )
-            if nexts is None:
-                # Two-phase: construct successors only for the compacted
-                # valid lanes (the fused body's phase B).
-                rows_v, _vv, lane_flags_v = jax.vmap(cm.step_lane)(
-                    states[v_orig // u(a)], v_orig % u(a)
-                )
-                step_flag = step_flag | jnp.any(lane_flags_v & v_act)
-            else:
-                rows_v = nexts.reshape(b, w)[v_orig]
-            gid_v = my_gids[v_orig // u(a)]
-            eb_v = eb[v_orig // u(a)]
-            return (
-                disc_v[None], rows_v, gid_v, eb_v, v_act,
-                local_overflow[None], gen_local.astype(u)[None],
-                step_flag[None],
-            )
-
-        def canon_shard(rows_v):
-            hi, lo = fp_of(rows_v)
-            return hi, lo
-
-        def prededup_shard(hi, lo, rows_v, gid_v, eb_v, v_act):
-            if sortless and n == 1:
-                # 1-shard sortless: the claim insert IS the global
-                # dedup — this phase is the identity (≈0 s in the
-                # breakdown), exactly the fused body's elision.
-                n_cand = jnp.sum(v_act, dtype=u)
-                return hi, lo, rows_v, gid_v, eb_v, v_act, n_cand[None]
-            # dd=1 over the already-compacted buffer, exactly the fused
-            # body's local pre-dedup: representatives in sorted key
-            # order, one lane per distinct local key.
-            u_hi, u_lo, u_origin0, u_valid, _never = prededup(
-                hi, lo, v_act, dedup_factor=1
-            )
-            rows_u = rows_v[u_origin0]
-            gid_u = gid_v[u_origin0]
-            eb_u = eb_v[u_origin0]
-            n_cand = jnp.sum(u_valid, dtype=u)
-            return u_hi, u_lo, rows_u, gid_u, eb_u, u_valid, n_cand[None]
-
-        def exchange_shard(u_hi, u_lo, rows_u, gid_u, eb_u, u_valid):
-            # Bucket by owner + the single packed all_to_all (the fused
-            # body's BUCKETED exchange block), plus the receiver-side
-            # re-fingerprint of the arrived rows — charged to this phase
-            # because it only exists when an exchange happened.  The
-            # per-shard bucket-overflow flag rides back so the host can
-            # abort BEFORE the insert/append phases commit anything and
-            # retry the wave at the next slack rung (the fused loop's
-            # contract, one wave later here because the host drives).
-            u_sz = u_hi.shape[0]
-            owner = _owner_mix(u_hi, u_lo) % u(n)
-            key = jnp.where(u_valid, owner, u(n))
-            order = jnp.argsort(key, stable=True)
-            key_s = key[order]
-            counts = jnp.stack(
-                [jnp.sum((key == u(d)).astype(u)) for d in range(n + 1)]
-            )
-            bucket_ovf = jnp.any(counts[:n] > u(bkt))
-            offsets = jnp.concatenate(
-                [jnp.zeros((1,), u), jnp.cumsum(counts)[:-1]]
-            )
-            pos = jnp.arange(u_sz, dtype=u) - offsets[key_s]
-            dst = jnp.where(key_s < n, key_s, u(n))
-            payload = jnp.concatenate(
-                [
-                    rows_u,
-                    gid_u[:, None],
-                    eb_u[:, None],
-                    u_valid.astype(u)[:, None],
-                ],
-                axis=1,
-            )
-            send = jnp.zeros((n, bkt, w + 3), u)
-            send = send.at[dst, pos].set(payload[order], mode="drop")
-            recv = jax.lax.all_to_all(
-                send, "shards", split_axis=0, concat_axis=0, tiled=False
-            )
-            flatrecv = recv.reshape(n * bkt, w + 3)
-            rw = flatrecv[:, :w]
-            rhi, rlo = fp_of(rw)
-            return (
-                rw, flatrecv[:, w], flatrecv[:, w + 1],
-                flatrecv[:, w + 2], rhi, rlo, bucket_ovf[None],
-            )
-
-        def insert_shard(key_hi, key_lo, rhi, rlo, rv):
-            if sortless:
-                (
-                    table, r_slot, r_new, r_origin, _ra, probe_ok,
-                    dd_overflow, rounds,
-                ) = insert_batch_claim(
-                    HashSet(key_hi, key_lo), rhi, rlo,
-                    rv.astype(jnp.bool_), with_rounds=True,
-                )
-            else:
-                (
-                    table, r_slot, r_new, r_origin, _ra, probe_ok,
-                    dd_overflow, rounds,
-                ) = insert_batch_compact(
-                    HashSet(key_hi, key_lo), rhi, rlo,
-                    rv.astype(jnp.bool_), dedup_factor=1,
-                    with_rounds=True,
-                )
-            return (
-                table.key_hi, table.key_lo, r_slot, r_new, r_origin,
-                probe_ok[None], dd_overflow[None], jnp.sum(rounds)[None],
-            )
-
-        def append_shard(store, parent, ebits, queue, rw, rg, reb,
-                         r_slot, r_new, r_origin, ctrl):
-            tail = ctrl[0, 0]
-            rows_r = rw[r_origin]
-            sslot = jnp.where(r_new, r_slot, u(cap_s))
-            store = store.at[sslot].set(rows_r, mode="drop")
-            parent = parent.at[sslot].set(rg[r_origin], mode="drop")
-            ebits = ebits.at[sslot].set(reb[r_origin], mode="drop")
-            n_new = jnp.sum(r_new, dtype=u)
-            qpos = tail + jnp.cumsum(r_new.astype(u)) - 1
-            # Always-OOB drop sentinel (not qcap+f): growth may halve
-            # the chunk mid-run while the queue keeps its minted length.
-            qidx = jnp.where(r_new, qpos, u(0xFFFFFFFF))
-            queue = queue.at[qidx].set(r_slot, mode="drop")
-            return store, parent, ebits, queue, n_new[None]
-
-        return {
-            "step": sharded(step_shard, 5),
-            "canon": sharded(canon_shard, 1),
-            "prededup": sharded(prededup_shard, 6),
-            "exchange": sharded(exchange_shard, 6),
-            "insert": sharded(insert_shard, 5, donate=(0, 1)),
-            "append": sharded(append_shard, 11, donate=(0, 1, 2, 3)),
-        }
-
-    def _traced_wave_bytes(self, probe_rounds: int, two_phase: bool) -> dict:
-        """Modeled PER-SHARD HBM bytes for one traced wave (each shard
-        streams the same fixed-width buffers in parallel, so per-shard
-        bytes over measured wall time is per-device bandwidth;
-        obs/roofline.py documents the model)."""
-        from ..obs.roofline import copy_bytes, probe_bytes, sort_bytes
-
-        cm = self._compiled
-        w = cm.state_width
-        fpw = cm.fp_words or w
-        n = self._n
-        f_eff = self._step_width()  # the live step rung (bytes.step)
-        b = f_eff * cm.max_actions
-        # The LIVE sort rung, not the worst-case unique_buffer_size:
-        # bytes.dedup drops in proportion to the rung — the ladder's
-        # regression gauge (docs/OBSERVABILITY.md).
-        u_sz = self._sort_width()
-        bkt = self._bucket_lanes()
-        recv = n * bkt if n > 1 else u_sz  # post-exchange insert lanes
-        step = copy_bytes(f_eff, w) + b * 4 + copy_bytes(u_sz, w)
-        if not two_phase:
-            step += b * w * 4
-        canon = (copy_bytes(u_sz, w) if self._canon is not None else 0)
-        canon += u_sz * fpw * 4 + 2 * u_sz * 4
-        if self._sortless:
-            # Claim-path dedup: the owner-side insert probes (no sort);
-            # the local prededup sort survives only on n>1 meshes (the
-            # exchange ships distinct keys) and is elided at n == 1.
-            dedup = probe_bytes(recv, probe_rounds) + 2 * recv * 4
-            if n > 1:
-                dedup += (
-                    sort_bytes(u_sz, 3) + 4 * u_sz * 4
-                    + copy_bytes(u_sz, w)
-                )
-        else:
-            dedup = (
-                sort_bytes(u_sz, 3) + 4 * u_sz * 4 + copy_bytes(u_sz, w)
-                + sort_bytes(recv, 3)
-                + probe_bytes(recv, probe_rounds) + 4 * recv * 4
-            )
-        exchange = 0
-        if n > 1:
-            # send-buffer scatter + the a2a move (in and out) of the
-            # BUCKETED [n, bkt, W+3] payload + the receiver-side
-            # re-fingerprint.
-            exchange = (
-                3 * n * bkt * (w + 3) * 4
-                + recv * fpw * 4 + 2 * recv * 4
-            )
-        append = copy_bytes(recv, w) + 2 * copy_bytes(recv, 1) + recv * 4
-        return {
-            "step": step, "canon": canon, "dedup": dedup,
-            "exchange": exchange, "append": append,
-        }
-
-    def _check_traced(self) -> None:
-        """The ``trace=True`` host loop: one wave per iteration, six
-        phase dispatches timed with ``block_until_ready``, per-shard
-        control scalars driven from the host, and the exchange measured
-        live — payload bytes and lane occupancy per wave in the journal.
-        Overflows raise (no growth path exists in this engine anyway);
-        results match the fused loop exactly."""
-        import time as _time
-
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        opts = self._options
-        cm = self._compiled
-        props = self._properties
-        n = self._n
-        f = self._chunk
-        f_eff = self._step_width()  # the live step-geometry rung
-        cap_s = self._cap_s
-        qcap = cap_s
-        w = cm.state_width
-        deadline = (
-            _time.monotonic() + opts._timeout
-            if opts._timeout is not None else None
-        )
-        from ..obs.trace import WaveTracer
-        from .wave_common import two_phase_capable
-
-        two_phase = two_phase_capable(cm)
-
-        bkt = self._bucket_lanes()
-        tracer = WaveTracer(
-            self._mesh.devices.flat[0], f"tpu-sharded-{n}"
-        )
-        self._tracer = tracer
-        shard = NamedSharding(self._mesh, P("shards"))
-        k_stats = S_DISC + len(props)
-        target_depth = opts._target_max_depth or 0
-
-        progs = self._traced_programs()
-        key_hi, key_lo, store, parent, ebits, queue, stats = (
-            self._seed_initial(shard)
-        )
-        stats_h = np.asarray(stats).reshape(n, k_stats).astype(np.int64)
-        if int(stats_h[0, S_FLAGS]) & 16:
-            raise RuntimeError(
-                "init-state seeding overflowed the insert buffers; "
-                "raise capacity or lower dedup_factor"
-            )
-        level_start = np.zeros(n, np.int64)
-        level_end = stats_h[:, S_LEVEL_END].copy()
-        tails = stats_h[:, S_TAIL].copy()
-        unique_l = stats_h[:, S_UNIQUE_L].copy()
-        cand_total = np.zeros(n, np.int64)
-        depth = 0
-        disc = jax.device_put(
-            jnp.asarray(np.full((n, len(props)), NO_GID, np.uint32)), shard
-        )
-        disc_h = np.asarray(disc).reshape(n, len(props))
-        waves = 0
-        # Always-on vitals (latency histogram, uniq/s EMA, density,
-        # grow counters) — same registry keys as the fused loop's.
-        from .wave_loop import LoopVitals, journal_geometry
-
-        vitals = LoopVitals(
-            self._metrics, initial_unique=self._unique_count,
-            initial_states=self._state_count,
-        )
-        journal_geometry(self)
-
-        while int((level_end - level_start).sum()) > 0:
-            if target_depth and depth >= target_depth - 1:
-                break
-            if (
-                f_eff < f
-                and int((level_end - level_start).max()) > f_eff
-            ):
-                # Step-rung clamp (flag 128): pure host data here, so
-                # the climb happens BEFORE the wave is even dispatched
-                # — same non-committing semantics as the fused flag.
-                if self._grow_knobs(128) is None:
-                    raise RuntimeError(self._wl_overflow_message(128))
-                f_eff = self._step_width()
-                bkt = self._bucket_lanes()
-                progs = self._traced_programs()
-                vitals.record_overflow_recovery()
-                continue
-            counts = np.minimum(level_end - level_start, f_eff)
-            ctrl = jax.device_put(
-                jnp.asarray(
-                    np.stack([level_start, level_end], axis=1)
-                    .astype(np.uint32)
-                ),
-                shard,
-            )
-            t0 = _time.perf_counter()
-            disc_before = disc  # restored on a retryable-overflow re-run
-            # xprof hook (obs/timeline.py): under --xprof-dir the wave's
-            # device phases land in a StepTraceAnnotation so hardware
-            # profiles align with journal wave events; nullcontext
-            # otherwise.
-            from ..obs.timeline import step_annotation
-            _step_ann = step_annotation(waves)
-            _step_ann.__enter__()
-            (
-                disc, rows_v, gid_v, eb_v, v_act, local_ovf_d, gen_d,
-                stepflag_d,
-            ) = progs["step"](store, ebits, queue, disc, ctrl)
-            jax.block_until_ready(rows_v)
-            t1 = _time.perf_counter()
-            hi_v, lo_v = progs["canon"](rows_v)
-            jax.block_until_ready(lo_v)
-            t2 = _time.perf_counter()
-            (
-                u_hi, u_lo, rows_u, gid_u, eb_u, u_valid, n_cand_d,
-            ) = progs["prededup"](hi_v, lo_v, rows_v, gid_v, eb_v, v_act)
-            jax.block_until_ready(u_valid)
-            t3 = _time.perf_counter()
-            if n > 1:
-                rw, rg, reb, rv, rhi, rlo, ovf_d = progs["exchange"](
-                    u_hi, u_lo, rows_u, gid_u, eb_u, u_valid
-                )
-                jax.block_until_ready(rlo)
-            else:
-                # 1-shard mesh: every owner is self — elide the whole
-                # exchange, like the fused program.
-                rw, rg, reb, rv, rhi, rlo = (
-                    rows_u, gid_u, eb_u, u_valid, u_hi, u_lo
-                )
-                ovf_d = None
-            t4 = _time.perf_counter()
-            # Retryable-overflow gate BEFORE the insert/append phases,
-            # so an overflowing wave commits nothing (the fused loop's
-            # contract): grow the tripped knob in place, rebuild the
-            # phase programs at the new shapes, and re-run this wave —
-            # its inputs (store/queue/level bounds, and ``disc``, which
-            # is restored) are untouched by growth.
-            retry_flags = 0
-            if bool(np.asarray(local_ovf_d).any()):
-                retry_flags |= 4
-            if ovf_d is not None and bool(np.asarray(ovf_d).any()):
-                retry_flags |= 32
-            if retry_flags:
-                _step_ann.__exit__(None, None, None)
-                if self._grow_knobs(retry_flags) is None:
-                    raise RuntimeError(
-                        self._wl_overflow_message(retry_flags)
-                    )
-                disc = disc_before
-                f = self._chunk  # dedup growth may halve it
-                f_eff = self._step_width()
-                bkt = self._bucket_lanes()
-                progs = self._traced_programs()
-                vitals.record_overflow_recovery()
-                continue
-            (
-                key_hi, key_lo, r_slot, r_new, r_origin, probe_ok_d,
-                dd_ovf_d, rounds_d,
-            ) = progs["insert"](key_hi, key_lo, rhi, rlo, rv)
-            jax.block_until_ready(key_lo)
-            t5 = _time.perf_counter()
-            tailctrl = jax.device_put(
-                jnp.asarray(tails[:, None].astype(np.uint32)), shard
-            )
-            store, parent, ebits, queue, n_new_d = progs["append"](
-                store, parent, ebits, queue, rw, rg, reb, r_slot,
-                r_new, r_origin, tailctrl,
-            )
-            jax.block_until_ready(queue)
-            _step_ann.__exit__(None, None, None)
-            t6 = _time.perf_counter()
-            # Host readback: the per-wave scalar sync.
-            n_new = np.asarray(n_new_d).astype(np.int64)
-            gen_h = np.asarray(gen_d).astype(np.int64)
-            n_cand = np.asarray(n_cand_d).astype(np.int64)
-            rounds = int(np.asarray(rounds_d).max())
-            disc_h = np.asarray(disc).reshape(n, len(props))
-            flags = 0
-            if (
-                not bool(np.asarray(probe_ok_d).all())
-                or ((unique_l + n_new) * 2 > cap_s).any()
-            ):
-                flags |= 1
-            if ((tails + n_new) > qcap).any():
-                flags |= 2
-            # Pre-exchange compaction overflow already retried above;
-            # the insert's own dd=1 buffer covers its whole batch, so
-            # this is a can't-happen invariant guard.
-            if bool(np.asarray(dd_ovf_d).any()):
-                flags |= 4
-            if bool(np.asarray(stepflag_d).any()):
-                flags |= 8
-            t7 = _time.perf_counter()
-
-            tails += n_new
-            unique_l += n_new
-            cand_total += n_cand
-            level_start = level_start + counts
-            if int((level_end - level_start).sum()) == 0:
-                depth += 1
-                level_end = tails.copy()
-            remaining = int((level_end - level_start).sum())
-            waves += 1
-            with self._lock:
-                self._state_count += int(gen_h.sum())
-                self._unique_count += int(n_new.sum())
-                self._max_depth = depth + (1 if remaining else 0)
-                for d in range(n):
-                    for p, prop in enumerate(props):
-                        g = int(disc_h[d, p])
-                        if g != NO_GID:
-                            self._discovery_gids.setdefault(prop.name, g)
-
-            if flags & 1:
-                raise RuntimeError(
-                    f"sharded fingerprint table overfull (per-shard "
-                    f"capacity {cap_s}); raise capacity"
-                )
-            if flags & 2:
-                raise RuntimeError(
-                    "a shard's frontier queue overflowed its backstop "
-                    "bound; raise capacity"
-                )
-            if flags & 4:
-                raise RuntimeError(
-                    "the owner-side insert dedup buffer overflowed — "
-                    "impossible by construction at dedup_factor=1 over "
-                    "the receive batch; please report"
-                )
-            if flags & 8:
-                raise RuntimeError(
-                    "the model step kernel flagged an encoding-capacity "
-                    "overflow (a successor exceeded the packed layout's "
-                    "bounds)"
-                )
-
-            phases = {
-                "step": t1 - t0,
-                "canon": t2 - t1,
-                "dedup": (t3 - t2) + (t5 - t4),
-                "exchange": t4 - t3,
-                "append": t6 - t5,
-                "readback": t7 - t6,
-            }
-            # The MEASURED exchange instrumentation: useful payload
-            # bytes this wave vs the BUCKETED transmitted buffer
-            # (waves × n² × bkt lanes across the mesh).
-            useful = int(n_cand.sum()) * (w + 3) * 4 if n > 1 else 0
-            occ_wave = (
-                float(n_cand.sum()) / (n * n * bkt) if n > 1 else 0.0
-            )
-            enrich = tracer.record_wave(
-                phases, self._traced_wave_bytes(rounds, two_phase),
-                probe_rounds=rounds,
-                exchange_payload_bytes=useful,
-            )
-            enrich["exchange_occupancy"] = round(occ_wave, 6)
-            vitals.record_quantum(
-                t7 - t0, 1, self._unique_count, committed=True,
-                states=self._state_count,
-                cand_lanes=self._wl_cand_lanes(),
-                occupancy=float(unique_l.max()) / cap_s,
-            )
-            vitals.record_host(phases["readback"])
-            self._update_shard_metrics(
-                level_end - level_start, unique_l, cand_total
-            )
-            if self._journal:
-                self._journal.append(
-                    "wave",
-                    waves=waves,
-                    remaining=remaining,
-                    unique=self._unique_count,
-                    states=self._state_count,
-                    depth=depth,
-                    flags=0,
-                    call_sec=round(t7 - t0, 6),
-                    occupancy=round(float(unique_l.max()) / cap_s, 6),
-                    **(
-                        {"density": round(vitals.last_density, 6)}
-                        if vitals.last_density is not None else {}
-                    ),
-                    **enrich,
-                )
-            self._metrics.update(
-                waves=waves,
-                table_occupancy=round(float(unique_l.max()) / cap_s, 6),
-                last_call_sec=round(t7 - t0, 6),
-                exchange_occupancy=round(occ_wave, 6),
-            )
-            self._metrics.inc("device_call_sec_total", t7 - t0)
-            self._metrics.inc("device_calls", 1)
-
-            # Density-driven sort-rung downshift and frontier-driven
-            # step-rung downshift, per committed wave (wave_loop's
-            # shared tuners); a rung change re-keys the phase programs
-            # and recomputes the rung-derived buckets.
-            from .wave_loop import maybe_retune_sort, maybe_retune_step
-
-            retuned = maybe_retune_sort(self, vitals.last_density)
-            # Per-shard evidence: the fullest shard's backlog is what
-            # the (per-shard) chunk rung must hold.  The fused loop
-            # feeds the global sum instead — an upper bound, so its
-            # downshifts are merely more conservative.
-            peak_backlog = int((level_end - level_start).max())
-            if maybe_retune_step(self, peak_backlog or None):
-                retuned = True
-            if retuned:
-                f_eff = self._step_width()
-                bkt = self._bucket_lanes()
-                progs = self._traced_programs()
-
-            # Shared termination tail (wave_loop.py): finish_when /
-            # target_state_count / deadline / cooperative cancel, the
-            # same predicate order as the fused loop by construction.
-            from .wave_loop import loop_should_break
-
-            if loop_should_break(self, remaining, depth, deadline):
-                break
-
-        self._accounting = self._build_accounting(
-            waves, cand_total, unique_l
-        )
-        self._tables_dev = (parent, store)
-        # Snapshot-ready carry, like the fused loop: the stats matrix is
-        # reconstructed from the host-tracked control state (sc/unique_g
-        # replicated per shard, exactly as the psums leave them).
-        stats_np = np.zeros((n, k_stats), np.uint32)
-        stats_np[:, S_LEVEL_START] = level_start.astype(np.uint32)
-        stats_np[:, S_LEVEL_END] = level_end.astype(np.uint32)
-        stats_np[:, S_TAIL] = tails.astype(np.uint32)
-        stats_np[:, S_SC_LO] = self._state_count & 0xFFFFFFFF
-        stats_np[:, S_SC_HI] = (self._state_count >> 32) & 0xFFFFFFFF
-        stats_np[:, S_UNIQUE_G] = self._unique_count
-        stats_np[:, S_UNIQUE_L] = unique_l.astype(np.uint32)
-        stats_np[:, S_CAND_LO] = (cand_total & 0xFFFFFFFF).astype(np.uint32)
-        stats_np[:, S_CAND_HI] = (cand_total >> 32).astype(np.uint32)
-        stats_np[:, S_DEPTH] = depth
-        stats_np[:, S_DISC:] = disc_h.astype(np.uint32)
-        if self._journal:
-            self._journal.append("trace_summary", **tracer.summary())
-        # Final carry / completion checkpoint / engine_done via the
-        # shared core, same as the fused loop.
-        from .wave_loop import finalize_run
-
-        finalize_run(self, {
-            "key_hi": key_hi,
-            "key_lo": key_lo,
-            "store": store,
-            "parent": parent,
-            "ebits": ebits,
-            "queue": queue,
-            "stats": stats_np,
-        })
-
     def _seed_initial(self, shard):
         """Host-side owner routing + the seed program: one upload + one
         dispatch mints every device buffer (the spawn-cost story in
-        ``_seed_program``).  Shared by the fused and traced host loops
-        so seeding semantics cannot drift between them."""
+        ``_seed_program``)."""
         import jax
         import jax.numpy as jnp
 
@@ -1804,8 +1133,6 @@ class ShardedTpuChecker(Checker):
         return out
 
     def _check(self) -> None:
-        if self._trace:
-            return self._check_traced()
         import time as _time
 
         import jax
@@ -2200,8 +1527,8 @@ class ShardedTpuChecker(Checker):
         return f"sharded engine overflow flags={flags}"
 
     def _grow_knobs(self, flags: int):
-        """The knob half of in-place growth, shared by the fused and
-        traced retry paths: relax ``dedup_factor`` straight to 1 (flag 4,
+        """The knob half of in-place growth, shared with the
+        tiered-sharded engine's recovery: relax ``dedup_factor`` straight to 1 (flag 4,
         the rule shared with wavefront.py via wave_loop) and/or climb
         the exchange bucket-slack ladder (flag 32).  Both knobs only
         shape per-wave scratch buffers — never the table, store, queue,
@@ -2306,15 +1633,15 @@ class ShardedTpuChecker(Checker):
 
     def _build_accounting(self, waves_total: int, cand_h, uniq_h) -> dict:
         """The weak-scaling accounting dict from measured per-shard
-        counters (``cand_h``/``uniq_h``: int64[n]); shared by the fused
-        and traced host loops so the payload geometry and occupancy
+        counters (``cand_h``/``uniq_h``: int64[n]); shared with the
+        tiered-sharded engine so the payload geometry and occupancy
         definitions cannot drift between them.
 
         The ``all_to_all_bytes_*`` keys derive from the ACTUAL bucket
         geometry (``_bucket_lanes()``, the same wave_loop function the
         device program compiled against) — never hand-computed from the
-        static ``u_sz`` buffer shape — so the doc generator and bench
-        read one source of truth.  If the slack rung ramped mid-run, the
+        static ``u_sz`` buffer shape — so the doc generator and the
+        benchmark read one source of truth.  If the slack rung ramped mid-run, the
         final (largest) bucket is reported: committed pre-ramp waves
         shipped smaller buckets, so totals are a slight over- and
         occupancy a slight under-statement, in the conservative
@@ -2532,13 +1859,11 @@ class ShardedTpuChecker(Checker):
     def metrics(self) -> dict:
         """Live observability snapshot (names: docs/OBSERVABILITY.md);
         safe to call mid-run.  Includes the weak-scaling accounting once
-        the run completes and, under ``trace=True``, the roofline trace
-        summary with the measured per-wave exchange totals."""
+        the run completes."""
         out = super().metrics()
         out.update(
             engine="tpu-sharded",
             shards=self._n,
-            trace=self._trace,
             capacity=self._cap_s * self._n,
             capacity_per_shard=self._cap_s,
             chunk_size=self._chunk,
@@ -2566,20 +1891,7 @@ class ShardedTpuChecker(Checker):
             out["histograms"] = hists
         if self._accounting:
             out["accounting"] = dict(self._accounting)
-        if self._tracer is not None:
-            out["trace_summary"] = self._tracer.summary()
         return out
-
-    def trace_summary(self) -> dict:
-        """The finished traced run's roofline reduction (per-phase
-        seconds, modeled bytes, ``hbm_util_frac``, measured exchange
-        payload totals).  Requires ``trace=True``."""
-        self.join()
-        if self._tracer is None:
-            raise RuntimeError(
-                "trace_summary() requires spawn_tpu_sharded(trace=True)"
-            )
-        return self._tracer.summary()
 
     def _gid_path(self, gid: int) -> Path:
         # The lazy ~GB-scale host pull happens at most once (guarded: two

@@ -96,8 +96,8 @@ def make_finish_when_device(fw, props):
 def two_phase_capable(cm) -> bool:
     """Host-side mirror of :func:`wave_eval`'s two-phase gate: the model
     exposes ``step_valid`` + ``step_lane`` AND is unbounded (``boundary``
-    None).  The traced engine loops (wavefront/sharded ``trace=True``)
-    use it to pick the matching roofline byte model; keeping it beside
+    None).  The tiered engine's ``trace=True`` uses it to pick the
+    matching roofline byte model; keeping it beside
     the trace-time gate keeps the two from drifting."""
     import numpy as np
 
@@ -205,7 +205,7 @@ def _timed_first_call(fn, sublabel, ctx):
 
 def _instrument_programs(prog, ctx):
     """Wrap every callable in a program (a bare callable, a tuple like
-    the single-chip ``(seed, run)`` pair, or the traced-mode dict) —
+    the single-chip ``(seed, run)`` pair, or a phase-program dict) —
     each is a distinct XLA program with its own compile."""
     if callable(prog):
         return _timed_first_call(prog, "", ctx)

@@ -13,8 +13,8 @@ contract exist on both engines by construction rather than by copy.
 
 This module also owns the **exchange bucket geometry** — the sharded
 engine's per-destination all_to_all buckets (:func:`exchange_bucket_lanes`)
-— as the single source of truth shared by the device program, the traced
-byte model, and `accounting()`, so the reported payload shape can never
+— as the single source of truth shared by the device programs and
+`accounting()`, so the reported payload shape can never
 drift from what the device actually transmits (docs/SHARDED_SCALING.md).
 """
 
@@ -288,8 +288,7 @@ def _maybe_retune(eng, measured, ns: dict) -> bool:
     ``_SORT_NS``/``_STEP_NS`` below — so the two tuners cannot drift):
     folds the quantum's measurement into the engine's running peak and
     applies a downshift once enough committed quanta accumulated.
-    Returns True exactly when the rung changed — traced loops use it to
-    refresh their phase programs."""
+    Returns True exactly when the rung changed."""
     apply = getattr(eng, ns["apply"], None)
     if apply is None or measured is None:
         return False
@@ -341,8 +340,8 @@ _STEP_NS = dict(
 
 def maybe_retune_sort(eng, density) -> bool:
     """Shared density→sort-rung downshift, called by every host loop
-    after a committed quantum (fused and traced alike; engines without
-    the ``_wl_apply_sort_rung`` hook are untouched)."""
+    after a committed quantum (engines without the
+    ``_wl_apply_sort_rung`` hook are untouched)."""
     return _maybe_retune(eng, density, _SORT_NS)
 
 
@@ -429,13 +428,13 @@ class LoopVitals:
     """Always-on fused-loop vitals (docs/OBSERVABILITY.md "Always-on
     vitals"): cheap per-quantum counters and histograms recorded at the
     host-side call boundary every engine loop already crosses — never an
-    extra device sync, so the trace=False device program stays
+    extra device sync, so the fused device program stays
     byte-for-byte pinned.  One instance per engine run; writes land in
     the engine's :class:`~stateright_tpu.obs.metrics.MetricsRegistry`:
 
     - ``wave_latency_sec`` histogram — per-wave wall latency (a fused
       quantum of ``waves_per_call`` waves records its mean latency with
-      weight waves_per_call; traced loops record each wave exactly);
+      weight waves_per_call; the tiered loops' quanta are one wave);
     - ``waves_per_grow`` histogram — committed waves between
       overflow-triggered recoveries (how long a geometry survived
       before overflowing);
@@ -444,9 +443,8 @@ class LoopVitals:
       memory, mid-run readable from ``/.metrics``);
     - ``host_sec_total`` counter — host-side loop time: the fused loop
       accounts the between-calls gap (journal/metrics/checkpoint/grow
-      dispatch); the traced loops report their measured ``readback``
-      phase via :meth:`record_host` instead.  Either way, the
-      time-in-host complement of the device time;
+      dispatch), and the final quantum's tail via :meth:`record_host`
+      — the time-in-host complement of the device time;
     - ``overflow_retries`` counter — every overflow-flagged wave that
       was recovered and re-run.  The separate ``grows`` counter
       (:func:`log_grow`) counts only ACTUAL geometry changes: a
@@ -513,10 +511,8 @@ class LoopVitals:
         self._host_mark = now
 
     def record_host(self, sec: float) -> None:
-        """Directly account host-side seconds — the traced loops' path:
-        their per-wave timers already isolate the host ``readback``
-        phase inside the wave, so they report it here instead of the
-        fused loop's between-calls gap."""
+        """Directly account host-side seconds: the loop's final tail,
+        which has no next call to close its between-calls gap."""
         self._reg.inc("host_sec_total", max(0.0, sec))
 
     def record_quantum(
@@ -595,9 +591,9 @@ class LoopVitals:
 
 
 def journal_geometry(eng) -> None:
-    """One ``geometry`` journal event at loop start (fused and traced
-    alike): the engine's live geometry knobs plus the worst-case
-    candidate-lane denominator the density telemetry divides by —
+    """One ``geometry`` journal event at loop start: the engine's live
+    geometry knobs plus the worst-case candidate-lane denominator the
+    density telemetry divides by —
     everything the report advisor (obs/report.py) needs to turn measured
     densities back into recommended knobs.  Engines expose it via the
     optional ``_wl_geometry()`` hook."""
@@ -625,9 +621,8 @@ class WaveView(NamedTuple):
 def loop_should_break(eng, view_remaining: int, depth: int, deadline) -> bool:
     """The shared termination tail (exact predicate order preserved from
     the pre-extraction loops): level drained / target depth / finish_when
-    / target_state_count / wall deadline / cooperative stop.  Used by the
-    fused driver below AND the engines' traced loops, so a traced run can
-    never outlive (or under-live) a fused one."""
+    / target_state_count / wall deadline / cooperative stop, checked by
+    the fused driver below after every committed call."""
     opts = eng._options
     if view_remaining == 0:
         return True
@@ -666,12 +661,14 @@ class FusedWaveLoop:
       recompile programs / re-upload a fixed stats vector); None means
       the tripped knob cannot grow;
     - ``_wl_overflow_message(flags) -> str`` — the loud error text;
-    - ``_wl_after_commit(carry, view) -> carry | None`` (OPTIONAL) — the
-      spill/refill dispatch: called after every committed (flags == 0)
-      wave, before the checkpoint cadence, so an engine with a tiered
-      store (tiered/engine.py) can evict hot-tier partitions at its
-      budget threshold and have the very next checkpoint persist the
-      post-spill state.  Returning None keeps the carry;
+    - ``_wl_after_commit(carry, view) -> carry | None`` (OPTIONAL) —
+      called after every committed (flags == 0) call, before the
+      checkpoint cadence: an engine with a tiered store
+      (tiered/engine.py) evicts hot-tier partitions at its budget
+      threshold there, so the very next checkpoint persists the
+      post-spill state, and the single-chip engine streams the states
+      the call expanded to an attached visitor.  Returning None keeps
+      the carry;
 
     plus the shared checker attributes (`_options`, `_properties`,
     `_journal`, `_metrics`, `_lock`, `_stop_requested`, counters, and the
@@ -696,7 +693,7 @@ class FusedWaveLoop:
         # Host-tail span decomposition (obs/timeline.py): every named
         # section of the between-calls tail below runs under
         # ``spans.span(...)`` — two extra ``time.monotonic()`` calls per
-        # section, no device traffic, so the trace=False fused program
+        # section, no device traffic, so the fused program
         # stays byte-for-byte pinned.  The recorder flushes ONE
         # ``host_span`` journal event per quantum at the same boundary
         # ``vitals.call_started`` accounts into ``host_sec_total``.
@@ -758,12 +755,12 @@ class FusedWaveLoop:
             eng._metrics.inc("device_call_sec_total", call_sec)
             eng._metrics.inc("device_calls", 1)
             if view.flags == 0:
-                # Spill/refill rung (tiered engines only): evict AT the
-                # committed boundary so the cadence block below persists
-                # the post-spill tier state in the same pass.
+                # Post-commit hook (tiered spills, the visitor stream):
+                # AT the committed boundary, so the cadence block below
+                # persists the post-spill tier state in the same pass.
                 after_commit = getattr(eng, "_wl_after_commit", None)
                 if after_commit is not None:
-                    with spans.span("spill"):
+                    with spans.span("after_commit"):
                         carry = after_commit(carry, view) or carry
                 # Density-driven sort-rung downshift and frontier-driven
                 # step-rung downshift (engines with the hooks only): the

@@ -76,8 +76,7 @@ STAT_DISC = 12  # disc[P] rides at [STAT_DISC : STAT_DISC + n_props]
 
 def host_stats(level_start, level_end, tail, states, unique, depth, disc):
     """A stats vector in the layout above, built on the host (resume,
-    and the traced and tiered loops' snapshots); flags and probe rounds
-    read 0."""
+    and the tiered loop's snapshots); flags and probe rounds read 0."""
     head = [
         level_start, level_end, tail, states & 0xFFFFFFFF,
         (states >> 32) & 0xFFFFFFFF, unique, depth,
@@ -240,7 +239,6 @@ class TpuChecker(Checker):
         checkpoint_path: Optional[str] = None,
         checkpoint_every_waves: Optional[int] = None,
         checkpoint_every_sec: Optional[float] = None,
-        trace: bool = False,
     ):
         """``capacity`` sizes the fingerprint table (slots; load is kept
         below 50%), ``log_capacity`` the append-only row log (positions =
@@ -317,42 +315,16 @@ class TpuChecker(Checker):
         is given); a killed run resumes from the latest checkpoint via
         ``resume_from``.
 
-        ``trace``: run the wave loop in PHASE-TIMED SEGMENTS (step
-        kernel / canon+fingerprint / dedup-sort+probe / append / host
-        readback) instead of the fused ``lax.while_loop`` — one host
-        sync per wave, each phase a separate dispatch timed with
-        ``block_until_ready`` and charged modeled bytes against the
-        device's peak HBM bandwidth (obs/roofline.py).  Results are
-        identical (same kernels, same commit order); throughput is not —
-        a traced run pays per-wave dispatch+sync overhead and exists to
-        say WHERE the untraced run's time goes, never to be the measured
-        number.  With ``trace=False`` (the default) the fused device
-        program is byte-for-byte unchanged and the host loop issues no
-        additional per-wave syncs.  Tracing surfaces: enriched ``wave``
-        journal records, ``metrics()`` (the Explorer's ``/.metrics``),
-        and ``trace_summary()``.  Traced runs auto-grow in place on
-        overflow exactly like the fused loop (an aborted wave never
-        commits; the rehash erases its keys), but do not support
-        ``resume_from`` and ignore the mid-run checkpoint cadence (the
-        final completion checkpoint still lands).  A visitor forces
-        ``trace`` on — a visitor-instrumented default-knob run still
-        completes, it just runs at traced speed.
-
-        Visitors: a ``visitor()`` on the builder is supported at COARSE
-        WAVE GRANULARITY via the traced readback path (``trace`` is
-        forced on): every unique state is visited exactly once, at
-        expansion, as a single-state path — BFS level order across
-        waves, fingerprint-sorted order within a level, no action
-        prefix.  docs/OBSERVABILITY.md states the full contract."""
+        Visitors: a ``visitor()`` on the builder is supported at the
+        granularity of one fused call (``waves_per_call`` waves): after
+        each committed call the log rows it expanded are read back and
+        every unique state is visited exactly once, at expansion, as a
+        single-state path — BFS level order, log order within a level,
+        no action prefix.  docs/OBSERVABILITY.md states the full
+        contract."""
         super().__init__(options.model)
         import jax
 
-        if options._visitor is not None:
-            # The wavefront never materializes per-state paths during
-            # the run; visits ride the traced per-wave readback instead
-            # (coarse wave granularity — see the docstring above).
-            trace = True
-        self._trace = bool(trace)
         self._options = options
         self._compiled = compiled or compiled_model_for(options.model)
         # Symmetry reduction: dedup on the fingerprint of the CANONICAL
@@ -488,16 +460,13 @@ class TpuChecker(Checker):
         self._errors: List[BaseException] = []
         self._lock = threading.Lock()
         self._resume_from = resume_from
-        if self._trace and resume_from is not None:
-            raise ValueError(
-                "spawn_tpu(trace=True) does not support resume_from: "
-                "tracing is a diagnostic mode; resume the run untraced "
-                "and trace a fresh (bounded) run instead"
-            )
+        if options._visitor is not None:
+            # The device visitor stream: the fused loop's post-commit
+            # hook, present only when a visitor is attached.
+            self._wl_after_commit = self._visit_committed
         from ..obs.metrics import MetricsRegistry
 
         self._metrics = MetricsRegistry()
-        self._tracer = None  # built by the traced host loop
         from ..runtime.journal import as_journal
 
         self._journal = as_journal(journal)
@@ -1014,72 +983,6 @@ class TpuChecker(Checker):
                     self._unique_count = 0
                     self._max_depth = 0
 
-    def _grow_on_flags(self, flags_h, qcap, pad, rows, parent, ebits,
-                       tail_h, unique_h, depth_h):
-        """In-place auto-tune growth for in-loop overflow flags (bits
-        1/2/4), shared by the fused and traced host loops so their
-        recovery semantics cannot drift: grow the tripped knobs
-        (:meth:`_grow`, honoring the dragged-log rule), resize the
-        row/parent/ebits buffers if the log geometry changed, and
-        rebuild the table from the committed row-log prefix (erasing any
-        keys the aborted wave wrote).  Returns ``(rows, parent, ebits,
-        key_hi, key_lo, qcap, pad)``; raises RuntimeError when the
-        tripped knob cannot grow (or ``auto_tune`` is off).  The caller
-        re-derives its capacity/frontier locals and programs from self
-        and re-runs the same chunk."""
-        msgs = {
-            1: (
-                f"fingerprint table overfull (capacity "
-                f"{self._capacity}); raise spawn_tpu(capacity=...)"
-            ),
-            2: (
-                f"the state row log is full (log_capacity {qcap}); "
-                "raise spawn_tpu(log_capacity=...)"
-            ),
-            4: (
-                "a wave generated more VALID successor candidates than "
-                "the compaction/dedup buffers hold (batch/dedup_factor); "
-                f"lower spawn_tpu(dedup_factor=...) (now "
-                f"{self._dedup_factor}; 1 is always safe)"
-            ),
-            128: (
-                "the step-rung ladder clamped a wave at the full chunk "
-                "width — impossible by construction (the clamp flag is "
-                "compiled out at the top rung); please report"
-            ),
-        }
-        grown = []
-        for bit in (1, 2, 4, 128):
-            if flags_h & bit:
-                if bit == 2 and self._log_capacity > qcap:
-                    # A simultaneous table growth (bit 1, processed
-                    # above) already dragged the log past the tripped
-                    # size — the flag is addressed; raising here would
-                    # kill a run whose log just grew.
-                    grown.append(
-                        f"log_capacity={self._log_capacity} (dragged)"
-                    )
-                    continue
-                g = self._grow(bit) if self._auto_tune else None
-                if g is None:
-                    raise RuntimeError(msgs[bit])
-                grown.append(g)
-        from .wave_loop import log_grow
-
-        log_grow(self, flags_h, "; ".join(grown), unique_h, depth_h)
-        new_qcap = self._log_capacity
-        new_pad = self._block_pad()
-        if (new_qcap + new_pad) != (qcap + pad):
-            n_new_len = new_qcap + new_pad
-            rows = _resize_flat(
-                rows, n_new_len * self._compiled.state_width, 0
-            )
-            parent = _resize_flat(parent, n_new_len, NO_SLOT_HOST)
-            ebits = _resize_flat(ebits, n_new_len, 0)
-            qcap, pad = new_qcap, new_pad
-        key_hi, key_lo = self._rehash(rows, tail_h)
-        return rows, parent, ebits, key_hi, key_lo, qcap, pad
-
     def _grow(self, flag: int):
         """Adjust the knob named by ``flag``; None if it cannot grow.
 
@@ -1214,8 +1117,6 @@ class TpuChecker(Checker):
         return None
 
     def _check_once(self, deadline=None) -> None:
-        if self._trace:
-            return self._check_once_traced(deadline)
         import jax
         import jax.numpy as jnp
 
@@ -1288,6 +1189,8 @@ class TpuChecker(Checker):
                     ) | int(snap["sc_lo"])
                     self._unique_count = int(snap["unique_count"])
                     self._max_depth = int(snap["depth"])
+                    # States the saved run expanded were visited there.
+                    self._visited_to = int(snap["level_start"])
                     # Discovery names derive from the persisted disc array
                     # and the property order, which the key above pins.
                     for p, prop in enumerate(props):
@@ -1329,6 +1232,7 @@ class TpuChecker(Checker):
                     )
                 self._state_count = n_init
                 self._unique_count = int(stats_h[STAT_UNIQUE])
+                self._visited_to = 0
 
             # The steady-state loop is the SHARED wave-loop core
             # (parallel/wave_loop.py) — journal/metrics/checkpoint
@@ -1343,6 +1247,10 @@ class TpuChecker(Checker):
             carry, _waves = FusedWaveLoop(self).run(carry, deadline)
             key_hi, key_lo, rows, parent, ebits, stats = carry
             stats_h = self._last_stats_h
+            if self._options._visitor is not None:
+                # A stop on a flagged call keeps the waves that call
+                # committed before the flagged one: visit them too.
+                self._visit_expanded(rows, int(stats_h[STAT_LEVEL_START]))
 
             # Keep the device arrays; path reconstruction walks the parent
             # chain ON DEVICE and reads back only the chain (a full-table
@@ -1469,8 +1377,8 @@ class TpuChecker(Checker):
     def _wl_apply_step_rung(self, rung: int) -> None:
         """Apply a frontier-tuner downshift (wave_loop.
         maybe_retune_step): swap the knob, re-journal the geometry
-        event, and — in fused mode — rebuild the run program at the new
-        shapes.  The loop carry is untouched: the rung only shapes
+        event, and rebuild the fused run program (once it exists) at the
+        new shapes.  The loop carry is untouched: the rung only shapes
         per-wave scratch buffers (the row log, table, and positions are
         rung-independent)."""
         self._step_lanes = int(rung)
@@ -1483,8 +1391,8 @@ class TpuChecker(Checker):
     def _wl_apply_sort_rung(self, rung: int) -> None:
         """Apply a density-tuner downshift (wave_loop.maybe_retune_sort):
         swap the knob, re-journal the geometry event (the watch verb's
-        source for the current rung), and — in fused mode — rebuild the
-        run program at the new shapes.  The loop carry is untouched:
+        source for the current rung), and rebuild the fused run program
+        (once it exists) at the new shapes.  The loop carry is untouched:
         the rung only shapes per-wave scratch buffers."""
         self._sort_lanes = int(rung)
         self._sort_quanta = 0  # fresh evidence before another move
@@ -1528,7 +1436,7 @@ class TpuChecker(Checker):
         # overflow (sortless fallback / sort-rung climb / dd relax),
         # 128 = step-rung clamp (climb one chunk rung): all grow in
         # place (auto_tune off raises the loud per-knob message from
-        # _grow_on_flags instead).  8 (encoding overflow) is never
+        # _wl_grow instead).  8 (encoding overflow) is never
         # retryable.
         return 1 | 2 | 4 | 128
 
@@ -1557,526 +1465,106 @@ class TpuChecker(Checker):
         """In-place auto-tune growth for the fused loop (the shared
         core's grow hook): the flagged wave did not commit (see
         wave_body), so the carry is the exact pre-wave state — grow the
-        tripped buffers, rebuild the table from the committed row-log
-        prefix (erasing any keys the aborted wave managed to write),
-        recompile, and re-run the same chunk with no work redone."""
+        tripped knobs (:meth:`_grow`, honoring the dragged-log rule),
+        resize the row/parent/ebits buffers if the log geometry changed,
+        rebuild the table from the committed row-log prefix (erasing any
+        keys the aborted wave managed to write), recompile, and re-run
+        the same chunk with no work redone.  Raises RuntimeError when
+        the tripped knob cannot grow (or ``auto_tune`` is off)."""
         stats_h = self._last_stats_h
-        rows, parent, ebits, key_hi, key_lo, qcap, pad = (
-            self._grow_on_flags(
-                flags, self._loop_qcap, self._loop_pad,
-                carry[2], carry[3], carry[4],
-                int(stats_h[STAT_TAIL]), int(stats_h[STAT_UNIQUE]),
-                int(stats_h[STAT_DEPTH]),
-            )
+        tail_h = int(stats_h[STAT_TAIL])
+        qcap, pad = self._loop_qcap, self._loop_pad
+        msgs = {
+            1: (
+                f"fingerprint table overfull (capacity "
+                f"{self._capacity}); raise spawn_tpu(capacity=...)"
+            ),
+            2: (
+                f"the state row log is full (log_capacity {qcap}); "
+                "raise spawn_tpu(log_capacity=...)"
+            ),
+            4: (
+                "a wave generated more VALID successor candidates than "
+                "the compaction/dedup buffers hold (batch/dedup_factor); "
+                f"lower spawn_tpu(dedup_factor=...) (now "
+                f"{self._dedup_factor}; 1 is always safe)"
+            ),
+            128: (
+                "the step-rung ladder clamped a wave at the full chunk "
+                "width — impossible by construction (the clamp flag is "
+                "compiled out at the top rung); please report"
+            ),
+        }
+        grown = []
+        for bit in (1, 2, 4, 128):
+            if flags & bit:
+                if bit == 2 and self._log_capacity > qcap:
+                    # A simultaneous table growth (bit 1, processed
+                    # above) already dragged the log past the tripped
+                    # size — the flag is addressed; raising here would
+                    # kill a run whose log just grew.
+                    grown.append(
+                        f"log_capacity={self._log_capacity} (dragged)"
+                    )
+                    continue
+                g = self._grow(bit) if self._auto_tune else None
+                if g is None:
+                    raise RuntimeError(msgs[bit])
+                grown.append(g)
+        from .wave_loop import log_grow
+
+        log_grow(
+            self, flags, "; ".join(grown), int(stats_h[STAT_UNIQUE]),
+            int(stats_h[STAT_DEPTH]),
         )
+        rows, parent, ebits = carry[2], carry[3], carry[4]
+        new_qcap, new_pad = self._log_capacity, self._block_pad()
+        if (new_qcap + new_pad) != (qcap + pad):
+            n_new_len = new_qcap + new_pad
+            rows = _resize_flat(
+                rows, n_new_len * self._compiled.state_width, 0
+            )
+            parent = _resize_flat(parent, n_new_len, NO_SLOT_HOST)
+            ebits = _resize_flat(ebits, n_new_len, 0)
+            qcap, pad = new_qcap, new_pad
+        key_hi, key_lo = self._rehash(rows, tail_h)
         self._loop_qcap, self._loop_pad = qcap, pad
         _seed, self._run_fn = self._programs()
         return (key_hi, key_lo, rows, parent, ebits, carry[5])
 
-    # --- traced (phase-timed) mode -------------------------------------------
-
-    def _traced_programs(self):
-        """Phase-program set for ``trace=True`` (cached like the fused
-        pair).  The key covers everything the closures trace over; host-
-        driven knobs (waves_per_call, finish_when, target depth) are NOT
-        baked in — the traced loop decides them per wave on the host."""
-        key = (
-            "traced",
-            self._compiled.cache_key(),
-            hasattr(self._compiled, "step_valid")
-            and hasattr(self._compiled, "step_lane"),
-            self._canon is not None,
-            self._max_frontier,
-            self._dedup_factor,
-            self._sortless,  # the dedup path is a trace-time branch
-            self._sort_width(),  # the live sort-geometry rung
-            self._step_width(),  # the live step-geometry rung
-            self._block_pad(),
-            tuple(p.expectation for p in self._properties),
-        )
-        from .wave_common import cached_program
-
-        return cached_program(
-            _PROGRAM_CACHE, _PROGRAM_CACHE_MAX, key, self._build_traced,
-            label=f"{type(self).__name__}.traced",
-            journal=self._journal,
-            provenance=self._key_provenance(),
+    def _visit_committed(self, carry, view) -> None:
+        """The device visitor stream, on the fused loop's post-commit
+        hook (bound in ``__init__`` only when a visitor is attached):
+        the log positions expanded since the last visit — up to the
+        call's new ``STAT_LEVEL_START`` — are read back and visited.
+        An aborted call is not visited; the waves it committed before
+        the flagged one are read with the next committed call."""
+        self._visit_expanded(
+            carry[2], int(self._last_stats_h[STAT_LEVEL_START])
         )
 
-    def _build_traced(self):
-        """The wave loop as four separately-dispatched phase programs —
-        the SAME kernels as the fused ``wave_body``, cut at the phase
-        boundaries the roofline models (step kernel / canon+fingerprint /
-        dedup-sort+probe / append) so the host can time each with
-        ``block_until_ready``.  Commit order, dedup keys, position
-        assignment, and discovery folding are identical to the fused
-        path; level/depth bookkeeping moves to the host (one sync per
-        wave is the traced mode's documented cost)."""
+    def _visit_expanded(self, rows, upto: int) -> None:
+        """Visit log positions ``[_visited_to, upto)`` in log order, each
+        as a single-state path, reading the row log back one append
+        block at a time (the log's padding keeps every block in
+        bounds)."""
         import jax
         import jax.numpy as jnp
 
-        from ..ops.device_fp import device_fp64
-        from .hashset import (
-            HashSet, compact_valid_indices, insert_batch_claim,
-            insert_batch_compact,
-        )
-        from .wave_common import compact, wave_eval
-
         cm = self._compiled
         w = cm.state_width
-        fpw = cm.fp_words or w
-        canon = self._canon
-        a = cm.max_actions
-        f_eff = self._step_width()  # the live step-geometry rung
-        pad = self._block_pad()
-        dedup_factor = self._dedup_factor
-        sortless = self._sortless  # the dedup path (claim vs sort)
-        sort_lanes = (
-            None if self._sort_lanes is None else self._sort_width()
-        )
-        props = self._properties
-        ev_indices = self._ev_indices
-
-        @jax.jit
-        def t_step(rows, ebits, disc, level_start, level_end):
-            count = jnp.minimum(level_end - level_start, jnp.uint32(f_eff))
-            lane = jnp.arange(f_eff, dtype=jnp.uint32)
-            active = lane < count
-            ids = level_start + lane
-            states = jax.lax.dynamic_slice(
-                rows, (level_start * jnp.uint32(w),), (f_eff * w,)
-            ).reshape(f_eff, w)
-            eb_chunk = jax.lax.dynamic_slice(
-                ebits, (level_start,), (f_eff,)
-            )
-            disc, eb, nexts, valid, generated, step_flag = wave_eval(
-                cm, props, ev_indices, states, active, ids, eb_chunk,
-                disc, allow_two_phase=True,
-            )
-            flat_valid = valid.reshape(f_eff * a)
-            v_orig, v_act, n_valid, v_overflow = compact_valid_indices(
-                flat_valid, dedup_factor, sort_lanes=sort_lanes
-            )
-            if nexts is None:
-                # Two-phase: construct successors only for the compacted
-                # valid lanes (the fused path's phase B).
-                src_state = v_orig // jnp.uint32(a)
-                cand_rows, _vu, lane_flags_u = jax.vmap(cm.step_lane)(
-                    states[src_state], v_orig % jnp.uint32(a)
-                )
-                step_flag = step_flag | jnp.any(lane_flags_u & v_act)
-                cand_src = src_state
-            else:
-                # Single-phase: compact the constructed rows.  Same keys
-                # and representatives as the fused compact_valid-on-keys
-                # order (compaction preserves lane order).
-                cand_rows = nexts.reshape(f_eff * a, w)[v_orig]
-                cand_src = v_orig // jnp.uint32(a)
-            return (
-                disc, eb, states, cand_rows, cand_src, v_act,
-                n_valid, v_overflow, generated, step_flag,
-            )
-
-        @jax.jit
-        def t_fp(cand_rows):
-            rows_c = (
-                cand_rows if canon is None else jax.vmap(canon)(cand_rows)
-            )
-            return device_fp64(rows_c[:, :fpw])
-
-        @partial(jax.jit, donate_argnums=(0, 1))
-        def t_insert(key_hi, key_lo, hi, lo, cand_act):
-            if sortless:
-                (
-                    table, _u_slot, u_new, u_origin, _u_active, probe_ok,
-                    dd_overflow, rounds,
-                ) = insert_batch_claim(
-                    HashSet(key_hi, key_lo), hi, lo, cand_act,
-                    with_rounds=True,
-                )
-            else:
-                (
-                    table, _u_slot, u_new, u_origin, _u_active, probe_ok,
-                    dd_overflow, rounds,
-                ) = insert_batch_compact(
-                    HashSet(key_hi, key_lo), hi, lo, cand_act,
-                    dedup_factor=1, with_rounds=True,
-                )
-            n_new = jnp.sum(u_new, dtype=jnp.uint32)
-            return (
-                table.key_hi, table.key_lo, u_new, u_origin, n_new,
-                probe_ok, dd_overflow, rounds,
-            )
-
-        @partial(jax.jit, donate_argnums=(0, 1, 2))
-        def t_append(rows, parent, ebits, cand_rows, cand_src, eb, u_new,
-                     u_origin, level_start, tail):
-            u = u_new.shape[0]
-            sel = compact(u_new, jnp.arange(u, dtype=jnp.uint32), pad)
-            sel_u = u_origin[sel]
-            rows_blk = cand_rows[sel_u]
-            src_state = cand_src[sel_u]
-            par_blk = level_start + src_state
-            eb_blk = eb[src_state]
-            rows = jax.lax.dynamic_update_slice(
-                rows, rows_blk.reshape(-1), (tail * jnp.uint32(w),)
-            )
-            parent = jax.lax.dynamic_update_slice(parent, par_blk, (tail,))
-            ebits = jax.lax.dynamic_update_slice(ebits, eb_blk, (tail,))
-            return rows, parent, ebits
-
-        return {
-            "step": t_step, "fp": t_fp, "insert": t_insert,
-            "append": t_append,
-        }
-
-    def _traced_wave_bytes(self, probe_rounds: int, two_phase: bool) -> dict:
-        """Modeled HBM bytes touched by one traced wave, per phase
-        (obs/roofline.py documents the model and its biases).  Buffer-
-        proportional, not count-proportional: the device streams full
-        fixed-width buffers regardless of how many lanes are live, so
-        charging the full widths is what matches what HBM actually
-        moves.  The chunk/candidate widths are the LIVE step rung
-        (``_step_width``) — ``bytes.step`` drops in proportion to it,
-        the step ladder's regression gauge (bench.py's step phase) —
-        and the compact/canon/dedup widths the LIVE compact width.  On
-        the sortless default path ``bytes.dedup`` carries NO sort term
-        at all (the claim election probes, it never sorts): that is the
-        density-insensitive drop bench's dedup phase gauges."""
-        from ..obs.roofline import copy_bytes, probe_bytes, sort_bytes
-
-        cm = self._compiled
-        w = cm.state_width
-        fpw = cm.fp_words or w
-        a = cm.max_actions
-        f_eff = self._step_width()
-        b = f_eff * a
-        u_sz = self._sort_width()
-        pad = self._block_pad()
-        # step: chunk read + candidate construction + the valid-lane
-        # index compaction scan.  Two-phase constructs only U rows (and
-        # gathers their U parents); single-phase materializes all B.
-        step = f_eff * w * 4 + b * 4 + copy_bytes(u_sz, w)
-        if not two_phase:
-            step += b * w * 4
-        canon = (copy_bytes(u_sz, w) if self._canon is not None else 0)
-        canon += u_sz * fpw * 4 + 2 * u_sz * 4
-        if self._sortless:
-            # Claim election: probe rounds over the compact width plus
-            # the claim-plane scatter/readback — no sort planes, no
-            # representative re-compaction.
-            dedup = probe_bytes(u_sz, probe_rounds) + 2 * u_sz * 4
-        else:
-            dedup = (
-                sort_bytes(u_sz, 3)
-                + probe_bytes(u_sz, probe_rounds)
-                + 4 * u_sz * 4  # representative compaction planes
-            )
-        append = copy_bytes(pad, w) + 2 * copy_bytes(pad, 1) + u_sz * 4
-        return {
-            "step": step, "canon": canon, "dedup": dedup, "append": append,
-        }
-
-    def _check_once_traced(self, deadline=None) -> None:
-        """The ``trace=True`` host loop: one wave per iteration, each
-        phase dispatched and timed separately, scalars read back every
-        wave (this is the documented trace cost), the visitor stream
-        delivered from the chunk-state readback.  Overflow flags grow
-        the tripped buffers in place and re-run the chunk, exactly like
-        the fused loop (the aborted wave never commits its append or
-        counters, and the rehash erases its table keys)."""
-        import time as _time
-
-        import jax
-        import jax.numpy as jnp
-
-        opts = self._options
-        cm = self._compiled
-        props = self._properties
-        f = self._max_frontier
-        f_eff = self._step_width()  # the live step-geometry rung
-        cap = self._capacity
-        qcap = self._log_capacity
-        pad = self._block_pad()
-        from .wave_common import two_phase_capable
-
-        two_phase = two_phase_capable(cm)
-        from ..obs.trace import WaveTracer
-
-        tracer = WaveTracer(self._device, "tpu-wavefront")
-        self._tracer = tracer
-        visitor = opts._visitor
-        model = self._model
-        target_depth = opts._target_max_depth or 0
-
-        with jax.default_device(self._device):
-            seed, _run = self._programs()
-            progs = self._traced_programs()
-            init = cm.init_packed()
-            n_init = init.shape[0]
-            if n_init > f:
-                raise ValueError(
-                    f"{n_init} init states exceed the chunk size ({f}); "
-                    "raise spawn_tpu(max_frontier=...) to at least the "
-                    "init-state count (interior levels are unbounded)"
-                )
-            key_hi, key_lo, rows, parent, ebits, stats = seed(
-                jnp.asarray(init.astype(np.uint32)), jnp.uint32(n_init)
-            )
-            stats_h = np.asarray(stats)
-            if int(stats_h[STAT_FLAGS]):
-                raise _OverflowRetry(
-                    1,
-                    "init-state seeding overflowed the fingerprint "
-                    "table; raise spawn_tpu(capacity=...)",
-                )
-            level_start = int(stats_h[STAT_LEVEL_START])
-            level_end = int(stats_h[STAT_LEVEL_END])
-            tail = int(stats_h[STAT_TAIL])
-            depth = 0
-            disc = _device_owned(jnp.asarray(
-                np.full((len(props),), NO_SLOT_HOST, np.uint32)
+        blk = self._block_pad()
+        visitor = self._options._visitor
+        pos = self._visited_to
+        while pos < upto:
+            n = min(blk, upto - pos)
+            block = np.asarray(jax.lax.dynamic_slice(
+                rows, (jnp.uint32(pos * w),), (blk * w,)
             ))
-            disc_h = np.asarray(disc)
-            with self._lock:
-                self._state_count = n_init
-                self._unique_count = int(stats_h[STAT_UNIQUE])
-
-            # Always-on vitals (latency histogram, uniq/s EMA, density,
-            # grow counters) — same registry keys as the fused loop's,
-            # so /.metrics readers see one schema in either mode.
-            from .wave_loop import LoopVitals, journal_geometry
-
-            vitals = LoopVitals(
-                self._metrics, initial_unique=self._unique_count,
-                initial_states=self._state_count,
-            )
-            journal_geometry(self)
-            wave_idx = 0
-            while level_start < level_end:
-                if target_depth and depth >= target_depth - 1:
-                    # The next wave would expand states at depth+1; the
-                    # reference counts-but-never-expands target-depth
-                    # states (same gate as the fused wave_cond).
-                    break
-                count = min(level_end - level_start, f_eff)
-                t0 = _time.perf_counter()
-                disc_prev = disc  # t_step does not donate it
-                # xprof hook (obs/timeline.py): under --xprof-dir each
-                # traced wave's device phases land in a
-                # StepTraceAnnotation so the hardware profile's steps
-                # line up with the journal's wave events; a nullcontext
-                # otherwise.
-                from ..obs.timeline import step_annotation
-                with step_annotation(wave_idx):
-                    (
-                        disc, eb, states, cand_rows, cand_src, cand_act,
-                        n_valid_d, v_ovf_d, gen_d, stepflag_d,
-                    ) = progs["step"](
-                        rows, ebits, disc_prev,
-                        jnp.uint32(level_start), jnp.uint32(level_end),
-                    )
-                    jax.block_until_ready(cand_rows)
-                    t1 = _time.perf_counter()
-                    hi, lo = progs["fp"](cand_rows)
-                    jax.block_until_ready(lo)
-                    t2 = _time.perf_counter()
-                    (
-                        key_hi, key_lo, u_new, u_origin, n_new_d,
-                        probe_ok_d, dd_ovf_d, rounds_d,
-                    ) = progs["insert"](key_hi, key_lo, hi, lo, cand_act)
-                    jax.block_until_ready(key_lo)
-                t3 = _time.perf_counter()
-                # Host readback: the per-wave scalar sync, plus the chunk
-                # states when a visitor is attached (the device visitor
-                # stream), plus the visitor callbacks themselves.
-                n_new = int(np.asarray(n_new_d))
-                generated = int(np.asarray(gen_d))
-                rounds = self._count_stage_rounds(np.asarray(rounds_d))
-                flags = 0
-                if (
-                    not bool(np.asarray(probe_ok_d))
-                    or (self._unique_count + n_new) * 2 > cap
-                ):
-                    flags |= 1
-                if tail + n_new > qcap:
-                    flags |= 2
-                if bool(np.asarray(dd_ovf_d)) or bool(np.asarray(v_ovf_d)):
-                    flags |= 4
-                if bool(np.asarray(stepflag_d)):
-                    flags |= 8
-                if f_eff < f and level_end - level_start > f_eff:
-                    # Step-rung clamp (the fused wave_body's flag 128,
-                    # host-computed here): the remaining level exceeds
-                    # the chunk rung — abort, climb, re-run.
-                    flags |= 128
-                disc_h = np.asarray(disc)
-                if visitor is not None and flags == 0:
-                    states_h = np.asarray(states)
-                    for i in range(count):
-                        visitor.visit(
-                            model,
-                            Path([(cm.decode(states_h[i]), None)]),
-                        )
-                t4 = _time.perf_counter()
-                if flags & 8:
-                    raise RuntimeError(
-                        "the model step kernel flagged an encoding-"
-                        "capacity overflow (a successor exceeded the "
-                        "packed layout's bounds); the compiled model's "
-                        "capacity assumptions do not hold for this "
-                        "configuration"
-                    )
-                if flags and (
-                    self._stop_requested.is_set()
-                    or (deadline is not None
-                        and _time.monotonic() >= deadline)
-                ):
-                    # Growth costs a rehash + re-run; a run already past
-                    # its budget (or asked to stop) keeps its partial
-                    # result instead (the fused loop's policy).  The
-                    # aborted wave's discoveries still REVERT (same rule
-                    # as the growth branch below): the final snapshot
-                    # must not persist a discovery from a wave that
-                    # never committed, or a resume would run with its
-                    # awaiting mask pruned and diverge from an
-                    # uninterrupted run.  Its table writes are erased
-                    # the same way (the fused loop's _wl_abort_cleanup):
-                    # persisted aborted keys would make a resume drop
-                    # the wave's states as duplicates.
-                    disc = disc_prev
-                    disc_h = np.asarray(disc_prev)
-                    key_hi, key_lo = self._rehash(rows, tail)
-                    break
-                if flags:
-                    # Same IN-PLACE auto-tune growth as the fused loop
-                    # (one shared helper, so recovery semantics cannot
-                    # drift): this wave's append and counters have not
-                    # committed (both are gated below on flags == 0),
-                    # and the rehash erases any keys the aborted insert
-                    # wrote — the chunk simply re-runs at the grown
-                    # geometry.  ``disc`` REVERTS to its pre-wave value,
-                    # mirroring the fused loop's on-device
-                    # `where(commit, disc, disc_prev)`: a kept discovery
-                    # would change the re-run's awaiting mask (wave_eval
-                    # prunes expansion once a property is discovered)
-                    # and generate different successors than a committed
-                    # execution of the same wave.
-                    disc = disc_prev
-                    rows, parent, ebits, key_hi, key_lo, qcap, pad = (
-                        self._grow_on_flags(
-                            flags, qcap, pad, rows, parent, ebits,
-                            tail, self._unique_count, depth,
-                        )
-                    )
-                    cap = self._capacity
-                    f = self._max_frontier  # dd growth may halve it
-                    f_eff = self._step_width()  # rung climbs move it
-                    progs = self._traced_programs()
-                    vitals.record_overflow_recovery()
-                    continue
-                rows, parent, ebits = progs["append"](
-                    rows, parent, ebits, cand_rows, cand_src, eb, u_new,
-                    u_origin, jnp.uint32(level_start), jnp.uint32(tail),
-                )
-                jax.block_until_ready(ebits)
-                t5 = _time.perf_counter()
-
-                tail += n_new
-                level_start += count
-                if level_start >= level_end:
-                    depth += 1
-                    level_end = tail
-                remaining = level_end - level_start
-                with self._lock:
-                    self._state_count += generated
-                    self._unique_count += n_new
-                    self._max_depth = depth + (1 if remaining else 0)
-                    for p, prop in enumerate(props):
-                        if int(disc_h[p]) != NO_SLOT_HOST:
-                            self._discovery_slots.setdefault(
-                                prop.name, int(disc_h[p])
-                            )
-                wave_idx += 1
-                phases = {
-                    "step": t1 - t0,
-                    "canon": t2 - t1,
-                    "dedup": t3 - t2,
-                    "append": t5 - t4,
-                    "readback": t4 - t3,
-                }
-                enrich = tracer.record_wave(
-                    phases, self._traced_wave_bytes(rounds, two_phase),
-                    probe_rounds=rounds,
-                )
-                vitals.record_quantum(
-                    t5 - t0, 1, self._unique_count, committed=True,
-                    states=self._state_count,
-                    cand_lanes=self._wl_cand_lanes(),
-                    occupancy=self._unique_count / cap,
-                )
-                vitals.record_host(phases["readback"])
-                if self._journal:
-                    self._journal.append(
-                        "wave",
-                        waves=wave_idx,
-                        remaining=remaining,
-                        tail=tail,
-                        unique=self._unique_count,
-                        states=self._state_count,
-                        depth=depth,
-                        flags=0,
-                        call_sec=round(t5 - t0, 6),
-                        occupancy=round(self._unique_count / cap, 6),
-                        **(
-                            {"density": round(vitals.last_density, 6)}
-                            if vitals.last_density is not None else {}
-                        ),
-                        **enrich,
-                    )
-                self._metrics.update(
-                    waves=wave_idx,
-                    table_occupancy=round(self._unique_count / cap, 6),
-                    last_call_sec=round(t5 - t0, 6),
-                )
-                self._metrics.inc("device_call_sec_total", t5 - t0)
-                self._metrics.inc("device_calls", 1)
-
-                # Density-driven sort-rung downshift and frontier-driven
-                # step-rung downshift, per committed wave (the traced
-                # analogue of the fused loop's between-quanta retunes);
-                # a rung change re-keys the phase programs.
-                from .wave_loop import maybe_retune_sort, maybe_retune_step
-
-                retuned = maybe_retune_sort(self, vitals.last_density)
-                if maybe_retune_step(self, remaining or None):
-                    retuned = True
-                if retuned:
-                    f_eff = self._step_width()
-                    progs = self._traced_programs()
-
-                # Shared termination tail (wave_loop.py): the same
-                # predicate order as the fused loop by construction.
-                from .wave_loop import loop_should_break
-
-                if loop_should_break(self, remaining, depth, deadline):
-                    break
-
-            # Same snapshot-ready tail as the fused loop: device tables
-            # for path reconstruction, a carry for save_snapshot, the
-            # final checkpoint, and the engine_done journal record.
-            self._tables_dev = (parent, rows)
-            stats_final = host_stats(
-                level_start, level_end, tail, self._state_count,
-                self._unique_count, depth, disc_h,
-            )
-            if self._journal:
-                self._journal.append("trace_summary", **tracer.summary())
-            from .wave_loop import finalize_run
-
-            finalize_run(self, self._carry_from(
-                key_hi, key_lo, rows, parent, ebits, stats_final
-            ))
+            for row in block[: n * w].reshape(n, w):
+                visitor.visit(self._model, Path([(cm.decode(row), None)]))
+            pos += n
+        self._visited_to = upto
 
     def _carry_from(self, key_hi, key_lo, rows, parent, ebits, stats_h):
         """Full run state as one dict — the ``save_snapshot`` npz layout
@@ -2170,7 +1658,7 @@ class TpuChecker(Checker):
         """Engine kwargs right-sized to THIS run's final counts, so a
         fresh spawn of the same workload runs without any auto-tune
         growth pauses: a default-knob discovery run, then a measured run
-        with the returned sizes (the bench.py pattern).  The table gets
+        with the returned sizes (what the knob cache stores).  The table gets
         ≥2× the unique count (50% max load), the row log the exact count
         plus safety slack."""
         self.join()
@@ -2251,7 +1739,6 @@ class TpuChecker(Checker):
         out.update(
             engine="tpu-wavefront",
             device=str(self._device),
-            trace=self._trace,
             capacity=self._capacity,
             log_capacity=self._log_capacity,
             max_frontier=self._max_frontier,
@@ -2305,21 +1792,7 @@ class TpuChecker(Checker):
         hists = self._metrics.snapshot_histograms()
         if hists:
             out["histograms"] = hists
-        if self._tracer is not None:
-            out["trace_summary"] = self._tracer.summary()
         return out
-
-    def trace_summary(self) -> dict:
-        """The finished traced run's roofline reduction: per-phase
-        seconds (``wave_breakdown``), modeled bytes, and
-        ``hbm_util_frac`` against the device's peak table.  Requires
-        ``trace=True``."""
-        self.join()
-        if self._tracer is None:
-            raise RuntimeError(
-                "trace_summary() requires spawn_tpu(trace=True)"
-            )
-        return self._tracer.summary()
 
     def _rehash_program(self):
         """Device program inserting one row-log chunk's fingerprints into

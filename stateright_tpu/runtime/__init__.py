@@ -21,7 +21,7 @@ PAPERS.md):
 - :mod:`compile_cache` — the one fixed place JAX's persistent
   compilation cache lives, shared by every entry point;
 - :mod:`knob_cache` — persisted ``tuned_kwargs`` keyed by (workload,
-  model, device, engine geometry), so bench rounds and the service
+  model, device, engine geometry), so the service and fleet workers
   reload discovered engine knobs instead of re-paying the ~21-minute
   auto-tune discovery (VERDICT r5 weak #2);
 - :mod:`chaos` — deterministic fault injection for the *actor* runtime

@@ -61,12 +61,12 @@ def run_child(run_dir: str) -> int:
         checkpoint = os.path.join(run_dir, CHECKPOINT_FILE)
         engine_kwargs = dict(spec.engine_kwargs)
         engine_kwargs.update(relax)
-        # Traced children never resume: the engines refuse trace=True +
-        # resume_from (tracing is a diagnostic mode), and a restart that
-        # passed both would die in __init__ on every attempt — burning
-        # the supervisor's restarts in seconds.  A traced child restarts
-        # from scratch instead; its journal still carries every
-        # completed wave's trace records.
+        # Traced children never resume: the tiered engine refuses
+        # trace=True + resume_from (tracing is a diagnostic mode), and a
+        # restart that passed both would die in __init__ on every
+        # attempt — burning the supervisor's restarts in seconds.  A
+        # traced child restarts from scratch instead; its journal still
+        # carries every completed wave's trace records.
         resumed = (
             bool(cfg.get("resume", True))
             and os.path.exists(checkpoint)

@@ -22,13 +22,11 @@ wall clocks step mid-run.  Readers never see it unless they ask
 Engine events: ``resume``, ``wave``, ``checkpoint``, ``grow``,
 ``geometry`` (the run's live knobs, once per loop start), ``compile``
 (program-cache misses with first-call timing + key provenance,
-parallel/wave_common.py), ``engine_done``, and — traced runs only —
-``trace_summary``.  Under
-``trace=True`` each ``wave`` event is enriched with ``wave_breakdown``
-(per-phase seconds), ``bytes`` (modeled bytes touched), and
-``hbm_util_frac`` (plus measured ``exchange_payload_bytes`` /
-``exchange_occupancy`` on the sharded engine) — the journal doubles as
-the wave-trace stream (docs/OBSERVABILITY.md).  Child events: ``run_start``, ``run_end``,
+parallel/wave_common.py), ``engine_done``, and — traced tiered runs
+only — ``trace_summary``.  Under ``spawn_tpu_tiered(trace=True)`` each
+``wave`` event is enriched with ``wave_breakdown`` (per-phase seconds),
+``bytes`` (modeled bytes touched), and ``hbm_util_frac`` — the journal
+doubles as the wave-trace stream (docs/OBSERVABILITY.md).  Child events: ``run_start``, ``run_end``,
 ``child_error``.  Supervisor events: ``supervisor_start``, ``crash``,
 ``hang``, ``relax``, ``restart``, ``wall_timeout``, ``give_up``,
 ``supervisor_done``.  Chaos-runtime events (``runtime/chaos.py``, see

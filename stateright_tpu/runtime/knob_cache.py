@@ -1,14 +1,13 @@
 """Persisted engine-knob cache: discovered ``tuned_kwargs`` survive the
 process.
 
-bench.py's measurement protocol discovers right-sized engine knobs with a
-default-knob auto-tune run before every measured run.  Discovery is the
-expensive half — ~21 minutes for the 61.5M-state ``2pc check 10`` — and
-was re-paid by every round because the result never left the process
-(VERDICT r5 weak #2).  This cache stores each workload's tuned kwargs
-as one JSON object keyed by (workload, model identity, device, engine
-geometry), under a directory that doubles as the bench's checkpoint
-dir; later rounds, fleet workers and the service reload instead of
+A default-knob auto-tune run discovers right-sized engine knobs
+(``tuned_kwargs()``).  Discovery is the expensive half — ~21 minutes for
+the 61.5M-state ``2pc check 10`` — and would be re-paid by every
+process if the result never left it.  This cache stores each workload's
+tuned kwargs as one JSON object keyed by (workload, model identity,
+device, engine geometry), under a directory of the caller's choosing;
+fleet workers and the checking service reload instead of
 rediscovering.
 
 Staleness is harmless by construction: the engines' auto-tune grows
@@ -71,13 +70,11 @@ def _path(cache_dir: str) -> str:
 def knob_key(label: str, engine: str = SINGLE_CHIP_ENGINE) -> str:
     """The canonical cache key: workload label + device identity +
     engine/protocol version (geometry defaults change what discovery
-    finds).  One definition shared by bench.py and the checking service
-    (serve/scheduler.py) so the key FORMAT cannot drift; their label
-    namespaces stay deliberately disjoint ("2pc_check_5" vs
-    "serve:twophase:5") because the two discover different things —
-    bench persists auto-tune-shrunk measurement sizes, the service its
-    jobs' exact final spawn geometry.  Imports jax lazily — callers
-    already run on a device."""
+    finds).  One definition shared by every caller (the checking
+    service, serve/scheduler.py, and the fleet workers) so the key
+    FORMAT cannot drift; each caller keeps its own label namespace
+    (e.g. "serve:twophase:5").  Imports jax lazily — callers already
+    run on a device."""
     import jax
 
     d = jax.devices()[0]

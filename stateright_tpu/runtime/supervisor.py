@@ -24,14 +24,14 @@ This is the swarm-verification / TLC-checkpointing recipe (PAPERS.md):
 restartable workers plus durable progress state.
 
 Observability: the child's ``result.json`` carries the checker's full
-``metrics()`` snapshot, and a child spawned with ``trace=True`` in its
-engine kwargs streams enriched per-wave trace records (and a final
-``trace_summary`` event) into the run dir's ``journal.jsonl`` — the
-wave-trace artifact (docs/OBSERVABILITY.md).  ``relax_geometry`` never
-touches ``trace``: backoff changes tuning knobs only, and whether a run
-is traced is a user decision, not a geometry.  Traced children never
-RESUME (the engines refuse trace+resume); a restarted traced child
-starts from scratch, keeping its journaled trace records.
+``metrics()`` snapshot, and a tiered child spawned with ``trace=True``
+in its engine kwargs streams enriched per-wave trace records (and a
+final ``trace_summary`` event) into the run dir's ``journal.jsonl`` —
+the wave-trace artifact (docs/OBSERVABILITY.md).  ``relax_geometry``
+never touches ``trace``: backoff changes tuning knobs only, and whether
+a run is traced is a user decision, not a geometry.  Traced children
+never RESUME (the tiered engine refuses trace+resume); a restarted
+traced child starts from scratch, keeping its journaled trace records.
 """
 
 from __future__ import annotations
@@ -157,7 +157,7 @@ class CheckSpec:
     """A supervised check, in picklable form (the child rebuilds it in a
     fresh process).  ``model_factory`` must be a module-level callable —
     e.g. a model class, ``functools.partial`` over one, or a helper like
-    ``bench.paxos_model`` — because lambdas do not pickle."""
+    ``serve.workloads.build_model`` — because lambdas do not pickle."""
 
     model_factory: Callable
     factory_args: tuple = ()

@@ -27,8 +27,9 @@ along.)
 
 The host loop IS the shared :class:`~stateright_tpu.parallel.wave_loop.
 FusedWaveLoop` core: the engine adapts one host-driven wave per
-``_wl_call`` (per-wave sync is the documented cost of the mode, like
-``trace=True``), spills ride the core's ``_wl_after_commit`` rung, and
+``_wl_call`` through its own phase programs (per-wave sync is the
+documented cost of the mode), spills ride the core's
+``_wl_after_commit`` rung, and
 overflow flags 2/4 reuse the shared in-place growth rules while flag 1
 (table overfull) SPILLS instead of growing — the budget is a hard cap.
 ``spill`` / ``cold_probe`` events carry bytes and pass counts for the
@@ -123,18 +124,20 @@ class TieredTpuChecker(TpuChecker):
         that triggers an LSM merge.  ``cold_dir``: optional directory —
         when set, runs live on disk memory-mapped (the disk tier).
 
-        ``trace=True`` produces per-wave phase breakdowns like the
-        in-HBM engine's traced mode — the tiered loop already dispatches
-        the traced-mode phase kernels separately, so tracing only adds
-        the per-phase sync, not a mode switch.  The phase set gains
-        ``cold_probe`` (the pre-commit merge-join; host-classed like
-        ``readback``, obs/trace.py).  Visitors stay unsupported (they
-        require the base traced readback path)."""
-        # Intercepted, NOT forwarded: base trace=True would dispatch
-        # _check_once_traced, which knows nothing of the tiers.  The
-        # tiered loop does its own phase timing in _wl_call instead.
+        ``trace=True`` produces per-wave phase breakdowns: the tiered
+        loop already dispatches its phase programs separately, so
+        tracing only adds the per-phase sync, not a mode switch — the
+        timings are of the program this engine runs.  Each phase is
+        charged modeled bytes against the device's peak HBM bandwidth
+        (obs/roofline.py); the phases are step / canon / dedup /
+        readback / ``cold_probe`` (the pre-commit merge-join;
+        host-classed like ``readback``, obs/trace.py) / append.
+        Tracing surfaces: enriched ``wave`` journal records, a
+        ``trace_summary`` journal event, ``metrics()``, and
+        ``trace_summary()``.  Visitors are unsupported."""
         self._t_trace = bool(kwargs.pop("trace", False))
         self._t_trace_last = None
+        self._tracer = None  # the WaveTracer of a trace=True run
         if self._t_trace and kwargs.get("resume_from") is not None:
             raise ValueError(
                 "spawn_tpu_tiered(trace=True) does not support "
@@ -143,9 +146,8 @@ class TieredTpuChecker(TpuChecker):
             )
         if options._visitor is not None:
             raise ValueError(
-                "spawn_tpu_tiered() does not support visitors (they "
-                "require the traced readback path); use spawn_tpu for "
-                "visitor-instrumented runs"
+                "spawn_tpu_tiered() does not support visitors; use "
+                "spawn_tpu for visitor-instrumented runs"
             )
         if not 0.0 < float(spill_threshold) <= 0.5:
             raise ValueError(
@@ -198,6 +200,211 @@ class TieredTpuChecker(TpuChecker):
         if flag & 1:
             return None
         return super()._grow(flag)
+
+    # --- phase programs (the in-HBM wave's kernels, dispatched apart) --------
+
+    def _phase_programs(self):
+        """The tiered wave's phase programs, cached like every other
+        program set.  The key covers everything the closures trace over;
+        host-driven knobs (finish_when, target depth) are NOT baked in —
+        the tiered loop decides them per wave on the host."""
+        key = (
+            "phases",
+            self._compiled.cache_key(),
+            hasattr(self._compiled, "step_valid")
+            and hasattr(self._compiled, "step_lane"),
+            self._canon is not None,
+            self._max_frontier,
+            self._dedup_factor,
+            self._sortless,  # the dedup path is a trace-time branch
+            self._sort_width(),  # the live sort-geometry rung
+            self._step_width(),  # the live step-geometry rung
+            self._block_pad(),
+            tuple(p.expectation for p in self._properties),
+        )
+        from ..parallel.wave_common import cached_program
+
+        return cached_program(
+            _PROGRAM_CACHE, _PROGRAM_CACHE_MAX, key,
+            self._build_phase_programs,
+            label=f"{type(self).__name__}.phases",
+            journal=self._journal,
+            provenance=self._key_provenance(),
+        )
+
+    def _build_phase_programs(self):
+        """The wave as four separately-dispatched phase programs — the
+        SAME kernels as the in-HBM engine's fused ``wave_body``, cut at
+        the phase boundaries the roofline models (step kernel /
+        canon+fingerprint / dedup-sort+probe / append), so the cold
+        merge-join can run between insert and append and ``trace=True``
+        can time each phase with ``block_until_ready``.  Commit order,
+        dedup keys, position assignment, and discovery folding are
+        identical to the fused path; level/depth bookkeeping lives on
+        the host (one sync per wave is the tiered mode's cost)."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops.device_fp import device_fp64
+        from ..parallel.hashset import (
+            HashSet, compact_valid_indices, insert_batch_claim,
+            insert_batch_compact,
+        )
+        from ..parallel.wave_common import compact, wave_eval
+
+        cm = self._compiled
+        w = cm.state_width
+        fpw = cm.fp_words or w
+        canon = self._canon
+        a = cm.max_actions
+        f_eff = self._step_width()  # the live step-geometry rung
+        pad = self._block_pad()
+        dedup_factor = self._dedup_factor
+        sortless = self._sortless  # the dedup path (claim vs sort)
+        sort_lanes = (
+            None if self._sort_lanes is None else self._sort_width()
+        )
+        props = self._properties
+        ev_indices = self._ev_indices
+
+        @jax.jit
+        def t_step(rows, ebits, disc, level_start, level_end):
+            count = jnp.minimum(level_end - level_start, jnp.uint32(f_eff))
+            lane = jnp.arange(f_eff, dtype=jnp.uint32)
+            active = lane < count
+            ids = level_start + lane
+            states = jax.lax.dynamic_slice(
+                rows, (level_start * jnp.uint32(w),), (f_eff * w,)
+            ).reshape(f_eff, w)
+            eb_chunk = jax.lax.dynamic_slice(
+                ebits, (level_start,), (f_eff,)
+            )
+            disc, eb, nexts, valid, generated, step_flag = wave_eval(
+                cm, props, ev_indices, states, active, ids, eb_chunk,
+                disc, allow_two_phase=True,
+            )
+            flat_valid = valid.reshape(f_eff * a)
+            v_orig, v_act, n_valid, v_overflow = compact_valid_indices(
+                flat_valid, dedup_factor, sort_lanes=sort_lanes
+            )
+            if nexts is None:
+                # Two-phase: construct successors only for the compacted
+                # valid lanes (the fused path's phase B).
+                src_state = v_orig // jnp.uint32(a)
+                cand_rows, _vu, lane_flags_u = jax.vmap(cm.step_lane)(
+                    states[src_state], v_orig % jnp.uint32(a)
+                )
+                step_flag = step_flag | jnp.any(lane_flags_u & v_act)
+                cand_src = src_state
+            else:
+                # Single-phase: compact the constructed rows.  Same keys
+                # and representatives as the fused compact_valid-on-keys
+                # order (compaction preserves lane order).
+                cand_rows = nexts.reshape(f_eff * a, w)[v_orig]
+                cand_src = v_orig // jnp.uint32(a)
+            return (
+                disc, eb, states, cand_rows, cand_src, v_act,
+                n_valid, v_overflow, generated, step_flag,
+            )
+
+        @jax.jit
+        def t_fp(cand_rows):
+            rows_c = (
+                cand_rows if canon is None else jax.vmap(canon)(cand_rows)
+            )
+            return device_fp64(rows_c[:, :fpw])
+
+        @partial(jax.jit, donate_argnums=(0, 1))
+        def t_insert(key_hi, key_lo, hi, lo, cand_act):
+            if sortless:
+                (
+                    table, _u_slot, u_new, u_origin, _u_active, probe_ok,
+                    dd_overflow, rounds,
+                ) = insert_batch_claim(
+                    HashSet(key_hi, key_lo), hi, lo, cand_act,
+                    with_rounds=True,
+                )
+            else:
+                (
+                    table, _u_slot, u_new, u_origin, _u_active, probe_ok,
+                    dd_overflow, rounds,
+                ) = insert_batch_compact(
+                    HashSet(key_hi, key_lo), hi, lo, cand_act,
+                    dedup_factor=1, with_rounds=True,
+                )
+            n_new = jnp.sum(u_new, dtype=jnp.uint32)
+            return (
+                table.key_hi, table.key_lo, u_new, u_origin, n_new,
+                probe_ok, dd_overflow, rounds,
+            )
+
+        @partial(jax.jit, donate_argnums=(0, 1, 2))
+        def t_append(rows, parent, ebits, cand_rows, cand_src, eb, u_new,
+                     u_origin, level_start, tail):
+            u = u_new.shape[0]
+            sel = compact(u_new, jnp.arange(u, dtype=jnp.uint32), pad)
+            sel_u = u_origin[sel]
+            rows_blk = cand_rows[sel_u]
+            src_state = cand_src[sel_u]
+            par_blk = level_start + src_state
+            eb_blk = eb[src_state]
+            rows = jax.lax.dynamic_update_slice(
+                rows, rows_blk.reshape(-1), (tail * jnp.uint32(w),)
+            )
+            parent = jax.lax.dynamic_update_slice(parent, par_blk, (tail,))
+            ebits = jax.lax.dynamic_update_slice(ebits, eb_blk, (tail,))
+            return rows, parent, ebits
+
+        return {
+            "step": t_step, "fp": t_fp, "insert": t_insert,
+            "append": t_append,
+        }
+
+    def _phase_wave_bytes(self, probe_rounds: int, two_phase: bool) -> dict:
+        """Modeled HBM bytes touched by one traced wave, per phase
+        (obs/roofline.py documents the model and its biases).  Buffer-
+        proportional, not count-proportional: the device streams full
+        fixed-width buffers regardless of how many lanes are live, so
+        charging the full widths is what matches what HBM actually
+        moves.  The chunk/candidate widths are the LIVE step rung
+        (``_step_width``) — ``bytes.step`` drops in proportion to it —
+        and the compact/canon/dedup widths the LIVE compact width, so
+        ``bytes.dedup`` drops with the sort rung.  On the sortless
+        default path ``bytes.dedup`` carries NO sort term at all (the
+        claim election probes, it never sorts)."""
+        from ..obs.roofline import copy_bytes, probe_bytes, sort_bytes
+
+        cm = self._compiled
+        w = cm.state_width
+        fpw = cm.fp_words or w
+        a = cm.max_actions
+        f_eff = self._step_width()
+        b = f_eff * a
+        u_sz = self._sort_width()
+        pad = self._block_pad()
+        # step: chunk read + candidate construction + the valid-lane
+        # index compaction scan.  Two-phase constructs only U rows (and
+        # gathers their U parents); single-phase materializes all B.
+        step = f_eff * w * 4 + b * 4 + copy_bytes(u_sz, w)
+        if not two_phase:
+            step += b * w * 4
+        canon = (copy_bytes(u_sz, w) if self._canon is not None else 0)
+        canon += u_sz * fpw * 4 + 2 * u_sz * 4
+        if self._sortless:
+            # Claim election: probe rounds over the compact width plus
+            # the claim-plane scatter/readback — no sort planes, no
+            # representative re-compaction.
+            dedup = probe_bytes(u_sz, probe_rounds) + 2 * u_sz * 4
+        else:
+            dedup = (
+                sort_bytes(u_sz, 3)
+                + probe_bytes(u_sz, probe_rounds)
+                + 4 * u_sz * 4  # representative compaction planes
+            )
+        append = copy_bytes(pad, w) + 2 * copy_bytes(pad, 1) + u_sz * 4
+        return {
+            "step": step, "canon": canon, "dedup": dedup, "append": append,
+        }
 
     # --- the tiered wave (one _wl_call) ---------------------------------------
 
@@ -335,11 +542,11 @@ class TieredTpuChecker(TpuChecker):
         return fresh, n_fresh, acct
 
     def _wl_call(self, carry):
-        """One tiered wave: the traced-mode phase programs (step /
-        fingerprint / hot insert / append — the SAME kernels as the
-        fused loop) with the cold merge-join between insert and append.
-        Host bookkeeping commits only at flags == 0, exactly like the
-        traced loop; an aborted wave leaves every counter and buffer
+        """One tiered wave: the phase programs (step / fingerprint / hot
+        insert / append — the SAME kernels as the fused loop) with the
+        cold merge-join between insert and append.  Host bookkeeping
+        commits only at flags == 0, like the fused program's on-device
+        commit gate; an aborted wave leaves every counter and buffer
         (except the hot table, which recovery rebuilds or spills) at
         its pre-wave state."""
         import jax.numpy as jnp
@@ -360,14 +567,16 @@ class TieredTpuChecker(TpuChecker):
             self._t_flags = 0
             self._t_cold_last = None
             return carry
-        progs = self._traced_programs()
-        f_eff = self._step_width()  # the live step-geometry rung
-        count = min(self._t_level_end - self._t_level_start, f_eff)
-        disc_prev = self._t_disc  # t_step does not donate it
         trace = self._t_trace
         if trace:
             import jax
 
+            t_entry = time.perf_counter()
+        progs = self._phase_programs()
+        f_eff = self._step_width()  # the live step-geometry rung
+        count = min(self._t_level_end - self._t_level_start, f_eff)
+        disc_prev = self._t_disc  # t_step does not donate it
+        if trace:
             t = [time.perf_counter()]
         (
             disc, eb, _states, cand_rows, cand_src, cand_act,
@@ -440,27 +649,6 @@ class TieredTpuChecker(TpuChecker):
             if trace:
                 jax.block_until_ready(ebits)
                 t.append(time.perf_counter())
-                from ..parallel.wave_common import two_phase_capable
-
-                phases = {
-                    "step": t[1] - t[0],
-                    "canon": t[2] - t[1],
-                    "dedup": t[3] - t[2],
-                    "readback": t[4] - t[3],
-                    "cold_probe": t[5] - t[4],
-                    "append": t[6] - t[5],
-                }
-                # Modeled device bytes: the base phase model (these ARE
-                # the base phase kernels) — cold_probe bytes stay out of
-                # the HBM model (host-classed, obs/trace.py) and ride
-                # the cold accounting instead.
-                self._t_trace_last = self._tracer.record_wave(
-                    phases,
-                    self._traced_wave_bytes(
-                        rounds,
-                        two_phase_capable(self._compiled),
-                    ),
-                )
             self._hot_entries += n_new_hot
             self._t_tail += n_fresh
             self._t_unique += n_fresh
@@ -492,6 +680,33 @@ class TieredTpuChecker(TpuChecker):
         self._t_disc_h = np.asarray(self._t_disc)
         self._t_flags = flags
         self._t_cold_last = cold
+        if trace and flags == 0:
+            from ..parallel.wave_common import two_phase_capable
+
+            # The phases partition the call: host work before the step
+            # dispatch and after the append counts as readback.
+            phases = {
+                "step": t[1] - t[0],
+                "canon": t[2] - t[1],
+                "dedup": t[3] - t[2],
+                "readback": (
+                    (t[4] - t[3]) + (t[0] - t_entry)
+                    + (time.perf_counter() - t[6])
+                ),
+                "cold_probe": t[5] - t[4],
+                "append": t[6] - t[5],
+            }
+            # Modeled device bytes: the in-HBM phase model (these ARE
+            # its kernels) — cold_probe bytes stay out of the HBM model
+            # (host-classed, obs/trace.py) and ride the cold accounting
+            # instead.
+            self._t_trace_last = self._tracer.record_wave(
+                phases,
+                self._phase_wave_bytes(
+                    rounds, two_phase_capable(self._compiled),
+                ),
+                probe_rounds=rounds,
+            )
         return (key_hi, key_lo, rows, parent, ebits)
 
     def _wl_view(self, carry):
@@ -512,7 +727,7 @@ class TieredTpuChecker(TpuChecker):
             extra["cold_bytes"] = self._t_cold_last["bytes"]
         if self._t_trace_last is not None:
             # Traced runs: the wave's phase breakdown rides the shared
-            # loop's journal "wave" event, like the base traced loop.
+            # loop's journal "wave" event.
             extra.update(self._t_trace_last)
         return WaveView(
             waves_this_call=1,
@@ -971,7 +1186,22 @@ class TieredTpuChecker(TpuChecker):
             cold_bytes=self._cold.nbytes,
             hot_entries=self._hot_entries,
             spill_tail=self._spill_tail,
+            trace=self._t_trace,
         )
         if self._memory_budget_mb is not None:
             out["memory_budget_mb"] = self._memory_budget_mb
+        if self._tracer is not None:
+            out["trace_summary"] = self._tracer.summary()
         return out
+
+    def trace_summary(self) -> dict:
+        """The finished traced run's roofline reduction: per-phase
+        seconds (``wave_breakdown``), modeled bytes, and
+        ``hbm_util_frac`` against the device's peak table.  Requires
+        ``trace=True``."""
+        self.join()
+        if self._tracer is None:
+            raise RuntimeError(
+                "trace_summary() requires spawn_tpu_tiered(trace=True)"
+            )
+        return self._tracer.summary()

@@ -26,9 +26,10 @@ re-keying pass (tiered/reshard.py) can translate to a different mesh
 width, something the base engine's ``shard << slot_bits | slot`` ids
 cannot do.
 
-The host drives one wave per ``_wl_call`` through the base engine's
-traced-mode phase programs (step / canon / prededup / exchange /
-insert), with the cold filter between insert and append — the same
+The host drives one wave per ``_wl_call`` through phase programs cut
+from the in-HBM sharded engine's fused body (step / canon / prededup /
+exchange / insert / append), with the cold filter between insert and
+append — the same
 shape as the single-chip tiered loop, under the shared
 :class:`FusedWaveLoop`.  Snapshots embed the full per-shard tier state
 (``ts_*`` keys); key planes are NOT persisted — a resume rebuilds them
@@ -49,6 +50,7 @@ from ..parallel.sharded import (
     _PROGRAM_CACHE_MAX,
     NO_GID,
     ShardedTpuChecker,
+    _owner_mix,
     _owner_mix_host_np,
 )
 from .cold_store import ColdStore
@@ -78,16 +80,13 @@ class TieredShardedTpuChecker(ShardedTpuChecker):
         directory, so concurrent spills cannot clobber or cross-adopt
         runs (tests/test_tiered.py pins this).
 
-        ``trace=True`` is refused like the single-chip tiered engine:
-        this loop is already host-driven per wave; trace the tiered
-        single-chip engine (``spawn_tpu_tiered(trace=True)``) or the
-        plain sharded engine instead."""
-        if kwargs.get("trace"):
+        ``trace=True`` is refused: the phase-timed breakdown exists
+        on the single-chip tiered engine only
+        (``spawn_tpu_tiered(trace=True)``)."""
+        if kwargs.pop("trace", False):
             raise ValueError(
                 "spawn_tpu_tiered_sharded(trace=True) is not supported: "
-                "the tiered-sharded loop is already host-driven per "
-                "wave; run the roofline trace on spawn_tpu_tiered or "
-                "spawn_tpu_sharded instead"
+                "run the phase-timed trace on spawn_tpu_tiered instead"
             )
         if not 0.0 < float(spill_threshold) <= 0.5:
             raise ValueError(
@@ -169,8 +168,9 @@ class TieredShardedTpuChecker(ShardedTpuChecker):
         """The engine-specific phase programs (step over the row log,
         fresh-masked append, spill segment fingerprinting, plane rebuild
         and clear), cached like every other program set.  canon /
-        prededup / exchange / insert are REUSED from the base engine's
-        traced set — identical kernels, one definition."""
+        prededup / exchange / insert live in :meth:`_phase_programs`,
+        keyed without the log geometry, so a log growth does not
+        rebuild them."""
         key = (
             "tiered-sharded",
             self._compiled.cache_key(),
@@ -337,6 +337,172 @@ class TieredShardedTpuChecker(ShardedTpuChecker):
             "clear": sharded(clear_shard, 3, donate=(0, 1)),
         }
 
+    def _phase_programs(self):
+        """The canon / prededup / exchange / insert phase programs —
+        the in-HBM sharded engine's fused-body kernels, dispatched apart
+        so the cold filter can run between insert and append — cached
+        like every other program set."""
+        key = (
+            "phases",
+            self._compiled.cache_key(),
+            hasattr(self._compiled, "step_valid")
+            and hasattr(self._compiled, "step_lane"),
+            self._canon is not None,
+            self._cap_s,
+            self._chunk,
+            self._dedup_factor,
+            self._sortless,  # the dedup path is a trace-time branch
+            self._sort_width(),  # the live sort-geometry rung
+            self._step_width(),  # the live step-geometry rung
+            self._bucket_slack,  # shapes the exchange buckets
+            tuple((d.platform, d.id) for d in self._mesh.devices.flat),
+            tuple(p.expectation for p in self._properties),
+        )
+        from ..parallel.wave_common import cached_program
+
+        return cached_program(
+            _PROGRAM_CACHE, _PROGRAM_CACHE_MAX, key,
+            self._build_phase_programs,
+            label="TieredShardedTpuChecker.phases",
+            journal=self._journal,
+            provenance=self._key_provenance(),
+        )
+
+    def _build_phase_programs(self):
+        """One shard_map program per phase — canon+fp / local
+        dedup-sort / exchange / table insert — with the same kernels as
+        the in-HBM engine's fused ``body``.  All cross-shard reductions
+        are host sums over per-shard outputs, so the only collective is
+        the all_to_all itself."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import PartitionSpec as P
+
+        from ..ops.device_fp import device_fp64
+        from ..parallel.hashset import (
+            HashSet, insert_batch_claim, insert_batch_compact, prededup,
+        )
+
+        cm = self._compiled
+        w = cm.state_width
+        fpw = cm.fp_words or w
+        canon = self._canon
+
+        def fp_of(rows):
+            rows_c = rows if canon is None else jax.vmap(canon)(rows)
+            return device_fp64(rows_c[:, :fpw])
+
+        n = self._n
+        sortless = self._sortless  # the dedup path (claim vs sort)
+        bkt = self._bucket_lanes()  # per-destination exchange bucket
+        u = jnp.uint32
+        shard = P("shards")
+
+        def sharded(fn, n_in, donate=()):
+            return jax.jit(
+                jax.shard_map(
+                    fn, mesh=self._mesh,
+                    in_specs=(shard,) * n_in, out_specs=shard,
+                ),
+                donate_argnums=donate,
+            )
+
+        def canon_shard(rows_v):
+            hi, lo = fp_of(rows_v)
+            return hi, lo
+
+        def prededup_shard(hi, lo, rows_v, gid_v, eb_v, v_act):
+            if sortless and n == 1:
+                # 1-shard sortless: the claim insert IS the global
+                # dedup — this phase is the identity, exactly the fused
+                # body's elision.
+                n_cand = jnp.sum(v_act, dtype=u)
+                return hi, lo, rows_v, gid_v, eb_v, v_act, n_cand[None]
+            # dd=1 over the already-compacted buffer, exactly the fused
+            # body's local pre-dedup: representatives in sorted key
+            # order, one lane per distinct local key.
+            u_hi, u_lo, u_origin0, u_valid, _never = prededup(
+                hi, lo, v_act, dedup_factor=1
+            )
+            rows_u = rows_v[u_origin0]
+            gid_u = gid_v[u_origin0]
+            eb_u = eb_v[u_origin0]
+            n_cand = jnp.sum(u_valid, dtype=u)
+            return u_hi, u_lo, rows_u, gid_u, eb_u, u_valid, n_cand[None]
+
+        def exchange_shard(u_hi, u_lo, rows_u, gid_u, eb_u, u_valid):
+            # Bucket by owner + the single packed all_to_all (the fused
+            # body's BUCKETED exchange block), plus the receiver-side
+            # re-fingerprint of the arrived rows.  The per-shard
+            # bucket-overflow flag rides back so the host can abort the
+            # wave before the append commits anything and retry it at
+            # the next slack rung (the fused loop's contract).
+            u_sz = u_hi.shape[0]
+            owner = _owner_mix(u_hi, u_lo) % u(n)
+            key = jnp.where(u_valid, owner, u(n))
+            order = jnp.argsort(key, stable=True)
+            key_s = key[order]
+            counts = jnp.stack(
+                [jnp.sum((key == u(d)).astype(u)) for d in range(n + 1)]
+            )
+            bucket_ovf = jnp.any(counts[:n] > u(bkt))
+            offsets = jnp.concatenate(
+                [jnp.zeros((1,), u), jnp.cumsum(counts)[:-1]]
+            )
+            pos = jnp.arange(u_sz, dtype=u) - offsets[key_s]
+            dst = jnp.where(key_s < n, key_s, u(n))
+            payload = jnp.concatenate(
+                [
+                    rows_u,
+                    gid_u[:, None],
+                    eb_u[:, None],
+                    u_valid.astype(u)[:, None],
+                ],
+                axis=1,
+            )
+            send = jnp.zeros((n, bkt, w + 3), u)
+            send = send.at[dst, pos].set(payload[order], mode="drop")
+            recv = jax.lax.all_to_all(
+                send, "shards", split_axis=0, concat_axis=0, tiled=False
+            )
+            flatrecv = recv.reshape(n * bkt, w + 3)
+            rw = flatrecv[:, :w]
+            rhi, rlo = fp_of(rw)
+            return (
+                rw, flatrecv[:, w], flatrecv[:, w + 1],
+                flatrecv[:, w + 2], rhi, rlo, bucket_ovf[None],
+            )
+
+        def insert_shard(key_hi, key_lo, rhi, rlo, rv):
+            if sortless:
+                (
+                    table, r_slot, r_new, r_origin, _ra, probe_ok,
+                    dd_overflow, rounds,
+                ) = insert_batch_claim(
+                    HashSet(key_hi, key_lo), rhi, rlo,
+                    rv.astype(jnp.bool_), with_rounds=True,
+                )
+            else:
+                (
+                    table, r_slot, r_new, r_origin, _ra, probe_ok,
+                    dd_overflow, rounds,
+                ) = insert_batch_compact(
+                    HashSet(key_hi, key_lo), rhi, rlo,
+                    rv.astype(jnp.bool_), dedup_factor=1,
+                    with_rounds=True,
+                )
+            return (
+                table.key_hi, table.key_lo, r_slot, r_new, r_origin,
+                probe_ok[None], dd_overflow[None], jnp.sum(rounds)[None],
+            )
+
+        return {
+            "canon": sharded(canon_shard, 1),
+            "prededup": sharded(prededup_shard, 6),
+            "exchange": sharded(exchange_shard, 6),
+            "insert": sharded(insert_shard, 5, donate=(0, 1)),
+        }
+
     # --- the tiered-sharded wave (one _wl_call) -------------------------------
 
     def _wl_call(self, carry):
@@ -366,7 +532,7 @@ class TieredShardedTpuChecker(ShardedTpuChecker):
             self._ts_cold_last = None
             return carry
         progs = self._ts_programs()
-        base = self._traced_programs()
+        base = self._phase_programs()
         counts = np.minimum(backlog, f_eff)
         ctrl_np = np.zeros((n, 2), np.uint32)
         ctrl_np[:, 0] = self._ts_level_start

@@ -219,21 +219,26 @@ def test_trace_flag_requires_check_tpu_and_rejects_resume():
     r = run_cli("twophase", "check", "3", "--trace")
     assert r.returncode == 2
     assert "check-tpu" in r.stderr
-    r = run_cli("twophase", "check-tpu", "3", "--trace", "--resume",
-                "--checkpoint-dir", "/tmp/x")
+    # The phase-timed trace is the tiered engine's; the fused program is
+    # profiled with --xprof-dir.
+    r = run_cli("twophase", "check-tpu", "3", "--trace")
+    assert r.returncode == 2
+    assert "--tiered" in r.stderr and "--xprof-dir" in r.stderr
+    r = run_cli("twophase", "check-tpu", "3", "--tiered", "--trace",
+                "--resume", "--checkpoint-dir", "/tmp/x")
     assert r.returncode == 2
     assert "--trace" in r.stderr
 
 
 @pytest.mark.slow
 def test_check_tpu_trace_emits_breakdown(tmp_path):
-    """`check-tpu --trace` completes with the golden count, prints the
-    one-line roofline reduction, and (with --checkpoint-dir) leaves the
-    enriched wave-trace records in the run journal — the CI artifact
-    path (docs/OBSERVABILITY.md)."""
+    """`check-tpu --tiered --trace` completes with the golden count,
+    prints the one-line roofline reduction, and (with --checkpoint-dir)
+    leaves the enriched wave-trace records in the run journal
+    (docs/OBSERVABILITY.md)."""
     run_dir = str(tmp_path / "trace-run")
     r = run_cli(
-        "twophase", "check-tpu", "3", "--trace",
+        "twophase", "check-tpu", "3", "--tiered", "--trace",
         "--checkpoint-dir", run_dir, timeout=600,
     )
     assert r.returncode == 0, r.stderr
@@ -244,7 +249,7 @@ def test_check_tpu_trace_emits_breakdown(tmp_path):
     summary = json.loads(trace_line[len("trace: "):])
     assert summary["traced_waves"] >= 1
     assert set(summary["wave_breakdown"]) == {
-        "step", "canon", "dedup", "append", "readback",
+        "step", "canon", "dedup", "cold_probe", "append", "readback",
     }
     from stateright_tpu.runtime.journal import read_journal
 

@@ -1,14 +1,15 @@
 """Cross-engine anchor for the flagship bench workload `paxos check 3`.
 
-bench.py's golden (1,194,428 unique / depth 28) was self-measured in round
-2; this pins the same configuration across all three engines — host BFS,
+The full-scale golden (1,194,428 unique / depth 28) is held on the chip by
+the benchmark's ``paxos-3.exhaustive`` cell, against the plain reference
+``benchmark/reference/paxos.py``; this pins the same configuration
+across all three engines — host BFS,
 single-chip wavefront, and the sharded mesh engine — so a simultaneous
 regression in the host and device encodings cannot go unnoticed.  Full
 scale exceeds suite runtime on a CPU box (the host alone needs ~10 min),
-so the pin is depth-bounded here; the full-scale count is verified fatally
-on real hardware by bench.py every round (bench.py:GOLDEN_UNIQUE), and the
-depth prefix below is exact for every engine (target_max_depth semantics
-are level-accurate on all three).
+so the pin is depth-bounded here, and the depth prefix below is exact for
+every engine (target_max_depth semantics are level-accurate on all
+three).
 """
 
 import pytest

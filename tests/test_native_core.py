@@ -145,8 +145,7 @@ def test_graph_engine_uses_native_set_when_threaded():
 
 
 def test_twophase_native_bfs_reference_goldens():
-    """The C++ hot-loop BFS (bench.py's `denominator_native` phase)
-    explores exactly the direct 2pc reachable space: reference goldens
+    """The C++ hot-loop BFS explores exactly the direct 2pc reachable space: reference goldens
     288 (3 RMs) and 8,832 (5 RMs, examples/2pc.rs:151-159), with the
     framework's depth convention and generated-state counts."""
     from stateright_tpu.models.twophase import TwoPhaseSys

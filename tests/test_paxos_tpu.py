@@ -364,8 +364,7 @@ def reachable_c2():
 def test_spawn_tpu_paxos_c4_depth_bounded_differential():
     """4 clients — past the round-2 client cap, exercising the widened
     proposal/value fields and base-8 envelope addressing.  Depth-bounded:
-    the full c=4 space exceeds suite runtime (the full-scale anchor is
-    bench.py's fatal golden on real hardware)."""
+    the full c=4 space exceeds suite runtime."""
     host = (
         paxos_model(4)
         .checker()
@@ -412,9 +411,9 @@ def test_spawn_tpu_paxos_c6_depth_bounded_differential():
     (bench.sh:28) — depth-bounded so the host oracle fits suite runtime.
     The bit-packed linearizability DP (128 subset words per value column
     at C=6) must agree exactly with the host tester; the full-scale
-    anchors are the tpu-marked golden below and bench.py's device suite
-    (full c=6 on hardware: 9,357,525 unique, depth 28, differential vs
-    host pinned at depth 12: 283,217)."""
+    anchors are the tpu-marked golden below and the benchmark's
+    ``paxos-6.exhaustive`` cell (full c=6 on hardware: 9,357,525 unique,
+    depth 28, differential vs host pinned at depth 12: 283,217)."""
     host = (
         paxos_model(6)
         .checker()

@@ -184,20 +184,30 @@ def test_run_report_untraced_supervised_journal():
     json.dumps(rep)  # the --json form must serialize
 
 
-def test_run_report_traced_journal_names_device_phase():
-    events = [
-        _wave(1.0, 1, 100, 1, 0.5, wave_breakdown={
-            "step": 0.1, "dedup": 0.3, "append": 0.05, "readback": 0.05,
-        }),
-        _wave(2.0, 2, 250, 2, 0.5, wave_breakdown={
-            "step": 0.1, "dedup": 0.25, "append": 0.05, "readback": 0.1,
-        }),
-        {"t": 2.5, "event": "trace_summary", "hbm_util_frac": 0.004},
-    ]
-    rep = analyze_journal(events)
+def test_run_report_traced_journal_names_device_phase(tmp_path):
+    """The report over a real traced journal (the tiered engine's
+    phase-timed waves): the phase split is the traced one, the
+    bottleneck a device phase (host phases excluded), and the summary
+    the run's own."""
+    import jax
+
+    from stateright_tpu.models.twophase import TwoPhaseSys
+    from stateright_tpu.obs.trace import HOST_PHASES
+    from stateright_tpu.runtime.journal import read_journal
+
+    journal = str(tmp_path / "journal.jsonl")
+    ck = TwoPhaseSys(rm_count=3).checker().spawn_tpu_tiered(
+        capacity=1 << 14, max_frontier=1 << 9,
+        device=jax.devices("cpu")[0], trace=True, journal=journal,
+    ).join()
+    assert ck.unique_state_count() == 288
+    rep = analyze_journal(read_journal(journal))
     assert rep["phase_source"] == "traced"
-    assert rep["bottleneck_phase"] == "dedup"  # readback excluded
-    assert rep["trace_summary"]["hbm_util_frac"] == 0.004
+    assert rep["bottleneck_phase"] not in HOST_PHASES
+    assert rep["bottleneck_phase"] in {"step", "canon", "dedup", "append"}
+    assert rep["trace_summary"]["hbm_util_frac"] == (
+        ck.trace_summary()["hbm_util_frac"]
+    )
 
 
 def test_service_journal_report_collects_job_spans():

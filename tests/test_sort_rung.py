@@ -1,8 +1,8 @@
 """The adaptive dedup-sort geometry ladder (parallel/wave_loop.py's
 ``sort_lanes`` rung): forced tiny-rung overflow-retry runs must land the
-bit-identical discovery set on every engine (single-chip fused AND
-traced, sharded at 1/2/4/8 virtual shards, tiered), the density tuner
-must downshift a default run once it has evidence, and the traced byte
+bit-identical discovery set on every engine (single-chip fused, sharded
+at 1/2/4/8 virtual shards, tiered), the density tuner must downshift a
+default run once it has evidence, and the tiered engine's traced byte
 model must reflect the rung (``bytes.dedup`` is the regression gauge).
 
 The fixed-geometry reference in every gate is ``sort_lanes`` pinned past
@@ -74,19 +74,6 @@ def test_forced_tiny_rung_single_chip_fused_bit_identical(
     m = ck.metrics()
     assert m["sort_lanes"] > SORT_RUNG_MIN
     assert ck.tuned_kwargs()["sort_lanes"] == m["sort_lanes"]
-
-
-def test_forced_tiny_rung_single_chip_traced_bit_identical(
-    tmp_path, reference_fps
-):
-    journal = str(tmp_path / "rung_traced.jsonl")
-    ck = _model().checker().spawn_tpu(
-        capacity=1 << 14, max_frontier=1 << 9, device=_cpu(),
-        sort_lanes=SORT_RUNG_MIN, trace=True, journal=journal,
-    ).join()
-    assert ck.unique_state_count() == GOLDEN
-    assert _rung_grows(journal)
-    assert np.array_equal(ck.discovered_fingerprints(), reference_fps)
 
 
 @pytest.mark.parametrize("shards", [1, 2, 4, 8])
@@ -163,11 +150,11 @@ def test_explicit_rung_disarms_tuner(tmp_path):
 
 
 def test_traced_bytes_dedup_reflect_rung(tmp_path):
-    """The modeled ``bytes.dedup`` must drop with the rung — the
-    regression gauge bench.py's dedup phase reports.  Byte totals are
-    deterministic modulo probe rounds, so strict inequality is safe."""
+    """The modeled ``bytes.dedup`` of the tiered engine's phase-timed
+    trace must drop with the rung.  Byte totals are deterministic modulo
+    probe rounds, so strict inequality is safe."""
     def spawn(sort_lanes):
-        return _model().checker().spawn_tpu(
+        return _model().checker().spawn_tpu_tiered(
             capacity=1 << 14, max_frontier=1 << 9, device=_cpu(),
             trace=True, sort_lanes=sort_lanes,
         ).join()

@@ -3,8 +3,8 @@
 Fingerprint-bit-identity matrix: the SORTLESS default (claim-plane
 representative election, hashset.insert_batch_claim) and the
 ``step_lanes`` chunk rung must land the exact discovery set of the
-sorted fixed-geometry path on every engine — single-chip fused and
-traced, sharded at 1/2/4/8 virtual shards, tiered under forced
+sorted fixed-geometry path on every engine — single-chip fused,
+sharded at 1/2/4/8 virtual shards, tiered under forced
 eviction, symmetry through the golden orbit count — including
 forced-overflow runs: a tiny forced step rung climbs via the
 non-committing flag 128, and a sortless run forced onto a tiny
@@ -82,35 +82,17 @@ def test_sortless_is_the_default_and_fused_bit_identical(
     assert ck.tuned_kwargs()["sortless"] == 1
 
 
-def test_sortless_traced_bit_identical(tmp_path, reference_fps):
-    ck = _model().checker().spawn_tpu(
-        capacity=1 << 14, max_frontier=1 << 9, device=_cpu(),
-        trace=True, journal=str(tmp_path / "t.jsonl"),
-    ).join()
-    assert ck.unique_state_count() == GOLDEN
-    assert np.array_equal(ck.discovered_fingerprints(), reference_fps)
-    # bytes.dedup on the sortless path carries no sort term: strictly
-    # below the sorted reference's at the same geometry.
-    sorted_ck = _model().checker().spawn_tpu(
-        capacity=1 << 14, max_frontier=1 << 9, device=_cpu(),
-        trace=True, sortless=False, sort_lanes=FULL,
-    ).join()
-    assert (
-        ck.trace_summary()["bytes"]["dedup"]
-        < sorted_ck.trace_summary()["bytes"]["dedup"]
-    )
-
-
 def test_dedup_stage_rounds_in_metrics():
     # 2pc 5 at the default geometry: every wave's valid lanes fit the
     # 16,384-lane rung, so the main stage hands off at once and that rung
-    # does the first rounds.  The traced loop counts the same rounds.
+    # does the first rounds.  A call per wave counts the same rounds as
+    # the default call of many waves.
     from stateright_tpu.parallel.hashset import STRAGGLER_LADDER
 
     readings = []
-    for trace in (False, True):
+    for waves_per_call in (1, None):
         ck = TwoPhaseSys(rm_count=5).checker().spawn_tpu(
-            device=_cpu(), trace=trace,
+            device=_cpu(), waves_per_call=waves_per_call,
         ).join()
         assert ck.unique_state_count() == 8832
         m = ck.metrics()
@@ -266,17 +248,6 @@ def test_forced_tiny_step_rung_climbs_and_bit_identical(
     m = ck.metrics()
     assert m["step_lanes"] > STEP_RUNG_MIN
     assert ck.tuned_kwargs()["step_lanes"] == m["step_lanes"]
-
-
-def test_forced_tiny_step_rung_traced(tmp_path, reference_fps):
-    journal = str(tmp_path / "step_traced.jsonl")
-    ck = _model().checker().spawn_tpu(
-        capacity=1 << 14, max_frontier=1 << 9, device=_cpu(),
-        trace=True, step_lanes=STEP_RUNG_MIN, journal=journal,
-    ).join()
-    assert ck.unique_state_count() == GOLDEN
-    assert np.array_equal(ck.discovered_fingerprints(), reference_fps)
-    assert _grows(journal, "step_lanes=")
 
 
 def test_forced_tiny_step_rung_sharded(tmp_path, reference_fps):

@@ -203,7 +203,7 @@ def test_bucket_overflow_retry_path_forced(tmp_path):
     """A deliberately tiny bucket slack forces the overflow-flag +
     retry-at-next-rung path: the run journals a ``grow`` event with
     flag 32, climbs the slack ladder, and still lands the exact
-    single-chip discovery set — on the fused AND the traced loop."""
+    single-chip discovery set."""
     from stateright_tpu.runtime.journal import read_journal
 
     model = TwoPhaseSys(rm_count=4)
@@ -229,18 +229,6 @@ def test_bucket_overflow_retry_path_forced(tmp_path):
     assert acc["bucket_slack"] > 1  # the ladder actually climbed
     grows = [e for e in read_journal(journal) if e["event"] == "grow"]
     assert grows and any(e["flags"] & 32 for e in grows)
-
-    traced = (
-        TwoPhaseSys(rm_count=4).checker()
-        .spawn_tpu_sharded(
-            mesh=_mesh(4), capacity=1 << 14, chunk_size=1 << 7,
-            bucket_slack=1, trace=True,
-        )
-        .join()
-    )
-    assert traced.unique_state_count() == 1568
-    assert np.array_equal(traced.discovered_fingerprints(), fps)
-    assert traced.accounting()["bucket_retries"] >= 1
 
 
 @pytest.mark.slow

@@ -121,7 +121,7 @@ def test_twophase_property_conds_parity(twophase3):
 def _assert_checker_parity(model, **tpu_kwargs):
     host = model.checker().spawn_bfs().join()
     # Default to the (virtual) CPU backend: fast and always present.  The
-    # real-TPU path is exercised by bench.py and the tpu-marked smoke test.
+    # real-TPU path is exercised by the benchmark and the tpu-marked tests.
     tpu_kwargs.setdefault("device", jax.devices("cpu")[0])
     tpu = model.checker().spawn_tpu(**tpu_kwargs).join()
     assert tpu.unique_state_count() == host.unique_state_count()
@@ -348,9 +348,8 @@ def test_log_capacity_smaller_than_table_exact():
 def test_twophase10_depth_bounded_differential():
     """`2pc check 10` — the largest reference bench workload (bench.sh:27)
     — depth-bounded so the host oracle fits suite runtime.  Full scale
-    runs in bench.py's reference-suite phase, golden-gated at 61,515,776
-    unique states / depth 32 (device, 2026-07-31; depth-8 differential
-    pinned 256,660 both engines)."""
+    is 61,515,776 unique states / depth 32 (device, 2026-07-31; depth-8
+    differential pinned 256,660 both engines)."""
     model = TwoPhaseSys(rm_count=10)
     host = model.checker().target_max_depth(7).spawn_bfs().join()
     tpu = (
@@ -468,11 +467,11 @@ def test_table_growth_drags_log_x2_not_to_half_capacity():
     a capacity/2 drag after the ×16 table jump can allocate gigabytes
     past what the run needs (w=77: observed as an HBM-pressure risk on
     `paxos check 6`)."""
-    import bench
     from stateright_tpu.parallel.wavefront import TpuChecker
+    from stateright_tpu.serve.workloads import build_model
 
     ck = TpuChecker.__new__(TpuChecker)
-    ck._compiled = bench.paxos_model(6).compiled()
+    ck._compiled = build_model("paxos", 6)[0].compiled()
     ck._capacity = 1 << 24
     ck._log_capacity = 1 << 23
     ck._log_capacity_explicit = False
