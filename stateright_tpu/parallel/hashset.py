@@ -35,11 +35,16 @@ rung (knob cache selected).  The sorted form runs in three stages:
    and 256 lanes).  A stage runs rounds while its unresolved count
    exceeds the next stage's width, then compacts the unresolved lane ids
    (order-preserving, so the lowest lane stays lowest) and gathers their
-   keys and slots into the next; the last stage runs to completion.  A
-   hand-off with nothing unresolved is skipped, and a stage not narrower
+   keys and slots into the next; the last stage runs to completion.
+   Where the unresolved lanes are already a prefix of the stage (it ran
+   no rounds over a compacted batch, as on most waves, or none are
+   left) that compaction is the identity, so the next stage is entered
+   by static slices instead, with the same lanes in the same order, and
+   a stage with nothing to probe runs no round.  A stage not narrower
    than the batch is never entered.  One deep chain must not make the
    full batch repeat forty rounds.  Both insert paths share the ladder
-   (:func:`_probe_ladder`), and report the rounds run at each stage.
+   (:func:`_probe_ladder`), and report the rounds run at each stage and
+   the hand-offs taken by slice.
 
 Empty slots are (0, 0); fingerprints are guaranteed nonzero
 (ops.device_fp, mirroring the reference's NonZeroU64 fingerprints,
@@ -175,9 +180,10 @@ def stage_widths(b: int) -> Tuple[int, ...]:
 
 def _ladder_stage(kh, kl, claim, hi, lo, slot, done, rungs, elect):
     """Probe rounds at this stage's width until the unresolved lanes fit
-    ``rungs[0]``, then hand them down the remaining rungs and scatter
+    ``rungs[0]``, then hand them down the remaining rungs and write
     their results back.  Returns ``(kh, kl, slot, done, is_new,
-    rounds[1 + len(rungs)])``."""
+    rounds[1 + len(rungs)], slices[len(rungs)])``; ``slices`` is 1 at
+    each rung entered by slice."""
     from .wave_common import compact
 
     w = hi.shape[0]
@@ -186,52 +192,80 @@ def _ladder_stage(kh, kl, claim, hi, lo, slot, done, rungs, elect):
         kh, kl, claim, hi, lo, slot, done, nxt, elect
     )
     if not rungs:
-        return kh, kl, slot, done, is_new, rounds[None]
-
-    def hand_off(ops):
-        kh, kl, claim, slot, done, is_new = ops
-        undone = ~done
-        ref = compact(undone, jnp.arange(w, dtype=_U32), nxt)
-        t_act = jnp.arange(nxt, dtype=_U32) < jnp.sum(undone, dtype=_U32)
-        kh, kl, t_slot, t_done, t_new, t_rounds = _ladder_stage(
-            kh, kl, claim, hi[ref], lo[ref], slot[ref], ~t_act, rungs[1:],
-            elect,
+        return (
+            kh, kl, slot, done, is_new, rounds[None],
+            jnp.zeros((0,), jnp.int32),
         )
+
+    undone = ~done
+    n = jnp.sum(undone, dtype=_U32)
+    lane = jnp.arange(w, dtype=_U32)
+    t_act = lane[:nxt] < n
+    # Unresolved lanes that are already the prefix [0, n) — a stage that
+    # ran no rounds over a compacted batch, or none left — compact to
+    # themselves (and lane 0 past n): the next stage then takes static
+    # slices, and its results go back by a slice update in place of the
+    # cumsum, gathers and scatters.  ``ref`` is the compaction either
+    # way; only the scatter-back reads it.  No cond takes the table
+    # planes, so they stay where the rounds keep them (VMEM at 2^23).
+    prefix = jnp.all(undone == (lane < n))
+
+    def by_slice():
+        return (
+            jnp.where(t_act, lane[:nxt], _U32(0)), hi[:nxt], lo[:nxt],
+            slot[:nxt],
+        )
+
+    def by_compaction():
+        ref = compact(undone, lane, nxt)
+        return ref, hi[ref], lo[ref], slot[ref]
+
+    ref, t_hi, t_lo, t_slot = jax.lax.cond(prefix, by_slice, by_compaction)
+    kh, kl, t_slot, t_done, t_new, t_rounds, t_slices = _ladder_stage(
+        kh, kl, claim, t_hi, t_lo, t_slot, ~t_act, rungs[1:], elect,
+    )
+
+    def put_slice():
+        def put(x, t):
+            return jnp.concatenate([jnp.where(t_act, t, x[:nxt]), x[nxt:]])
+
+        return put(slot, t_slot), put(done, t_done), put(is_new, t_new)
+
+    def put_scatter():
         back = jnp.where(t_act, ref, _U32(w))
         return (
-            kh, kl, slot.at[back].set(t_slot, mode="drop"),
+            slot.at[back].set(t_slot, mode="drop"),
             done.at[back].set(t_done, mode="drop"),
-            is_new.at[back].set(t_new, mode="drop"), t_rounds,
+            is_new.at[back].set(t_new, mode="drop"),
         )
 
-    def skip(ops):
-        kh, kl, _claim, slot, done, is_new = ops
-        return kh, kl, slot, done, is_new, jnp.zeros(
-            (len(rungs),), jnp.int32
-        )
-
-    kh, kl, slot, done, is_new, sub = jax.lax.cond(
-        jnp.any(~done), hand_off, skip, (kh, kl, claim, slot, done, is_new)
+    slot, done, is_new = jax.lax.cond(prefix, put_slice, put_scatter)
+    sliced = (prefix & (n > 0)).astype(jnp.int32)
+    return (
+        kh, kl, slot, done, is_new,
+        jnp.concatenate([rounds[None], t_rounds]),
+        jnp.concatenate([sliced[None], t_slices]),
     )
-    return kh, kl, slot, done, is_new, jnp.concatenate([rounds[None], sub])
 
 
 def _probe_ladder(table: HashSet, claim, hi, lo, active, elect: bool):
     """Insert-if-absent probing shared by both insert paths: the main
     stage at the batch width, then the straggler ladder.  Returns ``(kh,
-    kl, slot, done, is_new, stage_rounds)`` with ``stage_rounds`` an
-    int32 vector over :func:`stage_widths` of the batch (0 at a skipped
-    rung); the executed round count is its sum."""
+    kl, slot, done, is_new, stage_rounds, stage_slices)``, int32 vectors
+    over :func:`stage_widths` of the batch: the rounds run at each stage
+    (0 at a skipped rung; the executed round count is their sum), and 1
+    at each rung entered by slice (0 at the main stage)."""
     rungs = tuple(w for w in STRAGGLER_LADDER if w < hi.shape[0])
-    kh, kl, slot, done, is_new, rounds = _ladder_stage(
+    kh, kl, slot, done, is_new, rounds, slices = _ladder_stage(
         table.key_hi, table.key_lo, claim, hi, lo,
         home_slot(hi, lo, table.capacity), ~active, rungs, elect,
     )
-    skipped = len(STRAGGLER_LADDER) - len(rungs)
-    stage_rounds = jnp.concatenate(
-        [rounds[:1], jnp.zeros((skipped,), jnp.int32), rounds[1:]]
+    skipped = jnp.zeros((len(STRAGGLER_LADDER) - len(rungs),), jnp.int32)
+    stage_rounds = jnp.concatenate([rounds[:1], skipped, rounds[1:]])
+    stage_slices = jnp.concatenate(
+        [jnp.zeros((1,), jnp.int32), skipped, slices]
     )
-    return kh, kl, slot, done, is_new, stage_rounds
+    return kh, kl, slot, done, is_new, stage_rounds, stage_slices
 
 
 def unique_buffer_size(b: int, dedup_factor: int) -> int:
@@ -387,11 +421,13 @@ def insert_batch_compact(
     worst-case U (wave_loop.py's ladder): the pre-dedup sort, the probe
     rounds, and every U-sized result buffer then span the rung instead.
 
-    ``with_rounds=True`` appends one more element — the probe rounds run
-    at each stage, an int32 vector over :func:`stage_widths` of the
-    buffer, whose sum is the executed round count — which the engines
-    count (``metrics()["dedup_stage_rounds"]``) and the traced loops feed
-    to the roofline's probe-bytes model (obs/roofline.py).
+    ``with_rounds=True`` appends two more elements, int32 vectors over
+    :func:`stage_widths` of the buffer: the probe rounds run at each
+    stage, whose sum is the executed round count, and the hand-offs into
+    each stage taken by slice.  The engines count them
+    (``metrics()["dedup_stage_rounds"]``, ``["dedup_stage_slices"]``)
+    and the tiered loops feed the rounds to the roofline's probe-bytes
+    model (obs/roofline.py).
 
     This compact form is the engine hot path: per-chunk candidate batches
     carry ~95% invalid/duplicate lanes, and scattering B-indexed state
@@ -405,7 +441,7 @@ def insert_batch_compact(
     # Stages 2-3 over the unique buffer; distinct keys, so the claim is a
     # plain set.
     claim = table.key_hi ^ table.key_hi  # varying-typed zero plane
-    kh, kl, u_slot, u_done, u_new, rounds = _probe_ladder(
+    kh, kl, u_slot, u_done, u_new, rounds, slices = _probe_ladder(
         table, claim, u_hi, u_lo, u_active, elect=False
     )
 
@@ -413,7 +449,7 @@ def insert_batch_compact(
         HashSet(kh, kl), u_slot, u_new, u_origin, u_active,
         jnp.all(u_done), overflow,
     )
-    return (out + (rounds,)) if with_rounds else out
+    return (out + (rounds, slices)) if with_rounds else out
 
 
 def insert_batch_claim(
@@ -444,7 +480,8 @@ def insert_batch_claim(
     is structurally False — there is no representative buffer to
     overflow; the caller's :func:`compact_valid` criterion is the only
     capacity gate on this path.  ``with_rounds=True`` appends the
-    per-stage probe rounds, as in :func:`insert_batch_compact`.
+    per-stage probe rounds and slice hand-offs, as in
+    :func:`insert_batch_compact`.
 
     The one failure mode the election shares with the sorted path is
     probe exhaustion (``probe_ok`` False): the engines raise flag 1 and
@@ -462,7 +499,7 @@ def insert_batch_claim(
     # and their same-key followers, which move in lockstep and so stay
     # electable, finish down the straggler ladder.
     claim = (table.key_hi ^ table.key_hi) | _SENTINEL
-    kh, kl, slot, done, is_new, rounds = _probe_ladder(
+    kh, kl, slot, done, is_new, rounds, slices = _probe_ladder(
         table, claim, hi, lo, active, elect=True
     )
     lane = jnp.arange(hi.shape[0], dtype=_U32)
@@ -471,7 +508,7 @@ def insert_batch_claim(
         HashSet(kh, kl), slot, is_new, lane, active,
         jnp.all(done), jnp.zeros((), jnp.bool_),
     )
-    return (out + (rounds,)) if with_rounds else out
+    return (out + (rounds, slices)) if with_rounds else out
 
 
 def insert_batch(

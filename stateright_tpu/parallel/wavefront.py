@@ -67,11 +67,13 @@ NO_SLOT_HOST = 0xFFFFFFFF
     STAT_DEPTH,
     STAT_FLAGS,
 ) = range(8)
-# The dedup probe rounds run() spent at each stage, in
-# hashset.stage_widths order (main stage, then the straggler ladder):
-# counted over the call, zero on the way in.
+# The dedup probe rounds run() spent at each stage, then the hand-offs
+# into each stage taken by slice, both in hashset.stage_widths order
+# (main stage, then the straggler ladder): counted over the call, zero
+# on the way in.
 STAT_ROUNDS = 8
-STAT_DISC = 12  # disc[P] rides at [STAT_DISC : STAT_DISC + n_props]
+STAT_SLICES = 12
+STAT_DISC = 16  # disc[P] rides at [STAT_DISC : STAT_DISC + n_props]
 
 
 def host_stats(level_start, level_end, tail, states, unique, depth, disc):
@@ -381,8 +383,10 @@ class TpuChecker(Checker):
         # relax included, and sortless → sort flips.
         self._dedup_rung_climbs = 0
         self._dedup_fallbacks = 0
-        # Dedup probe rounds run at each stage width (metrics()).
+        # Dedup probe rounds run at each stage width, and hand-offs into
+        # each taken by slice (metrics()).
         self._stage_rounds: Dict[int, int] = {}
+        self._stage_slices: Dict[int, int] = {}
         # Dedup-path selection (the sortless claim-plane election is the
         # default; an explicit sort_lanes rung selects the sorted
         # fallback path — see the docstring).
@@ -516,7 +520,8 @@ class TpuChecker(Checker):
         level_end, tail, sc_lo, sc_hi, unique_count, depth, disc[P],
         waves_left, flags, probe).  ``sc_lo``/``sc_hi`` form the 64-bit
         generated-state counter (no u64 on device); ``probe`` counts the
-        call's dedup probe rounds per stage (``STAT_ROUNDS``).  flag
+        call's dedup probe rounds and slice hand-offs per stage
+        (``STAT_ROUNDS``, ``STAT_SLICES``).  flag
         values: 1 = table overfull (probe failure or beyond 50% load); 2 =
         row log full (unique states exceeded ``log_capacity``); 4 = insert
         dedup-buffer overflow; 8 = model step kernel capacity overflow.
@@ -667,7 +672,7 @@ class TpuChecker(Checker):
                 # are identical (u_origin is the identity map).
                 (
                     table, _u_slot, u_new, u_origin, _u_active, probe_ok,
-                    dd_overflow, stage_rounds,
+                    dd_overflow, stage_rounds, stage_slices,
                 ) = insert_batch_claim(
                     HashSet(key_hi, key_lo), hi, lo, v_act,
                     with_rounds=True,
@@ -675,12 +680,14 @@ class TpuChecker(Checker):
             else:
                 (
                     table, _u_slot, u_new, u_origin, _u_active, probe_ok,
-                    dd_overflow, stage_rounds,
+                    dd_overflow, stage_rounds, stage_slices,
                 ) = insert_batch_compact(
                     HashSet(key_hi, key_lo), hi, lo, v_act,
                     dedup_factor=1, with_rounds=True,
                 )
-            probe = probe + stage_rounds.astype(jnp.uint32)
+            probe = probe + jnp.concatenate(
+                [stage_rounds, stage_slices]
+            ).astype(jnp.uint32)
             dd_overflow = dd_overflow | v_overflow
             n_new = jnp.sum(u_new, dtype=jnp.uint32)
 
@@ -1277,7 +1284,9 @@ class TpuChecker(Checker):
         # host reads travels in the stats vector.
         stats_h = np.asarray(carry[5])
         self._last_stats_h = stats_h
-        self._count_stage_rounds(stats_h[STAT_ROUNDS:STAT_DISC])
+        self._count_stage_rounds(
+            stats_h[STAT_ROUNDS:STAT_SLICES], stats_h[STAT_SLICES:STAT_DISC]
+        )
         remaining = int(stats_h[STAT_LEVEL_END]) - int(
             stats_h[STAT_LEVEL_START]
         )
@@ -1320,24 +1329,25 @@ class TpuChecker(Checker):
         auto-grow and both ladders may move the geometry mid-run."""
         return self._wl_full_sort_lanes()
 
-    def _count_stage_rounds(self, stage_rounds) -> int:
-        """Fold one readback of per-stage probe rounds (the insert's
-        ``with_rounds`` vector, or the fused call's ``STAT_ROUNDS``
-        slots) into ``metrics()["dedup_stage_rounds"]``, keyed by the
-        live compaction width's stage widths; returns the rounds run."""
+    def _count_stage_rounds(self, stage_rounds, stage_slices) -> int:
+        """Fold one readback of per-stage probe rounds and slice hand-offs
+        (the insert's ``with_rounds`` vectors, or the fused call's
+        ``STAT_ROUNDS`` and ``STAT_SLICES`` slots) into
+        ``metrics()["dedup_stage_rounds"]`` and
+        ``["dedup_stage_slices"]``, keyed by the live compaction width's
+        stage widths; returns the rounds run."""
         from .hashset import stage_widths
 
-        total = 0
+        widths = stage_widths(self._sort_width())
         with self._lock:
-            for width, n in zip(
-                stage_widths(self._sort_width()), stage_rounds.tolist()
+            for counts, readback in (
+                (self._stage_rounds, stage_rounds),
+                (self._stage_slices, stage_slices),
             ):
-                if n:
-                    self._stage_rounds[width] = (
-                        self._stage_rounds.get(width, 0) + int(n)
-                    )
-                    total += int(n)
-        return total
+                for width, n in zip(widths, readback.tolist()):
+                    if n:
+                        counts[width] = counts.get(width, 0) + int(n)
+        return int(sum(stage_rounds.tolist()))
 
     # --- sort-geometry rung (wave_loop.py's ladder) --------------------------
 
@@ -1756,8 +1766,10 @@ class TpuChecker(Checker):
         )
         with self._lock:
             stage_rounds = dict(sorted(self._stage_rounds.items()))
+            stage_slices = dict(sorted(self._stage_slices.items()))
         out.update(
             dedup_stage_rounds=stage_rounds,
+            dedup_stage_slices=stage_slices,
             dedup_probe_lanes_total=sum(
                 w * n for w, n in stage_rounds.items()
             ),

@@ -319,7 +319,7 @@ class TieredTpuChecker(TpuChecker):
             if sortless:
                 (
                     table, _u_slot, u_new, u_origin, _u_active, probe_ok,
-                    dd_overflow, rounds,
+                    dd_overflow, rounds, slices,
                 ) = insert_batch_claim(
                     HashSet(key_hi, key_lo), hi, lo, cand_act,
                     with_rounds=True,
@@ -327,7 +327,7 @@ class TieredTpuChecker(TpuChecker):
             else:
                 (
                     table, _u_slot, u_new, u_origin, _u_active, probe_ok,
-                    dd_overflow, rounds,
+                    dd_overflow, rounds, slices,
                 ) = insert_batch_compact(
                     HashSet(key_hi, key_lo), hi, lo, cand_act,
                     dedup_factor=1, with_rounds=True,
@@ -335,7 +335,7 @@ class TieredTpuChecker(TpuChecker):
             n_new = jnp.sum(u_new, dtype=jnp.uint32)
             return (
                 table.key_hi, table.key_lo, u_new, u_origin, n_new,
-                probe_ok, dd_overflow, rounds,
+                probe_ok, dd_overflow, rounds, slices,
             )
 
         @partial(jax.jit, donate_argnums=(0, 1, 2))
@@ -594,13 +594,15 @@ class TieredTpuChecker(TpuChecker):
             t.append(time.perf_counter())
         (
             key_hi, key_lo, u_new, u_origin, n_new_d, probe_ok_d,
-            dd_ovf_d, rounds_d,
+            dd_ovf_d, rounds_d, slices_d,
         ) = progs["insert"](key_hi, key_lo, hi, lo, cand_act)
         if trace:
             jax.block_until_ready(key_lo)
             t.append(time.perf_counter())
         n_new_hot = int(np.asarray(n_new_d))
-        rounds = self._count_stage_rounds(np.asarray(rounds_d))
+        rounds = self._count_stage_rounds(
+            np.asarray(rounds_d), np.asarray(slices_d)
+        )
         flags = 0
         if (
             not bool(np.asarray(probe_ok_d))

@@ -477,7 +477,7 @@ class TieredShardedTpuChecker(ShardedTpuChecker):
             if sortless:
                 (
                     table, r_slot, r_new, r_origin, _ra, probe_ok,
-                    dd_overflow, rounds,
+                    dd_overflow, rounds, _slices,
                 ) = insert_batch_claim(
                     HashSet(key_hi, key_lo), rhi, rlo,
                     rv.astype(jnp.bool_), with_rounds=True,
@@ -485,7 +485,7 @@ class TieredShardedTpuChecker(ShardedTpuChecker):
             else:
                 (
                     table, r_slot, r_new, r_origin, _ra, probe_ok,
-                    dd_overflow, rounds,
+                    dd_overflow, rounds, _slices,
                 ) = insert_batch_compact(
                     HashSet(key_hi, key_lo), rhi, rlo,
                     rv.astype(jnp.bool_), dedup_factor=1,

@@ -538,13 +538,15 @@ def test_claim_election_straggler_tail_batch(path, batch):
     hi, lo = _keys(vals)
     table = HashSet(jnp.asarray(kh0), jnp.asarray(kl0))
     if path == "claim":
-        t, slot, new, origin, rep, ok, _ovf, rounds = insert_batch_claim(
+        t, slot, new, origin, rep, ok, _ovf, rounds, _sl = insert_batch_claim(
             table, hi, lo, jnp.asarray(active), with_rounds=True
         )
     else:
-        t, slot, new, origin, rep, ok, _ovf, rounds = insert_batch_compact(
-            table, hi, lo, jnp.asarray(active), dedup_factor=1,
-            with_rounds=True,
+        t, slot, new, origin, rep, ok, _ovf, rounds, _sl = (
+            insert_batch_compact(
+                table, hi, lo, jnp.asarray(active), dedup_factor=1,
+                with_rounds=True,
+            )
         )
     assert bool(ok)
     slot, new, origin, rep = (np.asarray(x) for x in (slot, new, origin, rep))
@@ -594,3 +596,122 @@ def test_claim_election_straggler_tail_batch(path, batch):
                                     capacity))
         depth = (slot[rep].astype(np.int64) - home) % capacity
         assert sum(rounds) >= int(depth.max()) + 1
+
+
+# --- slice hand-offs: bit-identical to the compaction they skip --------------
+
+_SLICE_B = 1 << 15  # the buffer width: main stage, then all three rungs
+_SLICE_CAP = 1 << 16
+
+
+@pytest.fixture(scope="module")
+def slice_fixture():
+    """A table at 45% load (linear probing there builds chains of dozens
+    of slots) and a key pool that mixes its keys with new ones, plus one
+    jitted insert per path and buffer width, so every count reuses them.
+
+    The prefix side inserts through the public entry points.  The
+    shifted side runs the same batch behind one leading inactive lane,
+    which makes the first hand-off compact: the claim path through
+    :func:`insert_batch_claim`, the sort path through :func:`prededup`'s
+    buffer shifted by one and handed to the ladder as
+    :func:`insert_batch_compact` hands it."""
+    from stateright_tpu.parallel.hashset import (
+        HashSet, _probe_ladder, insert_batch_claim, insert_batch_compact,
+    )
+
+    rng = np.random.default_rng(29)
+    keys = rng.choice(1 << 62, size=int(0.45 * _SLICE_CAP) + _SLICE_B,
+                      replace=False).astype(np.uint64) + np.uint64(1)
+    pre = keys[: int(0.45 * _SLICE_CAP)]
+    kh0, kl0 = _sequential_table(pre, _SLICE_CAP)
+    table = HashSet(jnp.asarray(kh0), jnp.asarray(kl0))
+    pool = np.concatenate([pre[: len(pre) // 4], keys[len(pre):]])
+
+    def shift(x):
+        return jnp.concatenate([x[:1] & ~x[:1], x])
+
+    @jax.jit
+    def claim_prefix(hi, lo, act):
+        t, slot, new, _o, _a, ok, _v, rounds, slices = insert_batch_claim(
+            table, hi, lo, act, with_rounds=True
+        )
+        return t, slot, new, ok, rounds, slices
+
+    @jax.jit
+    def claim_shifted(hi, lo, act):
+        t, slot, new, _o, _a, ok, _v, rounds, slices = insert_batch_claim(
+            table, shift(hi), shift(lo), shift(act), with_rounds=True
+        )
+        return t, slot[1:], new[1:], ok, rounds, slices
+
+    @jax.jit
+    def compact_prefix(hi, lo, act):
+        t, slot, new, _o, _a, ok, _v, rounds, slices = (
+            insert_batch_compact(
+                table, hi, lo, act, dedup_factor=1, with_rounds=True
+            )
+        )
+        return t, slot, new, ok, rounds, slices
+
+    @jax.jit
+    def compact_shifted(hi, lo, act):
+        u_hi, u_lo, _origin, u_act, _ovf = prededup(hi, lo, act, 1)
+        claim = table.key_hi ^ table.key_hi
+        kh, kl, slot, done, new, rounds, slices = _probe_ladder(
+            table, claim, shift(u_hi), shift(u_lo), shift(u_act),
+            elect=False,
+        )
+        return (
+            HashSet(kh, kl), slot[1:], new[1:], jnp.all(done), rounds,
+            slices,
+        )
+
+    runs = {
+        "claim": (claim_prefix, claim_shifted),
+        "compact": (compact_prefix, compact_shifted),
+    }
+    return pool, runs
+
+
+@pytest.mark.parametrize(
+    "count", [0, 1, 256, 257, 2048, 2049, 16384, 16385, _SLICE_B]
+)
+@pytest.mark.parametrize("path", ["claim", "compact"])
+def test_slice_hand_off_bit_identical_to_compaction(
+    slice_fixture, path, count
+):
+    # A batch whose valid lanes are a prefix enters the ladder by
+    # slices; the same keys in the same order one lane later must enter
+    # by compaction and land the same planes, per-lane results, probe
+    # rounds and deeper hand-offs, bit for bit.
+    from stateright_tpu.parallel.hashset import STRAGGLER_LADDER
+
+    pool, runs = slice_fixture
+    rng = np.random.default_rng(count)
+    # Inactive lanes carry keys of their own, as the engines' do.
+    vals = rng.choice(pool, size=_SLICE_B)
+    hi, lo = _keys(vals)
+    act = jnp.arange(_SLICE_B) < count
+    # Lanes entering the ladder: the sort path's are the distinct keys.
+    entering = count if path == "claim" else len(set(vals[:count].tolist()))
+    by_prefix, by_shift = (
+        [np.asarray(x) for x in jax.tree_util.tree_leaves(run(hi, lo, act))]
+        for run in runs[path]
+    )
+    kh, kl, slot, new, ok, rounds, slices = by_prefix
+    s_kh, s_kl, s_slot, s_new, s_ok, s_rounds, s_slices = by_shift
+    assert bool(ok) and bool(s_ok)
+    assert np.array_equal(kh, s_kh) and np.array_equal(kl, s_kl)
+    assert np.array_equal(slot, s_slot) and np.array_equal(new, s_new)
+    assert rounds.tolist() == s_rounds.tolist()
+    assert len(slices) == 1 + len(STRAGGLER_LADDER)
+    # The first hand-off (into 16,384 lanes) slices only where the main
+    # stage ran no rounds over a nonempty prefix; deeper hand-offs see
+    # the same lanes on both sides and take the same route.
+    assert slices[0] == s_slices[0] == 0
+    assert slices[1] == int(0 < entering <= STRAGGLER_LADDER[0])
+    assert s_slices[1] == 0
+    assert slices[2:].tolist() == s_slices[2:].tolist()
+    if count and path == "claim":
+        assert int(new.sum()) > 0 and rounds.sum() > 0

@@ -106,6 +106,63 @@ def test_dedup_stage_rounds_in_metrics():
     assert readings[0] == readings[1]
 
 
+# Goldens of whole checks on the CPU: unique states, depth, the sha256 of
+# discovered_fingerprints(), and the probe rounds per stage width, as the
+# checks read before the ladder entered its stages by slice; and the
+# hand-offs taken by slice since.
+_STAGE_GOLDENS = {
+    "2pc6": (
+        50816, 20,
+        "4cfa9ff19dd4a8a0d43072ca0412f2753f8a1f810b28e2b7cf3ee9b9b9e5cc00",
+        {256: 31, 2048: 13, 16384: 11, 131072: 14},
+        {256: 8, 2048: 13, 16384: 12},
+    ),
+    # The dense 2pc 6 run falls back from the election to the sort path.
+    "2pc6_dense": (
+        50816, 20,
+        "4cfa9ff19dd4a8a0d43072ca0412f2753f8a1f810b28e2b7cf3ee9b9b9e5cc00",
+        {256: 39, 2048: 9, 16384: 14, 32768: 4},
+        {256: 12, 2048: 10, 16384: 19},
+    ),
+    "paxos2": (
+        16668, 21,
+        "3e08f2cef14aa0e2f762b93db33f6ec797ca2a56ea5c824d711bba8389de8b3e",
+        {256: 46, 2048: 11, 16384: 11},
+        {256: 12, 2048: 13},
+    ),
+}
+
+
+@pytest.mark.parametrize("check", sorted(_STAGE_GOLDENS))
+def test_dedup_stage_counts_pinned(check, request):
+    # Entering a stage by slice changes no result and no round: the
+    # discovery set, depth and per-stage rounds are the pinned goldens,
+    # and the slice hand-offs are counted.
+    import hashlib
+
+    if check == "2pc6":
+        ck = TwoPhaseSys(rm_count=6).checker().spawn_tpu(
+            device=_cpu()
+        ).join()
+    elif check == "2pc6_dense":
+        ck, _journal = request.getfixturevalue("dense_run")
+        assert ck.metrics()["sortless"] is False
+    else:
+        from tests.test_paxos_compiled import paxos_model
+
+        ck = paxos_model(2).checker().spawn_tpu(
+            capacity=1 << 18, max_frontier=1 << 13, device=_cpu(),
+        ).join()
+    unique, depth, digest, rounds, slices = _STAGE_GOLDENS[check]
+    assert ck.unique_state_count() == unique
+    assert ck.max_depth() == depth
+    fps = np.asarray(ck.discovered_fingerprints())
+    assert hashlib.sha256(fps.tobytes()).hexdigest() == digest
+    m = ck.metrics()
+    assert m["dedup_stage_rounds"] == rounds
+    assert m["dedup_stage_slices"] == slices
+
+
 @pytest.mark.parametrize("shards", [1, 2, 4, 8])
 def test_sortless_sharded_bit_identical(shards, tmp_path, reference_fps):
     ck = _model().checker().spawn_tpu_sharded(
